@@ -5,7 +5,7 @@ peer-to-peer DMA; reference at charles-achilefu/nvme-strom), rebuilt
 idiomatically for TPU: a native async I/O engine (io_uring / O_DIRECT) feeds
 pinned host staging buffers that stream into TPU HBM through PJRT, with
 JAX/XLA/Pallas consuming the data in place.  See SURVEY.md for the layer map
-and BASELINE.md for performance targets.
+and BASELINE.json for performance targets.
 
 Public surface:
 

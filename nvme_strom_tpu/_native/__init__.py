@@ -100,32 +100,42 @@ _lib = None
 _lib_lock = threading.Lock()
 _load_failed = False
 
+#: the files the .so is built from: a source newer than the .so means a
+#: stale build, which is rebuilt before loading
+_SOURCES = ("strom_engine.cc", "strom_tpu.h", "Makefile")
+
+
+def _stale() -> bool:
+    if not os.path.exists(_SO):
+        return True
+    built = os.path.getmtime(_SO)
+    return any(os.path.getmtime(os.path.join(_CSRC, f)) > built
+               for f in _SOURCES if os.path.exists(os.path.join(_CSRC, f)))
+
 
 def _load() -> Optional[ctypes.CDLL]:
     global _lib, _load_failed
     with _lib_lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_SO):
-            try:
-                subprocess.run(["make", "-C", _CSRC], check=True,
-                               capture_output=True, timeout=120)
-            except Exception:
-                _load_failed = True
-                return None
         try:
+            if _stale():
+                subprocess.run(["make", "-C", _CSRC], check=True,
+                               capture_output=True, timeout=300)
             lib = ctypes.CDLL(_SO)
-        except OSError:
+        except (OSError, subprocess.SubprocessError) as e:
+            # no toolchain or no loadable build: I/O runs on the Python
+            # pool, and the reason is said once instead of hidden
+            from ..log import pr_warn
+            pr_warn("native engine unavailable, using the Python I/O "
+                    "pool: %s", e)
             _load_failed = True
             return None
         lib.nstpu_engine_create.restype = ctypes.c_uint64
         lib.nstpu_engine_create.argtypes = [ctypes.c_int, ctypes.c_int]
-        try:
-            lib.nstpu_engine_create2.restype = ctypes.c_uint64
-            lib.nstpu_engine_create2.argtypes = [ctypes.c_int, ctypes.c_int,
-                                                 ctypes.c_int]
-        except AttributeError:  # pragma: no cover - older .so
-            pass
+        lib.nstpu_engine_create2.restype = ctypes.c_uint64
+        lib.nstpu_engine_create2.argtypes = [ctypes.c_int, ctypes.c_int,
+                                             ctypes.c_int]
         lib.nstpu_engine_destroy.argtypes = [ctypes.c_uint64]
         lib.nstpu_engine_backend.argtypes = [ctypes.c_uint64]
         lib.nstpu_submit.restype = ctypes.c_int64
@@ -140,58 +150,37 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.nstpu_engine_stats.argtypes = [ctypes.c_uint64,
                                            ctypes.POINTER(ctypes.c_uint64),
                                            ctypes.c_int32]
-        try:
-            lib.nstpu_engine_member_stats.argtypes = [
-                ctypes.c_uint64, ctypes.c_int32,
-                ctypes.POINTER(ctypes.c_uint64)]
-        except AttributeError:  # pragma: no cover - older .so
-            pass
-        try:
-            lib.nstpu_signature.restype = ctypes.c_char_p
-        except AttributeError:  # pragma: no cover - older .so
-            pass
-        try:
-            lib.nstpu_buf_register.argtypes = [ctypes.c_uint64,
-                                               ctypes.c_void_p,
-                                               ctypes.c_uint64]
-            lib.nstpu_buf_unregister.argtypes = [ctypes.c_uint64,
-                                                 ctypes.c_int32]
-        except AttributeError:  # pragma: no cover - older .so
-            pass
-        try:
-            lib.nstpu_engine_lat_hist.argtypes = [
-                ctypes.c_uint64, ctypes.POINTER(ctypes.c_uint64),
-                ctypes.c_int32]
-        except AttributeError:  # pragma: no cover - older .so
-            pass
-        try:  # API v2: lane topology + per-member hist/occupancy
-            lib.nstpu_engine_nlanes.argtypes = [ctypes.c_uint64]
-            lib.nstpu_engine_lane_pin.argtypes = [
-                ctypes.c_uint64, ctypes.c_int32,
-                ctypes.POINTER(ctypes.c_int32), ctypes.c_int32]
-            lib.nstpu_engine_member_lat_hist.argtypes = [
-                ctypes.c_uint64, ctypes.c_int32,
-                ctypes.POINTER(ctypes.c_uint64), ctypes.c_int32]
-            lib.nstpu_engine_member_occ.argtypes = [
-                ctypes.c_uint64, ctypes.c_int32,
-                ctypes.POINTER(ctypes.c_uint64)]
-        except AttributeError:  # pragma: no cover - older .so
-            pass
-        try:  # API v3: flight-recorder event ring
-            lib.nstpu_engine_trace.argtypes = [ctypes.c_uint64, ctypes.c_int]
-            lib.nstpu_engine_trace_drain.argtypes = [
-                ctypes.c_uint64, ctypes.POINTER(_TraceEvent), ctypes.c_int32]
-        except AttributeError:  # pragma: no cover - older .so
-            pass
-        try:  # API v4: NVMe passthrough rung
-            lib.nstpu_engine_create3.restype = ctypes.c_uint64
-            lib.nstpu_engine_create3.argtypes = [ctypes.c_int, ctypes.c_int,
-                                                 ctypes.c_int,
-                                                 ctypes.c_char_p]
-            lib.nstpu_passthru_probe.argtypes = [ctypes.c_char_p]
-            lib.nstpu_engine_passthru_reason.argtypes = [ctypes.c_uint64]
-        except AttributeError:  # pragma: no cover - older .so
-            pass
+        lib.nstpu_engine_member_stats.argtypes = [
+            ctypes.c_uint64, ctypes.c_int32,
+            ctypes.POINTER(ctypes.c_uint64)]
+        lib.nstpu_signature.restype = ctypes.c_char_p
+        lib.nstpu_buf_register.argtypes = [ctypes.c_uint64,
+                                           ctypes.c_void_p,
+                                           ctypes.c_uint64]
+        lib.nstpu_buf_unregister.argtypes = [ctypes.c_uint64,
+                                             ctypes.c_int32]
+        lib.nstpu_engine_lat_hist.argtypes = [
+            ctypes.c_uint64, ctypes.POINTER(ctypes.c_uint64),
+            ctypes.c_int32]
+        lib.nstpu_engine_nlanes.argtypes = [ctypes.c_uint64]
+        lib.nstpu_engine_lane_pin.argtypes = [
+            ctypes.c_uint64, ctypes.c_int32,
+            ctypes.POINTER(ctypes.c_int32), ctypes.c_int32]
+        lib.nstpu_engine_member_lat_hist.argtypes = [
+            ctypes.c_uint64, ctypes.c_int32,
+            ctypes.POINTER(ctypes.c_uint64), ctypes.c_int32]
+        lib.nstpu_engine_member_occ.argtypes = [
+            ctypes.c_uint64, ctypes.c_int32,
+            ctypes.POINTER(ctypes.c_uint64)]
+        lib.nstpu_engine_trace.argtypes = [ctypes.c_uint64, ctypes.c_int]
+        lib.nstpu_engine_trace_drain.argtypes = [
+            ctypes.c_uint64, ctypes.POINTER(_TraceEvent), ctypes.c_int32]
+        lib.nstpu_engine_create3.restype = ctypes.c_uint64
+        lib.nstpu_engine_create3.argtypes = [ctypes.c_int, ctypes.c_int,
+                                             ctypes.c_int,
+                                             ctypes.c_char_p]
+        lib.nstpu_passthru_probe.argtypes = [ctypes.c_char_p]
+        lib.nstpu_engine_passthru_reason.argtypes = [ctypes.c_uint64]
         _lib = lib
         return _lib
 
@@ -204,12 +193,7 @@ def native_api_version() -> Optional[int]:
     """ABI version the loaded .so reports, or None when unavailable.
     Compared against :data:`API_VERSION` by strom_check's abi probe."""
     lib = _load()
-    if lib is None:
-        return None
-    try:
-        return int(lib.nstpu_engine_version())
-    except Exception:
-        return None
+    return None if lib is None else int(lib.nstpu_engine_version())
 
 
 def passthru_probe(dev_path: Optional[str]) -> Optional[int]:
@@ -217,10 +201,9 @@ def passthru_probe(dev_path: Optional[str]) -> Optional[int]:
 
     Returns the device's LBA shift (>= 9) when every rung of the probe
     passes, a negative ``NSTPU_PASSTHRU_*`` refusal reason when it does
-    not (see :data:`PASSTHRU_REASONS`), or None when the .so is missing
-    or predates API v4."""
+    not (see :data:`PASSTHRU_REASONS`), or None when the .so is missing."""
     lib = _load()
-    if lib is None or not hasattr(lib, "nstpu_passthru_probe"):
+    if lib is None:
         return None
     dev = dev_path.encode() if dev_path else None
     return int(lib.nstpu_passthru_probe(dev))
@@ -230,12 +213,7 @@ def native_signature() -> Optional[str]:
     """Build signature of the loaded .so (the /proc/nvme-strom
     version-read analog), or None when the native engine is unavailable."""
     lib = _load()
-    if lib is None:
-        return None
-    try:
-        return lib.nstpu_signature().decode()
-    except AttributeError:
-        return f"strom_tpu native engine api v{lib.nstpu_engine_version()}"
+    return None if lib is None else lib.nstpu_signature().decode()
 
 
 class NativeEngine:
@@ -250,13 +228,12 @@ class NativeEngine:
                 "threadpool": BACKEND_THREADPOOL,
                 "nvme_passthru": BACKEND_NVME_PASSTHRU}[backend]
         self._lib = lib
-        if hasattr(lib, "nstpu_engine_create3") and (
-                passthru_dev or want in (BACKEND_AUTO,
+        if (passthru_dev or want in (BACKEND_AUTO,
                                          BACKEND_NVME_PASSTHRU)):
             self._h = lib.nstpu_engine_create3(
                 want, queue_depth, rings,
                 passthru_dev.encode() if passthru_dev else None)
-        elif rings > 0 and hasattr(lib, "nstpu_engine_create2"):
+        elif rings > 0:
             self._h = lib.nstpu_engine_create2(want, queue_depth, rings)
         else:
             self._h = lib.nstpu_engine_create(want, queue_depth)
@@ -308,37 +285,31 @@ class NativeEngine:
         slot, or None when unsupported/full — callers just lose the fast
         path, never correctness.  The region must stay mapped until
         :meth:`buf_unregister` (or engine close)."""
-        if not hasattr(self._lib, "nstpu_buf_register"):
-            return None
         slot = self._lib.nstpu_buf_register(self._h, ctypes.c_void_p(addr),
                                             ctypes.c_uint64(length))
         return slot if slot >= 0 else None
 
     def buf_unregister(self, slot: int) -> None:
-        if hasattr(self._lib, "nstpu_buf_unregister") and self._h:
+        if self._h:
             self._lib.nstpu_buf_unregister(self._h, slot)
 
     def passthru_reason(self) -> Optional[int]:
         """Why the passthrough rung is (in)active: 0 when nvme_passthru IS
         the backend, a negative ``NSTPU_PASSTHRU_*`` refusal reason when
-        the ladder fell past it, or None on a pre-v4 .so."""
-        if not hasattr(self._lib, "nstpu_engine_passthru_reason"):
-            return None
+        the ladder fell past it."""
         return int(self._lib.nstpu_engine_passthru_reason(self._h))
 
     def nlanes(self) -> int:
-        """Lane (queue-pair) count of this engine, 1 on an older .so."""
-        if not hasattr(self._lib, "nstpu_engine_nlanes"):
-            return 1
+        """Lane (queue-pair) count of this engine."""
         n = self._lib.nstpu_engine_nlanes(self._h)
         return n if n > 0 else 1
 
     def lane_pin(self, lane: int, cpus: Sequence[int]) -> bool:
         """Pin one lane's reaper/worker threads to the given CPUs (the
-        NUMA-locality lever).  Returns True on success; False covers an
-        older .so, a bad lane, or a kernel that refuses the affinity —
-        callers lose only locality, never correctness."""
-        if not hasattr(self._lib, "nstpu_engine_lane_pin") or not cpus:
+        NUMA-locality lever).  Returns True on success; False covers a
+        bad lane or a kernel that refuses the affinity — callers lose
+        only locality, never correctness."""
+        if not cpus:
             return False
         arr = (ctypes.c_int32 * len(cpus))(*cpus)
         return self._lib.nstpu_engine_lane_pin(self._h, lane, arr,
@@ -392,9 +363,7 @@ class NativeEngine:
 
     def lat_hist(self) -> Optional[List[int]]:
         """Absolute per-request service-latency histogram (log2-ns
-        buckets), or None on an older .so without the export."""
-        if not hasattr(self._lib, "nstpu_engine_lat_hist"):
-            return None
+        buckets), or None when the engine refuses the export."""
         out = (ctypes.c_uint64 * LAT_HIST_BUCKETS)()
         n = self._lib.nstpu_engine_lat_hist(self._h, out, LAT_HIST_BUCKETS)
         if n < 0:
@@ -413,9 +382,7 @@ class NativeEngine:
             return [c - p for c, p in zip(cur, prev)]
 
     def member_lat_hist(self, member: int) -> Optional[List[int]]:
-        """Absolute per-member latency histogram, or None (older .so)."""
-        if not hasattr(self._lib, "nstpu_engine_member_lat_hist"):
-            return None
+        """Absolute per-member latency histogram, or None (bad member)."""
         out = (ctypes.c_uint64 * LAT_HIST_BUCKETS)()
         n = self._lib.nstpu_engine_member_lat_hist(self._h, member, out,
                                                    LAT_HIST_BUCKETS)
@@ -428,8 +395,6 @@ class NativeEngine:
         """Per-member histogram bucket deltas since the previous call
         (serialized like stats_delta).  Members with no new completions
         are omitted."""
-        if not hasattr(self._lib, "nstpu_engine_member_lat_hist"):
-            return {}
         with self._stats_lock:
             out: Dict[int, List[int]] = {}
             for m in sorted({min(max(m, 0), MAX_MEMBERS - 1)
@@ -447,9 +412,7 @@ class NativeEngine:
 
     def member_occ(self, member: int) -> Optional[Tuple[int, int]]:
         """Monotonic (occ_integral_ns, occ_busy_ns) for one member, or
-        None on an older .so."""
-        if not hasattr(self._lib, "nstpu_engine_member_occ"):
-            return None
+        None for a bad member."""
         out = (ctypes.c_uint64 * 2)()
         if self._lib.nstpu_engine_member_occ(self._h, member, out) < 0:
             return None
@@ -459,8 +422,6 @@ class NativeEngine:
                          ) -> Dict[int, Tuple[int, int]]:
         """Per-member (occ_integral_ns, occ_busy_ns) deltas since the
         previous call (serialized like stats_delta)."""
-        if not hasattr(self._lib, "nstpu_engine_member_occ"):
-            return {}
         with self._stats_lock:
             out: Dict[int, Tuple[int, int]] = {}
             for m in sorted({min(max(m, 0), MAX_MEMBERS - 1)
@@ -476,17 +437,13 @@ class NativeEngine:
 
     def trace_enable(self, on: bool = True) -> bool:
         """Turn the native flight-recorder ring on/off.  Returns the
-        PREVIOUS state; False also covers an older .so without the export
-        (callers lose only native spans, never correctness)."""
-        if not hasattr(self._lib, "nstpu_engine_trace"):
-            return False
+        PREVIOUS state."""
         return self._lib.nstpu_engine_trace(self._h, 1 if on else 0) > 0
 
     def trace_drain(self, cap: int = TRACE_RING_EVENTS) -> List[Dict[str, int]]:
-        """Drain recorded device events (oldest first per lane); [] on an
-        older .so.  Each dict carries the measured submit->complete window
+        """Drain recorded device events (oldest first per lane).  Each dict carries the measured submit->complete window
         in CLOCK_MONOTONIC ns — the same domain as time.monotonic_ns()."""
-        if not hasattr(self._lib, "nstpu_engine_trace_drain") or not self._h:
+        if not self._h:
             return []
         out = (_TraceEvent * cap)()
         n = self._lib.nstpu_engine_trace_drain(self._h, out, cap)
@@ -502,8 +459,6 @@ class NativeEngine:
         for the given member indices.  Serialized like stats_delta.
         Indices clamp to the engine's member table the same way submit()
         clamps them, so callers may pass raw source indices."""
-        if not hasattr(self._lib, "nstpu_engine_member_stats"):
-            return {}  # older .so without per-member accounting
         with self._stats_lock:
             out: Dict[int, Tuple[int, int, int]] = {}
             for m in sorted({min(max(m, 0), MAX_MEMBERS - 1)

@@ -108,6 +108,18 @@ def _probe_odirect(path: str) -> bool:
     return True
 
 
+def _pread_exact(fd: int, dest: memoryview, offset: int) -> None:
+    """Fill *dest* from *fd* at *offset*; a read may return short (a
+    network filesystem caps one reply), so loop until EOF."""
+    done = 0
+    while done < len(dest):
+        n = os.preadv(fd, [dest[done:]], offset + done)
+        if n <= 0:
+            raise StromError(_errno.EIO, f"short buffered read {done} != "
+                                         f"{len(dest)}")
+        done += n
+
+
 def check_file(path: str, *, dma_max_size: Optional[int] = None,
                strict: Optional[bool] = None,
                sysfs_root: str = "/sys") -> FileInfo:
@@ -582,9 +594,7 @@ class PlainSource(Source):
         return out
 
     def read_buffered(self, offset: int, dest: memoryview) -> None:
-        n = os.preadv(self._m.fd_buffered, [dest], offset)
-        if n != len(dest):
-            raise StromError(_errno.EIO, f"short buffered read {n} != {len(dest)}")
+        _pread_exact(self._m.fd_buffered, dest, offset)
 
     def read_member_buffered(self, member: int, file_off: int, dest: memoryview) -> None:
         n = os.preadv(self._m.fd_buffered, [dest], file_off)
@@ -643,10 +653,8 @@ class SegmentedSource(Source):
     def read_buffered(self, offset: int, dest: memoryview) -> None:
         done = 0
         for e in self.extents(offset, len(dest)):
-            n = os.preadv(self.members[e.member].fd_buffered,
-                          [dest[done:done + e.length]], e.file_off)
-            if n != e.length:
-                raise StromError(_errno.EIO, "short buffered read")
+            _pread_exact(self.members[e.member].fd_buffered,
+                         dest[done:done + e.length], e.file_off)
             done += e.length
 
     def read_member_buffered(self, member: int, file_off: int, dest: memoryview) -> None:
@@ -702,10 +710,8 @@ class StripedSource(Source):
     def read_buffered(self, offset: int, dest: memoryview) -> None:
         for e in self.extents(offset, len(dest)):
             rel = e.logical_off - offset
-            n = os.preadv(self.members[e.member].fd_buffered,
-                          [dest[rel:rel + e.length]], e.file_off)
-            if n != e.length:
-                raise StromError(_errno.EIO, "short buffered read")
+            _pread_exact(self.members[e.member].fd_buffered,
+                         dest[rel:rel + e.length], e.file_off)
 
     def read_member_buffered(self, member: int, file_off: int, dest: memoryview) -> None:
         n = os.preadv(self.members[member].fd_buffered, [dest], file_off)
@@ -2314,19 +2320,30 @@ class Session:
 
             # --- write-back copies (synchronous, like the in-ioctl memcpy;
             #     AFTER direct submission so the device queue fills first
-            #     and these page-cache copies overlap in-flight direct I/O)
-            for i, cid in enumerate(wb_ids):
-                slot = nr_ssd + i
-                base = cid * chunk_size
-                length = min(chunk_size, source.size - base)
-                target = wb_buffer if wb_buffer is not None else dest
-                off = (dest_offset if wb_buffer is None else 0) + slot * chunk_size
+            #     and these page-cache copies overlap in-flight direct I/O).
+            #     A run of file-consecutive chunks sits in consecutive
+            #     slots, so it is one read of up to coalesce_limit bytes:
+            #     a syscall per chunk of small chunks (the sharded loader's
+            #     8 KiB pages) overran the task deadline on a cached GiB file
+            run_max = max(chunk_size, int(config.get("coalesce_limit")))
+            target = wb_buffer if wb_buffer is not None else dest
+            r0 = 0
+            while r0 < len(wb_ids):
+                r1 = r0 + 1
+                while (r1 < len(wb_ids) and wb_ids[r1] == wb_ids[r1 - 1] + 1
+                       and (r1 - r0 + 1) * chunk_size <= run_max):
+                    r1 += 1
+                base = wb_ids[r0] * chunk_size
+                length = min((r1 - r0) * chunk_size, source.size - base)
+                off = ((dest_offset if wb_buffer is None else 0)
+                       + (nr_ssd + r0) * chunk_size)
                 tw0 = time.monotonic_ns()
                 source.read_buffered(base, target[off:off + length])
                 if _trace.active and task.trace_id:
                     _trace.span("writeback", tw0, time.monotonic_ns(),
                                 tid=task.trace_id, offset=base,
                                 length=length)
+                r0 = r1
 
             # --- residency-tier hit serving (tail-packed after the
             #     write-back slots): memcpy out of the pinned slab, no
@@ -3300,7 +3317,7 @@ class Session:
     def _drain_native_trace(self, eng=None) -> int:
         """Drain the native engine's per-lane trace ring into the flight
         recorder (device submit->complete windows, monotonic ns — same
-        clock as the Python spans).  No-op on older .so builds."""
+        clock as the Python spans)."""
         eng = eng if eng is not None else self._native
         if eng is None:
             return 0
@@ -3532,8 +3549,8 @@ class Session:
         # service-latency histograms: fold the native deltas and feed the
         # mean service time to the adaptive sizers (native requests never
         # pass through _do_request, so this is their only observation
-        # path).  Per-member histograms feed each member's own sizer; an
-        # older .so without them falls back to the global mean for all.
+        # path).  Per-member histograms feed each member's own sizer; a
+        # member with no histogram of its own falls back to the global mean.
         hd = eng.lat_hist_delta()
         if hd and any(hd):
             stats.merge_native_hist(hd)

@@ -177,7 +177,7 @@ class HbmRegistry:
             if n <= 0:
                 raise StromError(_errno.EINVAL, "buffer size must be positive")
             dev = device or jax.local_devices()[0]
-            arr = jax.device_put(jnp.zeros((n,), dtype=dtype), dev)
+            arr = jnp.zeros((n,), dtype=dtype, device=dev)
         with self._lock:
             handle = self._next
             self._next += 1

@@ -3,7 +3,7 @@
 The wire carries ``scan/colpack.py`` packed blocks (8KB pages holding
 ``rows_per_block`` rows each); this module expands them ON THE DEVICE and
 folds the filter + masked aggregate in the same fused dispatch, so the
-host->HBM link — the measured ceiling, BENCH_MATRIX ``h2d_peak`` — moves
+host->HBM link — where it is the ceiling — moves
 packed bytes while the query still sees logical rows.
 
 ``decode_block_words`` is deliberately built from nothing but slices,

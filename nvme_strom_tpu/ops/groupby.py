@@ -27,54 +27,23 @@ from .filter_xla import DEFAULT_SCHEMA, decode_pages
 __all__ = ["make_groupby_fn", "scan_groupby_step", "combine_groupby",
            "groupby_kernel_auto"]
 
-_measured_ratio_cache = None
-
-
-def _measured_groupby_ratio() -> float:
-    """Measured on-chip pallas/XLA GROUP BY ratio from BENCH_MATRIX
-    (``pallas_vs_xla_groupby``), falling back to the last recorded value
-    when the matrix is absent.  Cached per process — the file only
-    changes when ``make bench-matrix`` reruns."""
-    global _measured_ratio_cache
-    if _measured_ratio_cache is None:
-        import json
-        import os
-        path = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))),
-            "BENCH_MATRIX.json")
-        ratio = 0.851   # r4/r5 measurement; see groupby_kernel_routing
-        try:
-            with open(path) as f:
-                r = json.load(f).get("pallas_vs_xla_groupby")
-            if r:
-                ratio = float(r)
-        except (OSError, ValueError, TypeError):
-            pass
-        _measured_ratio_cache = ratio
-    return _measured_ratio_cache
-
-
 def groupby_kernel_auto(agg_kind: str):
-    """Measured kernel routing for on-chip GROUP BY: ``(kernel, why)``.
+    """Kernel routing for on-chip GROUP BY: ``(kernel, why)``.
 
-    The crossover is the BENCH_MATRIX same-batch ratio itself: the
-    one-hot pallas kernel pays an SMEM accumulator round-trip per group
-    that the XLA MXU contraction amortizes, and for FLOAT accumulation
-    that overhead is where the measured ratio lands below 1.0
-    (``pallas_vs_xla_groupby`` = 0.851 across r4/r5 sessions) — so any
-    measured ratio < 1.0 routes float aggregation to XLA.  Integer
-    accumulation keeps the pallas win (``pallas_vs_xla`` = 4.263 on the
-    same host) and stays on the hand kernel."""
+    Integer accumulation stays on the Pallas kernel.  Float accumulation
+    pays an SMEM accumulator round-trip per group that the XLA MXU
+    contraction amortizes, so it takes Pallas only where this device's
+    measured speedup (``device_figures``) is at least 1.0."""
     if agg_kind != "f":
-        return "pallas", "int accumulators keep the measured pallas win"
-    ratio = _measured_groupby_ratio()
+        return "pallas", "int accumulators stay on the pallas kernel"
+    from ..device_figures import device_figures
+    fig = device_figures()
+    ratio = fig.groupby_f32_pallas_speedup
     if ratio < 1.0:
-        return "xla", (f"float aggregation routes to XLA (measured "
-                       f"pallas_vs_xla_groupby = {ratio:g} < 1.0 — the "
-                       f"pallas GROUP BY earns its keep on int "
-                       f"accumulators only)")
-    return "pallas", (f"measured pallas_vs_xla_groupby = {ratio:g} "
-                      f">= 1.0: the hand kernel wins this host")
+        return "xla", (f"float aggregation routes to XLA (pallas "
+                       f"speedup {ratio:g} < 1.0; {fig.source})")
+    return "pallas", (f"float aggregation stays on pallas (speedup "
+                      f"{ratio:g} >= 1.0; {fig.source})")
 
 
 def combine_groupby(acc: dict, out: dict) -> dict:

@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.filter_xla import DEFAULT_SCHEMA, decode_pages
 from ..scan.heap import HeapSchema
-from ._compat import shard_map
+from jax import shard_map
 from .mesh import make_scan_mesh, pages_sharding
 
 __all__ = ["make_distributed_scan_step", "shard_pages"]

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 from .mesh import make_scan_mesh
 
 __all__ = ["make_bucket_exchange", "bucket_dispatch"]

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 from ..api import StromError
 
 from ..ops.filter_xla import decode_pages, global_row_positions

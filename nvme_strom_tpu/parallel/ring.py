@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 from ..config import config
 from ..ops.filter_xla import DEFAULT_SCHEMA, decode_pages
 from ..scan.heap import HeapSchema
@@ -41,14 +41,8 @@ __all__ = ["make_ring_multi_query_scan", "ring_scan_source",
 
 def _mark_varying(x, axis: str):
     """Mark *x* as axis-varying so scan carries type-match a rotating
-    (varying) block.  jax grew ``pcast`` (newest), then ``pvary``; on
-    versions with neither the carry types already unify without an
-    explicit annotation, so identity is the correct fallback."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis)
-    return x
+    (varying) block."""
+    return jax.lax.pcast(x, axis, to="varying")
 
 
 # ---------------------------------------------------------------------------
@@ -56,7 +50,7 @@ def _mark_varying(x, axis: str):
 # shard_map'ed body.  Two transports behind one call:
 #
 # * ``pallas`` — a Pallas kernel built on ``pltpu.make_async_remote_copy``
-#   (SNIPPETS.md [2] shape): src/dst refs live in TPUMemorySpace.ANY (HBM —
+#   (SNIPPETS.md [2] shape): src/dst refs live in ``pl.ANY`` (HBM —
 #   the landing buffers the sharded loader adopts are HBM-resident), a
 #   paired send/recv DMA-semaphore pledge fences the device-to-device copy,
 #   and the neighbour is addressed by LOGICAL device id computed from the
@@ -101,8 +95,8 @@ def _pallas_permute_step(block, axis: str, ring: int):
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
     )
     return pl.pallas_call(
@@ -172,7 +166,7 @@ def ring_all_gather(arr, mesh: Mesh, *, axis: str = "dp",
         _local, mesh=mesh,
         in_specs=P(axis, *n_spec),
         out_specs=P(*((None,) + n_spec)),
-        check_rep=False))
+        check_vma=False))
     _ring_jit_cache[key] = fn
     return fn(arr)
 

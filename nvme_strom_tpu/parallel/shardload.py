@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import deque
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -41,7 +42,7 @@ from ..hbm.staging import safe_device_put
 from ..scan.heap import PAGE_SIZE
 from ..stats import stats
 from ..trace import recorder as _trace
-from ._compat import shard_map
+from jax import shard_map
 from .ring import _mark_varying, permute_backend, ring_all_gather, \
     ring_permute_step
 
@@ -58,27 +59,53 @@ def shard_ownership(source: Source, n_hosts: int,
     return plan_shard_ownership(source, range(n_chunks), chunk_size, n_hosts)
 
 
+#: engine tasks a host keeps in flight while reading its shard
+_TASK_WINDOW = 4
+
+
 def _read_host_shard(host: int, ids: List[int], source: Source,
                      session: Optional[Session]) -> np.ndarray:
     """One host's local read: submit the owned chunk grid through this
     host's OWN engine session, wait, restore caller order.  Returns an
     owned (len(ids), PAGE_SIZE) array (copied out before the pinned
-    buffer unmaps)."""
+    buffer unmaps).
+
+    The grid goes out as one engine task per ``config chunk_size`` of
+    pages, ``_TASK_WINDOW`` in flight: the task deadline
+    (``task_deadline_s``) bounds one task, and a single task for a whole
+    GiB shard of 8 KiB pages overran it on a page-cached heap (PR 21)."""
     if not ids:
         return np.empty((0, PAGE_SIZE), np.uint8)
     own = session is None
     sess = session or Session()
     ts = time.monotonic_ns()
+    per_task = max(1, int(config.get("chunk_size")) // PAGE_SIZE)
+    host_rows = np.empty((len(ids), PAGE_SIZE), np.uint8)
     try:
         nbytes = len(ids) * PAGE_SIZE
         handle, buf = sess.alloc_dma_buffer(nbytes)
-        try:
-            res = sess.memcpy_ssd2ram(source, handle, ids, PAGE_SIZE)
+        view = np.frombuffer(buf.view()[:nbytes], np.uint8)
+        inflight: deque = deque()
+
+        def retire() -> None:
+            lo, part, res = inflight.popleft()
             sess.memcpy_wait(res.dma_task_id)
-            host_rows = np.array(reorder_chunks(
-                np.frombuffer(buf.view()[:nbytes], np.uint8),
-                PAGE_SIZE, res.chunk_ids, ids)).reshape(len(ids), PAGE_SIZE)
+            raw = view[lo * PAGE_SIZE:(lo + len(part)) * PAGE_SIZE]
+            host_rows[lo:lo + len(part)] = reorder_chunks(
+                raw, PAGE_SIZE, res.chunk_ids, part).reshape(-1, PAGE_SIZE)
+
+        try:
+            for lo in range(0, len(ids), per_task):
+                part = ids[lo:lo + per_task]
+                inflight.append((lo, part, sess.memcpy_ssd2ram(
+                    source, handle, part, PAGE_SIZE,
+                    dest_offset=lo * PAGE_SIZE)))
+                if len(inflight) >= _TASK_WINDOW:
+                    retire()
+            while inflight:
+                retire()
         finally:
+            del view
             sess.unmap_buffer(handle)
             buf.close()
     finally:
@@ -140,7 +167,7 @@ def _make_redistribute(mesh: Mesh, axis: str, rows_max: int,
     fn = jax.jit(shard_map(
         _local, mesh=mesh,
         in_specs=(P(axis, None), P(axis)),
-        out_specs=P(axis, None), check_rep=False))
+        out_specs=P(axis, None), check_vma=False))
     _redistribute_cache[key] = fn
     return fn
 

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 from .mesh import make_scan_mesh
 
 __all__ = ["make_distributed_sort", "make_distributed_distinct",

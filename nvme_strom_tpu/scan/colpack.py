@@ -1,8 +1,7 @@
 """Packed columnar extents: the compressed wire format for compute pushdown.
 
-The h2d link is the hard ceiling of every tier built so far (BENCH_MATRIX:
-``h2d_peak`` 1.06 GB/s against ``raw_seq_read`` 3.36 GB/s), and the way past
-a transport ceiling is to move fewer, denser bytes and expand them on-chip
+Where the h2d link is the ceiling of a scan, the way past a transport
+ceiling is to move fewer, denser bytes and expand them on-chip
 (ROADMAP item 5; AXI4MLIR's host<->accelerator transfer codegen is the
 model for the per-column host-vs-chip expansion decision).  This module is
 the *format* half: a ``<table>.cpk`` sidecar holding the same rows as the

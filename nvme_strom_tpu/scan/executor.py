@@ -326,9 +326,9 @@ class TableScanner:
         ``dispatch_coalesce=K`` folds K fenced device batches inside ONE
         jitted call (filter_fn traced K times, results tree-summed or
         *combine*-folded on device) instead of dispatching per batch —
-        on a high-latency backend each dispatch is a full tunnel round
-        trip, and per-16MB dispatches cap a streamed scan far below the
-        transport ceiling.  OPT-IN because it traces ``filter_fn`` and
+        on a high-latency backend each dispatch is a full round trip,
+        and per-16MB dispatches cap a streamed scan below the transport
+        ceiling.  OPT-IN because it traces ``filter_fn`` and
         *combine*: both must be jit-safe (the query kernels are; host-
         side collect closures are not).  None/1 = per-batch dispatch.
         Pass a prebuilt (warmable) :class:`CoalescedFold` to share one

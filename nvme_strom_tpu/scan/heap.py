@@ -357,16 +357,28 @@ def build_pages(columns: Sequence[np.ndarray], schema: HeapSchema, *,
     return out
 
 
+#: pages built per slab by build_heap_file: bounds its host memory to
+#: ~512 MiB of pages whatever the table size
+_SLAB_PAGES = 1 << 16
+
+
 def build_heap_file(path: str, columns: Sequence[np.ndarray],
                     schema: HeapSchema, *,
                     visibility: Optional[np.ndarray] = None,
                     nulls: Optional[dict] = None) -> int:
-    """Write a heap file; returns number of pages."""
-    pages = build_pages(columns, schema, visibility=visibility,
-                        nulls=nulls)
+    """Write a heap file, slab by slab; returns number of pages."""
+    t = schema.tuples_per_page
+    n_rows = len(columns[0])
+    n_pages = max((n_rows + t - 1) // t, 1)
     with open(path, "wb") as f:
-        f.write(pages.tobytes())
-    return len(pages)
+        for p0 in range(0, n_pages, _SLAB_PAGES):
+            rows = slice(p0 * t, (p0 + _SLAB_PAGES) * t)
+            f.write(build_pages(
+                [c[rows] for c in columns], schema,
+                visibility=None if visibility is None else visibility[rows],
+                nulls={c: m[rows] for c, m in (nulls or {}).items()},
+                start_page_id=p0))
+    return n_pages
 
 
 def validate_heap_header(path: str, schema: HeapSchema) -> None:

@@ -86,6 +86,8 @@ def _query_worker(spec: dict, cursor_name: str, lock, out_q) -> None:
         # test hook: die like an OOM-kill/segfault — no report, no
         # cleanup — so the leader's death detection is testable in CI
         os._exit(42)
+    # workers compute on the host CPU (EXPLAIN and --workers say so): a
+    # chip belongs to one process, and the leader may hold it
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     cursor = None
     try:

@@ -183,53 +183,32 @@ def cost_vfs_scan(n_pages: int, n_tuples: int, *, workers: int = 0) -> ScanCost:
 # VMEM; when the SSD is the ceiling instead, host expansion already
 # captures the win and keeps the decode off the accelerator.
 
-# round-4 measured fallbacks, used when BENCH_MATRIX.json is absent and
-# no override/live sample exists
-_H2D_GBPS_DEFAULT = 1.06
-_SSD_GBPS_DEFAULT = 3.36
 
-_bench_rates_cache: Optional[Tuple[Optional[float], Optional[float]]] = None
-
-
-def _bench_matrix_rates() -> Tuple[Optional[float], Optional[float]]:
-    """(h2d_peak, raw_seq_read) GB/s from the repo's BENCH_MATRIX.json,
-    (None, None) when absent/unreadable.  Cached: the file only changes
-    when `make bench-matrix` reruns."""
-    global _bench_rates_cache
-    if _bench_rates_cache is not None:
-        return _bench_rates_cache
-    import json
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))), "BENCH_MATRIX.json")
-    h2d = ssd = None
-    try:
-        with open(path) as f:
-            d = json.load(f)
-        s = d.get("summary", d)
-        h2d = float(s.get("h2d_peak")) if s.get("h2d_peak") else None
-        ssd = float(s.get("raw_seq_read")) if s.get("raw_seq_read") else None
-    except (OSError, ValueError, TypeError):
-        pass
-    _bench_rates_cache = (h2d, ssd)
-    return _bench_rates_cache
+def _live_ssd_gbps() -> Optional[float]:
+    """The host disk's rate as the engine saw it: bytes of direct reads
+    over the time the device queue was busy (None before any direct
+    read in this process)."""
+    from ..stats import stats
+    nbytes = stats._c.get("total_dma_length", 0)
+    busy_ns = stats._c.get("occ_busy_ns", 0)
+    if nbytes <= 0 or busy_ns <= 0:
+        return None
+    return nbytes / busy_ns * (1e9 / (1 << 30))
 
 
-def transport_rates() -> Tuple[float, float]:
+def transport_rates() -> Tuple[float, Optional[float]]:
     """(h2d_gbps, ssd_gbps) the pushdown decision runs on.
 
-    h2d precedence: config override > live H2D rate meter (fed by
-    transfer-bound scan fences) > BENCH_MATRIX calibration > measured
-    default.  ssd precedence is the same minus the live meter (the scan
-    path has no clean SSD-only probe)."""
+    h2d: config override > live H2D rate meter (fed by transfer-bound
+    scan fences) > this device kind's figure (``device_figures``; an
+    unknown kind raises).  ssd: config override > live meter of the
+    engine's direct reads, else None (unknown)."""
     h2d = float(config.get("pushdown_h2d_gbps"))
-    ssd = float(config.get("pushdown_ssd_gbps"))
-    bh2d, bssd = _bench_matrix_rates()
+    ssd = float(config.get("pushdown_ssd_gbps")) or _live_ssd_gbps()
     if not h2d:
+        from ..device_figures import device_figures
         from ..hbm.staging import h2d_meter
-        live = h2d_meter.observed_gbps()
-        h2d = live if live else (bh2d or _H2D_GBPS_DEFAULT)
-    if not ssd:
-        ssd = bssd or _SSD_GBPS_DEFAULT
+        h2d = h2d_meter.observed_gbps() or device_figures().h2d_gbps
     return h2d, ssd
 
 
@@ -269,7 +248,9 @@ def decide_pushdown(meta, need_cols=None) -> PushdownDecision:
     thresh = float(config.get("pushdown_chip_ratio"))
     need = set(range(len(meta.cols))) if need_cols is None \
         else set(need_cols)
-    h2d_bound = ssd > h2d
+    # an unknown SSD rate counts as h2d-bound: shipping packed bytes is
+    # never more wire than shipping expanded ones
+    h2d_bound = ssd is None or ssd > h2d
     per_col, wire = [], 0
     for c, cm in enumerate(meta.cols):
         if c not in need:
@@ -299,9 +280,10 @@ def decide_pushdown(meta, need_cols=None) -> PushdownDecision:
         mode, why = "raw", (f"whole-scan codec ratio {scan_ratio:.2f}x "
                             f"below chip threshold {thresh:.2f}x")
     elif h2d_bound:
-        mode, why = "chip", (f"h2d is the ceiling ({h2d:.2f} vs SSD "
-                             f"{ssd:.2f} GB/s): packed bytes cross the "
-                             f"link, expand in VMEM")
+        vs = f"{ssd:.2f} GB/s" if ssd is not None else "unknown"
+        mode, why = "chip", (f"h2d is the ceiling ({h2d:.2f} GB/s vs SSD "
+                             f"{vs}): packed bytes cross the link, "
+                             f"expand in VMEM")
     else:
         mode, why = "host", (f"SSD is the ceiling ({ssd:.2f} vs h2d "
                              f"{h2d:.2f} GB/s): packed off disk, "

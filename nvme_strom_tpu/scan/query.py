@@ -1256,8 +1256,7 @@ class Query:
                            "4-byte non-null layout)")
         if self._op == "aggregate":
             if on_tpu:
-                return "pallas", "single-pass SMEM-accumulator kernel " \
-                                 "(bench: pallas_vs_xla > 1 on chip)"
+                return "pallas", "single-pass SMEM-accumulator kernel"
             return "xla", "non-TPU backend: interpret-mode pallas would " \
                           "be pure overhead"
         if self._op == "group_by":
@@ -1276,20 +1275,19 @@ class Query:
                 # dtypes Mosaic cannot hold in SMEM on real hardware
                 return "xla", "x64 accumulators (i64/f64) exceed the " \
                               "pallas kernel's SMEM dtype support"
+            if g > _PALLAS_MAX_GROUPS:
+                return "xla", f"G={g} exceeds the pallas unroll bound"
+            if not on_tpu:
+                return "xla", "non-TPU backend"
             from ..ops.groupby import _check_agg_cols as _cac
             from ..ops.groupby import groupby_kernel_auto
-            # measured routing decision (VERDICT r4 weak #4 / next #8):
-            # the auto-selector keys on BENCH_MATRIX's live
-            # pallas_vs_xla_groupby ratio, crossover at 1.0
+            # per-device routing decision (device_figures, keyed by
+            # device_kind), crossover at speedup 1.0
             gk, gwhy = groupby_kernel_auto(_cac(self.schema, agg)[1].kind)
             if gk == "xla":
                 return "xla", gwhy
-            if on_tpu and g <= _PALLAS_MAX_GROUPS:
-                return "pallas", f"G={g} within the static-unroll bound " \
-                                 f"({_PALLAS_MAX_GROUPS})"
-            return "xla", (f"G={g} exceeds the pallas unroll bound"
-                           if g > _PALLAS_MAX_GROUPS
-                           else "non-TPU backend")
+            return "pallas", f"G={g} within the static-unroll bound " \
+                             f"({_PALLAS_MAX_GROUPS}); {gwhy}"
         if self._op in ("order_by", "count_distinct", "quantiles"):
             return "xla", ("distributed sample sort (splitter election + "
                            "all_to_all)" if mode == "mesh"
@@ -1484,7 +1482,8 @@ class Query:
                 f"; parallel: {self._workers} worker processes claim "
                 f"chunks from ONE shared cursor (per-worker Sessions, "
                 f"partials fold on the leader; cost divisor "
-                f"{_parallel_divisor(self._workers):.1f})")
+                f"{_parallel_divisor(self._workers):.1f}); the workers "
+                f"compute on the host CPU, not the accelerator")
         if self._group_cols is not None:
             plan = dataclasses.replace(
                 plan, reason=plan.reason +

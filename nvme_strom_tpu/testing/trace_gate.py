@@ -3,7 +3,7 @@
 The flight recorder's contract is near-zero cost when off (one branch per
 event site) and production-safe when sampling (``trace_policy=sampled``,
 default 1% of tasks).  This gate holds the second half: it runs the
-bench-smoke workload under ``trace_policy=off`` and ``sampled`` in
+64MB direct-read workload under ``trace_policy=off`` and ``sampled`` in
 alternating order (A/B/A/B — interleaving cancels thermal/page-cache
 drift that back-to-back blocks would alias onto one arm) and fails when
 the sampled median throughput drops more than ``STROM_TRACE_GATE_PCT``

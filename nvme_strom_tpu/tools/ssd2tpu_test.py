@@ -136,8 +136,8 @@ def main(argv=None) -> int:
     if args.loops < 1:
         ap.error("--loops must be >= 1")
 
-    from .common import apply_platform_env
-    apply_platform_env()
+    from .common import tool_startup
+    tool_startup()
     import jax
     import jax.numpy as jnp
     from ..hbm import StagingPipeline, registry
@@ -172,6 +172,9 @@ def main(argv=None) -> int:
         f"{len(paths)}-way stripe ({args.stripe_chunk >> 10}KB chunks)"
     print(f"file: {label} ({total_size / (1 << 20):.1f} MB)  "
           f"device: {dev}  numa: {info.numa_node_id}")
+    # the device the numbers below belong to, in one parseable line
+    print(f"platform: {dev.platform} kind: {dev.device_kind} "
+          f"count: {len(jax.devices())}")
     if args.backend:
         config.set("io_backend", args.backend)
     _drop()
@@ -198,7 +201,7 @@ def main(argv=None) -> int:
             # warmup: compile the landing kernels + first-touch the H2D path
             # with the run's real shapes, outside the timed region
             warm = jax.device_put(np.zeros(min(args.vfs, nbytes), np.uint8), dev)
-            _land(hbm, warm, 0, args.vfs)
+            _land(hbm, warm, 0)
             registry.get(handle).array.block_until_ready()
             for loop in range(args.loops):
                 _drop()
@@ -213,7 +216,7 @@ def main(argv=None) -> int:
                         src.read_buffered(off, memoryview(data))
                         part = jax.device_put(
                             np.frombuffer(data, dtype=np.uint8), dev)
-                        _land(hbm, part, off, args.vfs)
+                        _land(hbm, part, off)
                         off += n
                 registry.get(handle).array.block_until_ready()
                 dt = time.monotonic() - tl
@@ -259,12 +262,12 @@ def main(argv=None) -> int:
                     # is async and must never watch a refilling buffer
                     host = np.frombuffer(
                         dbufs[ridx][1].view()[:nb], dtype=np.uint8).copy()
-                    _land(hbm, jax.device_put(host, dev), off, seg)
+                    _land(hbm, jax.device_put(host, dev), off)
 
                 # warmup compiles the landing kernels with the run's shapes
                 warm = jax.device_put(np.zeros(min(seg, nbytes), np.uint8),
                                       dev)
-                _land(hbm, warm, 0, seg)
+                _land(hbm, warm, 0)
                 registry.get(handle).array.block_until_ready()
                 for loop in range(args.loops):
                     _drop()

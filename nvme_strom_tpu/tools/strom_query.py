@@ -252,7 +252,10 @@ def main(argv=None) -> int:
                     help="run the scan as N worker processes sharing "
                          "one cursor (the Gather analog; structured "
                          "filters and --sql predicates parallelize; "
-                         "exclusive with --mesh)")
+                         "exclusive with --mesh).  The workers compute "
+                         "on the HOST CPU (JAX_PLATFORMS=cpu unless the "
+                         "environment sets it): one process per chip, "
+                         "and the chip belongs to the leader")
     ap.add_argument("--mesh", action="store_true",
                     help="stream sharded over all devices (dp axis)")
     ap.add_argument("--sql", default=None, metavar="STATEMENT",
@@ -298,8 +301,8 @@ def main(argv=None) -> int:
     agg_cols = [int(c) for c in args.agg_cols.split(",")] \
         if args.agg_cols else None
 
-    from .common import apply_platform_env
-    apply_platform_env()
+    from .common import tool_startup
+    tool_startup()
     from ..scan.query import Query
     from .common import parse_size
     src = args.file[0] if len(args.file) == 1 else list(args.file)

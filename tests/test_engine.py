@@ -218,6 +218,35 @@ def test_dirty_pages_detected_via_kpageflags(tmp_path):
     assert dirty > 0.0
 
 
+@pytest.mark.parametrize("limit, want_reads", [(8 << 20, 3), (0, 9)])
+def test_writeback_reads_coalesce_by_run(tmp_data_file, limit, want_reads):
+    """Write-back copies of file-consecutive chunks are one read per run,
+    bounded by coalesce_limit: one read per 8 KiB page overran the task
+    deadline of a cached 4 GiB sharded load on the chip (PR 21)."""
+    config.set("coalesce_limit", limit)
+    page = 8 << 10
+    ids = [4, 5, 6, 7, 20, 9, 10, 11, 12]
+    src = FakeNvmeSource(tmp_data_file, force_cached_fraction=1.0)
+    reads = []
+    plain = src.read_buffered
+    src.read_buffered = lambda off, dest: (reads.append(len(dest)),
+                                           plain(off, dest))
+    try:
+        with Session() as sess:
+            handle, buf = sess.alloc_dma_buffer(len(ids) * page)
+            res = sess.memcpy_ssd2ram(src, handle, ids, page)
+            sess.memcpy_wait(res.dma_task_id)
+            data = bytes(buf.view()[:len(ids) * page])
+            sess.unmap_buffer(handle)
+    finally:
+        src.close()
+    assert res.nr_ram2dev == len(ids) and res.chunk_ids == ids
+    assert len(reads) == want_reads and sum(reads) == len(ids) * page
+    for slot, cid in enumerate(ids):
+        assert data[slot * page:(slot + 1) * page] == \
+            expected_bytes(cid * page, page)
+
+
 def test_cache_arbitration_off(tmp_data_file):
     config.set("cache_arbitration", False)
     src = FakeNvmeSource(tmp_data_file, force_cached_fraction=1.0)

@@ -30,6 +30,31 @@ def _drop_cache(path):
     os.close(fd)
 
 
+@pytest.mark.parametrize("change,stale", [
+    (None, False), ("strom_tpu.h", True), ("strom_engine.cc", True),
+    ("Makefile", True), ("no .so", True)])
+def test_native_build_staleness(tmp_path, monkeypatch, change, stale):
+    """The loader rebuilds a .so that is missing or older than any of
+    the csrc/ files it is built from — a copied-along build is not
+    trusted just because it exists."""
+    from nvme_strom_tpu import _native
+    csrc = tmp_path / "csrc"
+    csrc.mkdir()
+    so = tmp_path / "libstrom_tpu.so"
+    so.write_text("")
+    for f in _native._SOURCES:
+        (csrc / f).write_text("")
+        os.utime(csrc / f, (1000, 1000))
+    os.utime(so, (2000, 2000))
+    if change == "no .so":
+        so.unlink()
+    elif change:
+        os.utime(csrc / change, (3000, 3000))
+    monkeypatch.setattr(_native, "_SO", str(so))
+    monkeypatch.setattr(_native, "_CSRC", str(csrc))
+    assert _native._stale() is stale
+
+
 # ---------------------------------------------------------------------------
 # direct ABI
 # ---------------------------------------------------------------------------

@@ -54,6 +54,7 @@ def test_workers_explain_shows_plan(table):
     assert plan.workers == 3
     assert "workers=3" in str(plan)
     assert "cost divisor" in plan.reason
+    assert "compute on the host CPU" in plan.reason
     # the worker-aware cost model is LIVE: 3 workers cost less than 1
     serial = Query(path, schema).where_eq(2, 7).aggregate().explain()
     assert plan.cost_direct < serial.cost_direct

@@ -214,6 +214,46 @@ def test_planner_decision_flips_with_forced_rates(tmp_path):
     assert dec.mode == "chip" and "forced" in dec.reason
 
 
+@pytest.mark.parametrize("case", ["known", "unknown", "override"])
+def test_h2d_rate_comes_from_the_device_table(monkeypatch, case):
+    """With no live sample, the h2d rate is this device kind's figure; an
+    unknown kind is an error unless pushdown_h2d_gbps is set.  The SSD
+    rate never comes from the table."""
+    from nvme_strom_tpu import StromError
+    from nvme_strom_tpu import device_figures as df
+    from nvme_strom_tpu.hbm.staging import h2d_meter
+    from nvme_strom_tpu.scan import planner
+    monkeypatch.setattr(h2d_meter, "observed_gbps", lambda: None)
+    monkeypatch.setattr(planner, "_live_ssd_gbps", lambda: None)
+    if case != "known":
+        monkeypatch.setattr(df, "FIGURES", {})
+    if case == "override":
+        config.set("pushdown_h2d_gbps", 2.5)
+    if case == "unknown":
+        with pytest.raises(StromError, match="no planning figures"):
+            planner.transport_rates()
+        return
+    want = 2.5 if case == "override" else df.device_figures("cpu").h2d_gbps
+    assert planner.transport_rates() == (want, None)
+
+
+def test_device_table_names_its_sources():
+    from nvme_strom_tpu.device_figures import FIGURES
+    assert "PR 21" in FIGURES["TPU v5 lite"].source
+    assert "test setting" in FIGURES["cpu"].source
+
+
+@pytest.mark.parametrize("speedup,kernel", [(0.851, "xla"), (92.0, "pallas")])
+def test_float_groupby_routes_on_the_device_speedup(monkeypatch, speedup,
+                                                   kernel):
+    from nvme_strom_tpu import device_figures as df
+    from nvme_strom_tpu.ops.groupby import groupby_kernel_auto
+    monkeypatch.setitem(df.FIGURES, "cpu", df.DeviceFigures(
+        h2d_gbps=1.0, groupby_f32_pallas_speedup=speedup, source="t"))
+    assert groupby_kernel_auto("f")[0] == kernel
+    assert groupby_kernel_auto("i")[0] == "pallas"
+
+
 def test_planner_raw_when_codec_never_pays(tmp_path):
     """All-distinct data: whole-scan ratio below threshold -> raw, and
     the predicted wire bytes are the logical bytes."""

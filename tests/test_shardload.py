@@ -113,6 +113,34 @@ def test_multihost_load_identical_to_single_host(page_file):
             assert np.array_equal(np.asarray(out), ref), f"hosts={hosts}"
 
 
+@pytest.mark.parametrize("cached", [0.0, 1.0])
+def test_multihost_load_reads_in_bounded_tasks(page_file, monkeypatch,
+                                               cached):
+    """A host's shard goes out as one engine task per config chunk_size
+    of pages (the task deadline bounds one task; one task per GiB shard
+    overran it on a page-cached heap on the chip), on the direct and the
+    write-back path alike, and the bytes stay identical."""
+    from nvme_strom_tpu.parallel import load_pages_multihost
+    from nvme_strom_tpu.testing import FakeNvmeSource
+
+    config.set("chunk_size", 64 << 10)          # 8 pages per task
+    tasks = []
+    submit = Session.memcpy_ssd2ram
+
+    def counted(self, source, handle, ids, chunk_size, **kw):
+        res = submit(self, source, handle, ids, chunk_size, **kw)
+        tasks.append((len(ids), res.nr_ram2dev))
+        return res
+
+    monkeypatch.setattr(Session, "memcpy_ssd2ram", counted)
+    want = np.fromfile(page_file, np.uint8).reshape(N_PAGES, PAGE_SIZE)
+    with FakeNvmeSource(page_file, force_cached_fraction=cached) as src:
+        out = load_pages_multihost(src, _mesh(), hosts=2)
+    assert np.array_equal(np.asarray(out), want)
+    assert [n for n, _ in tasks] == [8] * 4
+    assert sum(r for _, r in tasks) == (N_PAGES if cached else 0)
+
+
 def test_multihost_load_striped_gather_and_spans(striped_pages):
     """Striped source, trace on: the gathered array equals the file
     bytes, one shard_load span fires per host, and the redistribution
