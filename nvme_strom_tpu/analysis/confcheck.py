@@ -52,14 +52,15 @@ def _registrations(project: Project) -> List[Tuple[SourceFile, int, str]]:
 
 
 def _literal_accesses(project: Project) -> Set[str]:
-    """Names passed as the literal first argument of any ``.get``/``.set``
-    call — the read/write sites the unread check accepts."""
+    """Names passed as the literal first argument of any ``.get``/``.set``/
+    ``.peek`` call — the read/write sites the unread check accepts."""
     got: Set[str] = set()
     for _src, tree in project.iter_trees():
         for node in ast.walk(tree):
             if (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
-                    and node.func.attr in ("get", "set") and node.args):
+                    and node.func.attr in ("get", "set", "peek")
+                    and node.args):
                 name = _str_const(node.args[0])
                 if name is not None:
                     got.add(name)

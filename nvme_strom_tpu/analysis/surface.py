@@ -131,7 +131,7 @@ def _check_mutators(project: Project, fields: Set[str],
             wanted = []
             if node.func.attr in _STATS_MUTATORS:
                 wanted = [name]
-            elif node.func.attr == "count_clock":
+            elif node.func.attr in ("count_clock", "stage"):
                 wanted = ["nr_" + name, "clk_" + name]
             for w in wanted:
                 if w not in fields:
@@ -261,12 +261,18 @@ def _collect_emissions(project: Project
     for src, tree in project.iter_trees():
         for node in ast.walk(tree):
             if not (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
-                    and node.func.attr in ("span", "instant") and node.args):
+                    and isinstance(node.func, ast.Attribute)):
                 continue
-            name = _str_const(node.args[0])
-            if name is not None:
-                out.append((src, node.lineno, name, node.func.attr))
+            if node.func.attr in ("span", "instant") and node.args:
+                name = _str_const(node.args[0])
+                if name is not None:
+                    out.append((src, node.lineno, name, node.func.attr))
+            elif node.func.attr == "stage":
+                # stats.stage(..., ring="<event>") records a ring span
+                for kw in node.keywords:
+                    name = _str_const(kw.value) if kw.arg == "ring" else None
+                    if name is not None:
+                        out.append((src, node.lineno, name, "span"))
     return out
 
 

@@ -390,6 +390,18 @@ STAT_FIELDS: Tuple[str, ...] = (
     "nr_tier_ram_demote",     # RAM victims dropped to the SSD-backed
     #                           tier (ARC capacity eviction)
     "nr_tier_ram_shed",       # RAM residents shed under memlock pressure
+    # caller-thread stages of the load and scan paths, count+clock pairs
+    # timed by stats.stage() (which also puts each entry on a running
+    # jax.profiler trace as a strom.<stage> span)
+    "nr_pagecache_copy", "clk_pagecache_copy",  # write-back page-cache
+    #                           copies, nested in ioctl_memcpy_submit
+    "nr_staging_fence", "clk_staging_fence",    # waits for the device to
+    #                           finish reading a staging buffer to reuse
+    "nr_staging_retire", "clk_staging_retire",  # staged batch h2d issue
+    #                           plus its landing dispatch
+    "nr_load_setup", "clk_load_setup",  # load_file_to_device's set-up
+    #                           and tear-down (one count per load)
+    "nr_vfs_read", "clk_vfs_read",      # buffered reads of the VFS scan
     "nr_debug1", "clk_debug1",
     "nr_debug2", "clk_debug2",
     "nr_debug3", "clk_debug3",

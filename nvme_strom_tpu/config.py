@@ -732,6 +732,12 @@ class Config:
             alias = self._vars[name].alias_of
             return self._values[alias or name]
 
+    def peek(self, name: str) -> Any:
+        """:meth:`get` without the lock, for per-call hot paths: one dict
+        read of a var that is not an alias (a set() that its validators
+        then roll back may show for that instant)."""
+        return self._values[name]
+
     def set(self, name: str, raw: Any) -> None:
         with self._lock:
             if name not in self._vars:

@@ -1900,11 +1900,12 @@ class Session:
         waiter loop mirrors the reference's spurious-wakeup handling
         (``strom_dma_task_wait``, kmod/nvme_strom.c:1230-1316), counting
         wrong wakeups."""
-        t0 = time.monotonic_ns()
         s = self._slot_of(task_id)
         cv = self._slot_cv[s]
         deadline = None if timeout is None else time.monotonic() + timeout
-        with cv:
+        # a poll that times out (or an unknown id) raises out of the stage
+        # uncounted; the blocking part alone is the stage
+        with stats.stage("ioctl_memcpy_wait", ring="wait") as st, cv:
             while True:
                 task = self._slots[s].get(task_id)
                 if task is None:
@@ -1919,10 +1920,9 @@ class Session:
                     raise StromError(_errno.ETIMEDOUT, f"dma task {task_id} timeout")
                 if task.state == DmaTaskState.RUNNING:
                     stats.add("nr_wrong_wakeup")
-        stats.count_clock("ioctl_memcpy_wait", time.monotonic_ns() - t0)
-        if _trace.active and task.trace_id:
-            _trace.span("wait", t0, time.monotonic_ns(), tid=task.trace_id,
-                        args=({"errno": task.errno_} if task.errno_ else None))
+            st.tid = task.trace_id
+            if task.errno_:
+                st.args = {"errno": task.errno_}
         if task.errno_:
             if _trace.active:
                 # the flight-recorder moment: dump what the engine did in
@@ -2007,7 +2007,17 @@ class Session:
         has nothing to do), does not train the readahead predictor, and
         its wait-time cache fills carry provenance so ARC's ghost lists
         stay blind to speculation."""
-        t0 = time.monotonic_ns()
+        # the caller's whole submit, the page-cache copies included
+        with stats.stage("ioctl_memcpy_submit") as st:
+            return self._submit_read(st.t0, source, buf_handle, chunk_ids,
+                                     chunk_size, dest_offset, wb_buffer,
+                                     speculative)
+
+    def _submit_read(self, t0: int, source: Source, buf_handle: int,
+                     chunk_ids: Sequence[int], chunk_size: int,
+                     dest_offset: int, wb_buffer: Optional[memoryview],
+                     speculative: bool) -> MemCopyResult:
+        """The body of :meth:`memcpy_ssd2ram`, entered at *t0*."""
         if self._closed:
             raise StromError(_errno.EBADF, "session closed")
         if chunk_size <= 0 or (chunk_size & (chunk_size - 1)):
@@ -2185,16 +2195,14 @@ class Session:
                 task.passthru = pt_chan
             native_failed = False
             for w in range(0, len(entries), window):
-                tp0 = time.monotonic_ns()
-                with stats.stage("setup_prps"):
+                with stats.stage("setup_prps", ring="plan",
+                                 tid=task.trace_id) as st:
                     reqs = plan_requests(source, entries[w:w + window],
                                          chunk_size, dest_offset,
                                          coalesce_limit=climit or None)
-                if _trace.active and task.trace_id:
-                    _trace.span("plan", tp0, time.monotonic_ns(),
-                                tid=task.trace_id,
-                                args={"window": w // window,
-                                      "requests": len(reqs)})
+                    if st.tid:
+                        st.args = {"window": w // window,
+                                   "requests": len(reqs)}
                 if pt_chan is not None:
                     reqs = self._passthru_split(task, source, reqs,
                                                 pt_chan, mirror_remap)
@@ -2337,12 +2345,10 @@ class Session:
                 length = min((r1 - r0) * chunk_size, source.size - base)
                 off = ((dest_offset if wb_buffer is None else 0)
                        + (nr_ssd + r0) * chunk_size)
-                tw0 = time.monotonic_ns()
-                source.read_buffered(base, target[off:off + length])
-                if _trace.active and task.trace_id:
-                    _trace.span("writeback", tw0, time.monotonic_ns(),
-                                tid=task.trace_id, offset=base,
-                                length=length)
+                with stats.stage("pagecache_copy", ring="writeback",
+                                 tid=task.trace_id, offset=base,
+                                 length=length):
+                    source.read_buffered(base, target[off:off + length])
                 r0 = r1
 
             # --- residency-tier hit serving (tail-packed after the
@@ -2406,7 +2412,6 @@ class Session:
             task.frozen = True
         task.buf_handle = buf_handle
         self._task_put(task)  # drop creator ref; releases the buffer ref on completion
-        stats.count_clock("ioctl_memcpy_submit", time.monotonic_ns() - t0)
         return result
 
     # SSD->device is the same submit path; the HBM leg lives in hbm.staging.

@@ -476,30 +476,29 @@ class StagingPipeline:
                 stats.add("bytes_staging_copy", nbytes)
                 # staged batch -> device (async H2D), landed with an async
                 # donated update; nothing here blocks
-                t0 = time.monotonic_ns()
-                dev = list(hbm.array.devices())[0]
-                host = np.frombuffer(dbuf.view()[:nbytes], dtype=device_dtype)
-                dev_chunk, fence = h2d_transfer(host, dev)
-                _land(hbm, dev_chunk, elem_start)
-                # the staging buffer is reusable once the H2D *read* of it
-                # completes — fence on the transfer's first leg, not the
-                # landing (on the pinned_host path the buffer frees before
-                # the DMA to HBM finishes; on CPU the chunk is an owned
-                # copy, so this stays safe)
-                self._barriers[bufidx] = fence
-                now = time.monotonic_ns()
-                stats.count_clock("debug3", now - t0)
-                if _tr.active:
-                    trid = _tr.traced_id(task_id)
+                trid = _tr.traced_id(task_id) if _tr.active else 0
+                with stats.stage("staging_retire", ring="staging_retire",
+                                 tid=trid, length=nbytes) as st:
+                    dev = list(hbm.array.devices())[0]
+                    host = np.frombuffer(dbuf.view()[:nbytes],
+                                         dtype=device_dtype)
+                    dev_chunk, fence = h2d_transfer(host, dev)
+                    _land(hbm, dev_chunk, elem_start)
+                    # the staging buffer is reusable once the H2D *read*
+                    # of it completes — fence on the transfer's first leg,
+                    # not the landing (on the pinned_host path the buffer
+                    # frees before the DMA to HBM finishes; on CPU the
+                    # chunk is an owned copy, so this stays safe)
+                    self._barriers[bufidx] = fence
                     if trid:
-                        _tr.span("staging_retire", t0, now, tid=trid,
-                                 length=nbytes,
-                                 args={"batch_chunks": len(batch),
-                                       "buffer": bufidx,
-                                       "ssd2dev": res.nr_ssd2dev,
-                                       "ram2dev": res.nr_ram2dev})
+                        st.args = {"batch_chunks": len(batch),
+                                   "buffer": bufidx,
+                                   "ssd2dev": res.nr_ssd2dev,
+                                   "ram2dev": res.nr_ram2dev}
+                if _tr.active:
+                    if trid:
                         _trace_landing(source, res.chunk_ids, chunk_size,
-                                       nbytes, "staged", t0, now, trid)
+                                       nbytes, "staged", st.t0, st.t1, trid)
                     _tr.task_end(task_id)
 
             def retire_one() -> None:
@@ -539,8 +538,9 @@ class StagingPipeline:
                     # SSD engine overwrites it — and a dead backend must
                     # fail the command, not hang it
                     if self._barriers[bufidx] is not None:
-                        bounded_fence(self._barriers[bufidx],
-                                      "staging-reuse")
+                        with stats.stage("staging_fence"):
+                            bounded_fence(self._barriers[bufidx],
+                                          "staging-reuse")
                         self._barriers[bufidx] = None
                     handle, _ = self._bufs[bufidx]
                     nbytes = len(batch) * chunk_size
@@ -748,6 +748,9 @@ def load_file_to_device(source: Source, *, chunk_size: Optional[int] = None,
 
     Allocates a device buffer of the source's (dtype-truncated) size, streams
     every chunk through the staging pipeline, and returns the device array.
+    The load's own set-up and tear-down (destination, staging ring, the
+    final fence, unmap) is one ``load_setup`` stage entry in several
+    pieces.
     """
     chunk_size = chunk_size or min(config.get("chunk_size"), 1 << 20)
     reg = hbm_registry or global_registry
@@ -759,20 +762,29 @@ def load_file_to_device(source: Source, *, chunk_size: Optional[int] = None,
     own_session = session is None
     sess = session or Session()
     try:
-        handle = reg.map_device_memory(n_elems, dtype=dtype, device=device)
+        with stats.stage("load_setup"):
+            handle = reg.map_device_memory(n_elems, dtype=dtype,
+                                           device=device)
         try:
             n_chunks = (source.size + chunk_size - 1) // chunk_size
-            with StagingPipeline(sess, staging_bytes=staging_bytes,
-                                 hbm_registry=reg) as pipe:
+            with stats.stage("load_setup", n=0):
+                pipe = StagingPipeline(sess, staging_bytes=staging_bytes,
+                                       hbm_registry=reg)
+            try:
                 # a non-multiple file tail rides the pipeline as a partial
                 # final chunk (ISSUE 8) — no separate pinned hop
                 pipe.memcpy_ssd2dev(source, handle, list(range(n_chunks)),
                                     chunk_size, device_dtype=dtype)
+            finally:
+                with stats.stage("load_setup", n=0):
+                    pipe.close()
             arr = reg.get(handle).array
-            arr.block_until_ready()
+            with stats.stage("load_setup", n=0):
+                arr.block_until_ready()
             return arr
         finally:
-            reg.unmap(handle)
+            with stats.stage("load_setup", n=0):
+                reg.unmap(handle)
     finally:
         if own_session:
             sess.close()

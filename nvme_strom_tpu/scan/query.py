@@ -3713,6 +3713,7 @@ class Query:
         objects scan identically to the direct path."""
         import jax
 
+        from ..stats import stats
         from .executor import fold_results
         dev = device or jax.local_devices()[0]
         src, own = self._open_owned()
@@ -3723,10 +3724,13 @@ class Query:
             for p0 in range(0, n_pages, batch):
                 n = min(batch, n_pages - p0)
                 raw = bytearray(n * PAGE_SIZE)
-                src.read_buffered(p0 * PAGE_SIZE, memoryview(raw))
+                with stats.stage("vfs_read"):
+                    src.read_buffered(p0 * PAGE_SIZE, memoryview(raw))
                 pages = np.frombuffer(raw, np.uint8).reshape(n, PAGE_SIZE)
-                acc = fold_results(acc, fn(jax.device_put(pages, dev)),
-                                   combine)
+                with stats.stage("staging_retire"):
+                    out = fn(jax.device_put(pages, dev))
+                stats.add("nr_kernel_dispatch")
+                acc = fold_results(acc, out, combine)
         finally:
             if own:
                 src.close()

@@ -16,13 +16,15 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
-from contextlib import contextmanager
+from typing import Optional
 
 from .api import STAT_FIELDS, StatInfo
 from .config import config
+from .trace import recorder as _recorder
 
 __all__ = ["StatRegistry", "stats", "DEFAULT_STAT_EXPORT",
            "STAT_EXPORT_DIR", "pid_export_path", "list_exports",
@@ -171,9 +173,12 @@ class StatRegistry:
         # passthru->io_uring->threadpool ladder this process landed on;
         # set once per Session, surfaced by the export and tpu_stat
         self._backend = ""
+        # whether the last stage entry found a jax.profiler trace running:
+        # the entry that sees it switch on drops the clock anchor
+        self._profiling = False
 
     def enabled(self) -> bool:
-        return bool(config.get("stat_info"))
+        return bool(config.peek("stat_info"))
 
     def add(self, name: str, delta: int = 1) -> None:
         if not self.enabled():
@@ -450,17 +455,12 @@ class StatRegistry:
             return {k: dict(v, wait_hist=list(v["wait_hist"]))
                     for k, v in sorted(self._tenants.items())}
 
-    @contextmanager
-    def stage(self, name: str):
-        """Time a pipeline stage into its count+clock pair."""
-        if not self.enabled():
-            yield
-            return
-        t0 = time.monotonic_ns()
-        try:
-            yield
-        finally:
-            self.count_clock(name, time.monotonic_ns() - t0)
+    def stage(self, name: str, *, ring: Optional[str] = None, tid: int = 0,
+              n: int = 1, offset: int = -1, length: int = 0,
+              args: Optional[dict] = None) -> "Stage":
+        """Time a pipeline stage on the calling thread (see :class:`Stage`):
+        ``with stats.stage("staging_fence"): ...``."""
+        return Stage(self, name, ring, tid, n, offset, length, args)
 
     def snapshot(self, *, debug: bool = False, reset_max: bool = False) -> StatInfo:
         """STAT_INFO: consistent snapshot.
@@ -618,6 +618,88 @@ class StatRegistry:
                                               "coldstart_bytes_per_sec",
                                               "cache_unpinned_bytes"):
                     self._c[k] += v
+
+
+#: ``jax.profiler.TraceAnnotation`` (jaxlib's TraceMe), looked up once JAX
+#: is imported: a process that never imported JAX runs no profiler
+_TraceMe = None
+
+
+def _trace_me():
+    global _TraceMe
+    if _TraceMe is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _TraceMe = TraceAnnotation
+    return _TraceMe
+
+
+class Stage:
+    """One timed entry of a pipeline stage on the calling thread.
+
+    On a clean exit it adds *n* and the elapsed ns to the stage's
+    ``nr_<name>``/``clk_<name>`` pair (``n=0`` adds one more piece of
+    time to an entry already counted).  While a ``jax.profiler`` trace
+    runs, the entry is also a ``strom.<name>`` span on the thread's host
+    line, on the device trace's clock; the first entry after the trace
+    starts first drops a ``strom.clock`` span whose ``mono_ns`` stat is
+    CLOCK_MONOTONIC at that instant, so a flight-recorder dump shifts
+    onto the trace's time base by (anchor start - ``mono_ns``).  With a
+    *ring* name and a task *tid* the flight recorder traces, the entry
+    is also recorded as that ring span.  The block may set ``tid`` and
+    ``args``; ``t0``/``t1`` are its CLOCK_MONOTONIC bounds.  A block that
+    raises moves neither the pair nor the ring (a timed-out poll is no
+    stage); its profiler span still closes."""
+
+    __slots__ = ("reg", "name", "ring", "tid", "n", "offset", "length",
+                 "args", "t0", "t1", "_on", "_tm")
+
+    def __init__(self, reg: StatRegistry, name: str, ring, tid: int, n: int,
+                 offset: int, length: int, args) -> None:
+        self.reg = reg
+        self.name = name
+        self.ring = ring
+        self.tid = tid
+        self.n = n
+        self.offset = offset
+        self.length = length
+        self.args = args
+
+    def __enter__(self) -> "Stage":
+        reg = self.reg
+        self._on = config.peek("stat_info")
+        tm = _TraceMe or _trace_me()
+        self._tm = None
+        if tm is not None:
+            if tm.is_enabled():
+                if not reg._profiling:
+                    reg._profiling = True
+                    with tm("strom.clock", mono_ns=time.monotonic_ns()):
+                        pass
+                self._tm = tm("strom." + self.name)
+                self._tm.__enter__()
+            elif reg._profiling:
+                reg._profiling = False
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self.t1 = t1 = time.monotonic_ns()
+        if self._tm is not None:
+            self._tm.__exit__(et, ev, tb)
+        if et is None:
+            if self._on:
+                c, lock = self.reg._c, self.reg._lock
+                lock.acquire()      # cheaper than `with` on this path
+                try:
+                    c["nr_" + self.name] += self.n
+                    c["clk_" + self.name] += t1 - self.t0
+                finally:
+                    lock.release()
+            if self.ring is not None and self.tid and _recorder.active:
+                _recorder.span(self.ring, self.t0, t1, tid=self.tid,
+                               offset=self.offset, length=self.length,
+                               args=self.args)
+        return False
 
 
 #: process-global registry (the reference's counters are module-global too)

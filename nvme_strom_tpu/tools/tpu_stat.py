@@ -114,11 +114,11 @@ def _row(cur_snap: dict, prev_snap: dict, verbose: bool) -> str:
             show_avg(d.get("clk_submit_dma", 0), d.get("nr_submit_dma", 0)),
             f"{d.get('nr_enter_dma', 0):6d}",
             # spare debug pairs, current writers: 1 = engine short-I/O
-            # resubmits, 2 = SQ-full stalls, 3 = staging-pipeline H2D
-            # landings (hbm/staging.py retire()), 4 = fixed-buffer rides
+            # resubmits, 2 = SQ-full stalls, 4 = fixed-buffer rides; the
+            # h2d column is the staging_retire stage (H2D landings)
             f"{d.get('nr_debug1', 0):6d}",
             f"{d.get('nr_debug2', 0):6d}",
-            f"{d.get('nr_debug3', 0):6d}",
+            f"{d.get('nr_staging_retire', 0):6d}",
             f"{d.get('nr_debug4', 0):6d}",
             # fault-tolerance tier (PR 1): recovery actions this interval —
             # a degrading device shows here before it latches errors

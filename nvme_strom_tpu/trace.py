@@ -436,9 +436,13 @@ def chrome_trace_from_events(events: List[tuple], *, reason: str = "manual",
                      "tid": tid_, "args": {"name": tname}})
         meta.append({"name": "thread_sort_index", "ph": "M", "pid": pid,
                      "tid": tid_, "args": {"sort_index": tid_}})
+    # "clock": ts is CLOCK_MONOTONIC; a jax.profiler trace of the same
+    # process carries a strom.clock span (stats.Stage) whose start minus
+    # its mono_ns stat shifts these events onto the trace's time base
     return {"traceEvents": meta + out, "displayTimeUnit": "ns",
             "otherData": {"tool": "strom_tpu flight recorder",
-                          "reason": reason, "dropped_events": dropped}}
+                          "reason": reason, "dropped_events": dropped,
+                          "clock": "CLOCK_MONOTONIC"}}
 
 
 _PHASES_REQUIRED_DUR = {"X"}

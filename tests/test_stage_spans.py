@@ -1,0 +1,175 @@
+"""Stages of the load path on the device trace's clock (``stats.stage``).
+
+A staged ``load_file_to_device`` under ``jax.profiler`` must leave one
+``strom.<stage>`` span per stage entry on the calling thread's host line,
+nested in the caller's own annotation; its counters must count entries;
+with the profiler off no profiler span may even be built; and the
+``strom.clock`` anchor must shift a flight-recorder span onto the trace's
+time base."""
+
+import glob
+import importlib
+import os
+
+import jax
+import pytest
+
+from nvme_strom_tpu import Session, config, open_source
+from nvme_strom_tpu.hbm.staging import load_file_to_device
+from nvme_strom_tpu.stats import stats
+from nvme_strom_tpu.trace import chrome_trace_from_events, recorder
+
+#: the module (the package's ``stats`` attribute is the registry)
+stats_mod = importlib.import_module("nvme_strom_tpu.stats")
+
+STAGES = {"ioctl_memcpy_submit", "pagecache_copy", "ioctl_memcpy_wait",
+          "staging_fence", "staging_retire", "load_setup"}
+STAGING = 1 << 20                  # a 4 MiB file is 4 staged batches
+CALLER = "caller.load"
+
+
+def _load(path, sess):
+    with jax.profiler.TraceAnnotation(CALLER), open_source(path) as src:
+        return load_file_to_device(src, session=sess, staging_bytes=STAGING)
+
+
+def _traced_load(path, sess, tdir):
+    """One load under the profiler (host spans only, as the benchmark
+    traces); returns the host line that holds the caller's annotation
+    as [(start_ns, end_ns, name, stats)]."""
+    from jax._src.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tdir), profiler_options=opts)
+    try:
+        _load(path, sess)
+    finally:
+        jax.profiler.stop_trace()
+    pb, = glob.glob(os.path.join(str(tdir), "plugins", "profile", "*",
+                                 "*.xplane.pb"))
+    for plane in ProfileData.from_file(pb).planes:
+        for line in plane.lines:
+            evs = [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+                    dict(e.stats) if e.name == "strom.clock" else None)
+                   for e in line.events]
+            if any(n == CALLER for _, _, n, _ in evs):
+                return evs
+    raise AssertionError("no host line holds the caller's annotation")
+
+
+def _counters(sess):
+    """The session's counters, the native engine's folded in."""
+    return dict(sess.stat_info().counters)
+
+
+def _delta(sess, before):
+    after = _counters(sess)
+    return {k: after[k] - before.get(k, 0) for k in after}
+
+
+@pytest.fixture()
+def staged():
+    config.set("landing", "staged")
+    sess = Session()
+    yield sess
+    sess.close()
+
+
+def test_staged_load_leaves_stage_spans_on_the_callers_line(
+        tmp_data_file, staged, tmp_path):
+    _load(tmp_data_file, staged)              # compiles the landing
+    before = _counters(staged)
+    line = _traced_load(tmp_data_file, staged, tmp_path / "trace")
+    d = _delta(staged, before)
+    (c0, c1), = [(a, b) for a, b, n, _ in line if n == CALLER]
+    spans = [(a, b, n) for a, b, n, _ in line if n.startswith("strom.")]
+    assert {n for _, _, n in spans} == {"strom." + s for s in STAGES} | \
+        {"strom.clock"}
+    assert all(c0 <= a <= b <= c1 for a, b, _ in spans)
+    per = {s: sum(1 for *_, n in spans if n == "strom." + s)
+           for s in STAGES}
+    # one span per entry; load_setup is one entry in four pieces
+    assert d["nr_staging_retire"] == per["staging_retire"] == 4
+    assert d["nr_load_setup"] == 1 and per["load_setup"] == 5
+    assert d["nr_ioctl_memcpy_submit"] == per["ioctl_memcpy_submit"] == 4
+    assert d["nr_pagecache_copy"] == per["pagecache_copy"] >= 4
+    # page-cache copies run inside the submit that does them
+    subs = [(a, b) for a, b, n in spans if n == "strom.ioctl_memcpy_submit"]
+    assert all(any(a <= p and q <= b for a, b in subs)
+               for p, q, n in spans if n == "strom.pagecache_copy")
+
+
+@pytest.mark.parametrize("arbitration", [True, False])
+def test_pagecache_copies_only_where_arbitration_writes_back(
+        tmp_data_file, staged, arbitration):
+    config.set("cache_arbitration", arbitration)
+    before = _counters(staged)
+    arr = _load(tmp_data_file, staged)
+    d = _delta(staged, before)
+    assert arr.size == 4 << 20
+    assert d["nr_staging_retire"] == 4 and d["nr_load_setup"] == 1
+    assert d["nr_staging_fence"] == 1      # the 4th batch reuses a buffer
+    assert d["clk_load_setup"] > 0
+    # a freshly written file is page-cache resident: with arbitration on
+    # every chunk is copied from the page cache, with it off none is
+    assert (d["nr_pagecache_copy"] > 0) == arbitration
+    assert (d["nr_submit_dma"] == 0) == arbitration
+
+
+class _SpyTraceMe:
+    """Stands in for jaxlib's TraceMe: counts constructions."""
+    on = False
+    made = 0
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.on
+
+    def __init__(self, name, **kw):
+        type(self).made += 1
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_profiler_off_builds_no_traceme(tmp_data_file, staged, monkeypatch):
+    monkeypatch.setattr(stats_mod, "_TraceMe", _SpyTraceMe)
+    monkeypatch.setattr(_SpyTraceMe, "made", 0)
+    before = _counters(staged)
+    _load(tmp_data_file, staged)
+    assert _SpyTraceMe.made == 0
+    # counted all the same
+    assert _delta(staged, before)["nr_staging_retire"] == 4
+    monkeypatch.setattr(_SpyTraceMe, "on", True)       # the control
+    with stats.stage("staging_fence"):
+        pass
+    assert _SpyTraceMe.made == 2                       # anchor + the stage
+
+
+def test_clock_anchor_shifts_recorder_spans_onto_the_trace(
+        tmp_data_file, tmp_path):
+    config.set("landing", "staged")
+    config.set("trace_policy", "all")
+    recorder.configure()
+    sess = Session()
+    try:
+        _load(tmp_data_file, sess)            # an entry with the profiler off
+        recorder.clear()
+        line = _traced_load(tmp_data_file, sess, tmp_path / "trace")
+        events = recorder.snapshot_events()
+    finally:
+        sess.close()
+    (anchor_ns, _, _, st), = [e for e in line if e[2] == "strom.clock"]
+    offset = anchor_ns - int(st["mono_ns"])
+    waits = sorted(a for a, _, n, _ in line if n == "strom.ioctl_memcpy_wait")
+    rings = [e for e in events if e[2] == "wait" and e[1] is not None]
+    assert rings and waits
+    for ev in rings:
+        shifted = ev[0] + offset
+        assert min(abs(shifted - w) for w in waits) < 1_000_000
+    doc = chrome_trace_from_events(events)
+    assert doc["otherData"]["clock"] == "CLOCK_MONOTONIC"

@@ -373,6 +373,26 @@ class TestSurface:
         assert rules_of(found) == ["surface.undeclared"]
         assert "nr_writes" in found[0].message
 
+    def test_stage_names_a_declared_pair_and_its_ring_span(self):
+        """stats.stage("x") bumps nr_x/clk_x; its ring= keyword emits a
+        recorder span, which the schema must hold as a span."""
+        src = """
+            STAT_FIELDS = ("nr_plan", "clk_plan")
+            EVENT_SCHEMA = {"plan": "span", "wait": "instant"}
+
+            def go(stats):
+                with stats.stage("plan", ring="plan"):
+                    pass
+                with stats.stage("wait", ring="wait"):
+                    pass
+            """
+        found = surface.run(proj({"pkg/api.py": src}))
+        assert rules_of(found) == ["surface.trace-kind",
+                                   "surface.undeclared"]
+        assert {"nr_wait", "clk_wait"} == {
+            f.message.split("'")[1] for f in found
+            if f.rule == "surface.undeclared"}
+
     def test_stat_render_generic_dump_covers_everything(self):
         files = {
             "pkg/api.py": "STAT_FIELDS = ('nr_reads', 'bytes_read')\n",
