@@ -397,6 +397,9 @@ STAT_FIELDS: Tuple[str, ...] = (
     #                           copies, nested in ioctl_memcpy_submit
     "nr_staging_fence", "clk_staging_fence",    # waits for the device to
     #                           finish reading a staging buffer to reuse
+    "nr_staging_fence_blocked",  # of those, fences whose transfer had not
+    #                           finished on entry (the ring's reuse order
+    #                           failed to hide the h2d behind the next read)
     "nr_staging_retire", "clk_staging_retire",  # staged batch h2d issue
     #                           plus its landing dispatch
     "nr_load_setup", "clk_load_setup",  # load_file_to_device's set-up

@@ -277,7 +277,9 @@ class Config:
                      "(pgsql/nvme_strom.c:353-446,1126-1181)",
                 validate=_check_numa_policy))
         reg(Var("staging_buffers", 3, "int", minval=2, maxval=16,
-                help="pinned host staging buffers for the SSD->HBM pipeline (triple-buffered default)"))
+                help="pinned host staging buffers for the SSD->HBM "
+                     "pipeline: up to N-1 receive SSD reads while the "
+                     "rest wait on their host->device transfers"))
         reg(Var("scan_dispatch_batch", 4, "int", minval=1, maxval=64,
                 help="jitted-call coalescing width for streamed scan "
                      "compute: fold this many device-resident page "

@@ -25,6 +25,8 @@ stats_mod = importlib.import_module("nvme_strom_tpu.stats")
 STAGES = {"ioctl_memcpy_submit", "pagecache_copy", "ioctl_memcpy_wait",
           "staging_fence", "staging_retire", "load_setup"}
 STAGING = 1 << 20                  # a 4 MiB file is 4 staged batches
+#: three buffers: the 4th batch refills the first, so one load fences once
+BUFFERS = 3
 CALLER = "caller.load"
 
 
@@ -71,6 +73,7 @@ def _delta(sess, before):
 @pytest.fixture()
 def staged():
     config.set("landing", "staged")
+    config.set("staging_buffers", BUFFERS)
     sess = Session()
     yield sess
     sess.close()
@@ -110,6 +113,7 @@ def test_pagecache_copies_only_where_arbitration_writes_back(
     assert arr.size == 4 << 20
     assert d["nr_staging_retire"] == 4 and d["nr_load_setup"] == 1
     assert d["nr_staging_fence"] == 1      # the 4th batch reuses a buffer
+    assert d["nr_staging_fence_blocked"] <= d["nr_staging_fence"]
     assert d["clk_load_setup"] > 0
     # a freshly written file is page-cache resident: with arbitration on
     # every chunk is copied from the page cache, with it off none is
