@@ -306,6 +306,12 @@ class Cell:
         tmp = self.path + ".tmp"
         pages = build_heap_file(tmp, cols, self._schema())
         del cols, gen
+        # write the table back in set-up, not under the first window
+        fd = os.open(tmp, os.O_RDONLY)
+        try:
+            os.fsync(fd)
+        finally:
+            os.close(fd)
         os.replace(tmp, self.path)
         page = self.cfg["page_bytes"]
         warm = WARM_UNIT + pages % WARM_UNIT
@@ -364,8 +370,15 @@ class Cell:
         self.control = None
 
     def check(self, records) -> dict:
+        """Every answer of the window against the reference, which runs
+        once per distinct request (the mix has 80)."""
         cols = reference_columns(self.cfg)
         by_year: dict = {}
-        wrong = sum(r["revenue"] != q6_reference(cols, r["req"], by_year)
-                    for r in records)
+        want: dict = {}
+        wrong = 0
+        for r in records:
+            key = tuple(sorted(r["req"].items()))
+            if key not in want:
+                want[key] = q6_reference(cols, r["req"], by_year)
+            wrong += r["revenue"] != want[key]
         return {"wrong_revenue": {"value": wrong, "limit": 0}}

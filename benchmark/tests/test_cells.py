@@ -16,10 +16,6 @@ import harness
 import traffic
 
 SPEC = harness.load_spec()
-#: the cells of pending.json, not yet proven on the chip, rehearse too
-for _key, _entries in harness.load_json("pending.json").items():
-    if isinstance(_entries, list):
-        SPEC[_key] = SPEC[_key] + _entries
 SEED = 2 ** 33 + 12345            # larger than 32 signed bits hold
 #: tiny sizes: the same code, a few MiB of data
 SMALL = {
