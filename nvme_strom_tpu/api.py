@@ -405,6 +405,9 @@ STAT_FIELDS: Tuple[str, ...] = (
     "nr_load_setup", "clk_load_setup",  # load_file_to_device's set-up
     #                           and tear-down (one count per load)
     "nr_vfs_read", "clk_vfs_read",      # buffered reads of the VFS scan
+    "nr_group_discover", "clk_group_discover",  # value-keyed GROUP BY's
+    #                           key discovery (sidecar or table scan; the
+    #                           scan's vfs_read/staging_retire nest in it)
     "nr_debug1", "clk_debug1",
     "nr_debug2", "clk_debug2",
     "nr_debug3", "clk_debug3",

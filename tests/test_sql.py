@@ -526,3 +526,198 @@ def test_sql_join_float_payload(joined, tmp_path):
     assert isinstance(out["sum(d.c1)"], float)
     np.testing.assert_allclose(out["sum(d.c1)"],
                                float(fv[c1[partner]].sum()), rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# Grouped expression aggregates: TPC-H Q1's shape
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def x64():
+    import jax
+    was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", True)
+    yield
+    jax.config.update("jax_enable_x64", was)
+
+
+#: TPC-H dates as int32 days since 1970-01-01 (1992-01-02, 1995-06-17,
+#: 1998-12-01)
+SHIP_LO, CURRENT, DATE_END = 8036, 9298, 10561
+
+
+def _lineitem_q1(tmp_path, n=6000, seed=26):
+    """The seven LINEITEM columns Q1 reads at the widths TPC-H asks:
+    quantity, price, discount and tax as int64 hundredths, the two flags
+    as their byte in an int32, the ship date in int32 days; flag rules
+    of dbgen (sec. 4.2.3)."""
+    rng = np.random.default_rng(seed)
+    qty = rng.integers(1, 51, n).astype(np.int64)
+    price = qty * rng.integers(90_000, 209_901, n)
+    disc = rng.integers(0, 11, n).astype(np.int64)
+    tax = rng.integers(0, 9, n).astype(np.int64)
+    ship = rng.integers(SHIP_LO, DATE_END, n).astype(np.int32)
+    receipt = ship + rng.integers(1, 31, n)
+    rf = np.where(receipt <= CURRENT,
+                  np.where(rng.integers(0, 2, n) == 0, ord("A"), ord("R")),
+                  ord("N")).astype(np.int32)
+    ls = np.where(ship > CURRENT, ord("O"), ord("F")).astype(np.int32)
+    cols = [qty * 100, price, disc, tax, rf, ls, ship]
+    schema = HeapSchema(n_cols=7, visibility=False,
+                        dtypes=("int64",) * 4 + ("int32",) * 3)
+    path = str(tmp_path / "lineitem.heap")
+    build_heap_file(path, cols, schema)
+    return path, schema, cols
+
+
+def _q1_sql(cutoff: int) -> str:
+    return ("SELECT c4, c5, SUM(c0) AS sum_qty, SUM(c1) AS sum_base_price, "
+            "SUM(c1 * (100 - c2)) AS sum_disc_price, "
+            "SUM(c1 * (100 - c2) * (100 + c3)) AS sum_charge, "
+            "AVG(c0) AS avg_qty, AVG(c1) AS avg_price, AVG(c2) AS avg_disc, "
+            "COUNT(*) AS count_order FROM lineitem "
+            f"WHERE c6 <= {cutoff} GROUP BY c4, c5 ORDER BY c4, c5")
+
+
+def _q1_numpy(cols, cutoff: int) -> dict:
+    """Plain Q1: a loop over the groups in (returnflag, linestatus)
+    order, exact int64 sums, AVG as the float64 quotient."""
+    qty, price, disc, tax, rf, ls, ship = cols
+    keep = ship <= cutoff
+    out = {k: [] for k in ("c4", "c5", "sum_qty", "sum_base_price",
+                           "sum_disc_price", "sum_charge", "count_order")}
+    for a, b in sorted(set(zip(rf[keep].tolist(), ls[keep].tolist()))):
+        m = keep & (rf == a) & (ls == b)
+        dp = price[m] * (100 - disc[m])
+        for k, v in (("c4", a), ("c5", b), ("sum_qty", qty[m].sum()),
+                     ("sum_base_price", price[m].sum()),
+                     ("sum_disc_price", dp.sum()),
+                     ("sum_charge", (dp * (100 + tax[m])).sum()),
+                     ("count_order", m.sum())):
+            out[k].append(int(v))
+    out = {k: np.asarray(v, np.int32 if k in ("c4", "c5") else np.int64)
+           for k, v in out.items()}
+    n = out["count_order"]
+    for avg, col in (("avg_qty", qty), ("avg_price", price),
+                     ("avg_disc", disc)):
+        s = [int(col[keep & (rf == a) & (ls == b)].sum())
+             for a, b in zip(out["c4"], out["c5"])]
+        out[avg] = np.asarray(s, np.int64) / n
+    return out
+
+
+@pytest.mark.parametrize("delta", [60, 90, 120])
+def test_sql_tpch_q1_matches_numpy(x64, tmp_path, delta):
+    """TPC-H Q1 (sec. 2.4.1) through sql_query equals the plain numpy Q1
+    exactly: int64 sums and counts, AVG as float64 quotients, the groups
+    in ORDER BY order."""
+    path, schema, cols = _lineitem_q1(tmp_path)
+    got = sql_query(_q1_sql(DATE_END - delta), path, schema)
+    want = _q1_numpy(cols, DATE_END - delta)
+    assert set(got) == set(want)
+    assert len(want["c4"]) == 4           # A/F, N/F, N/O, R/F
+    for k, v in want.items():
+        assert got[k].dtype == v.dtype, k
+        np.testing.assert_array_equal(got[k], v, err_msg=k)
+    assert want["sum_charge"].max() > 1 << 40
+
+
+def test_sql_tpch_q1_explain_names_kernel_and_discovery(x64, tmp_path):
+    from nvme_strom_tpu.scan.index import build_index
+    path, schema, _cols = _lineitem_q1(tmp_path, n=2000)
+    q, _ = parse_sql(_q1_sql(10000), path, schema)
+    plan = q.explain()
+    assert (plan.operator, plan.kernel) == ("group_by", "xla")
+    assert "group_efn sums 5 argument(s)" in plan.reason
+    assert "by a projection scan of the table" in plan.reason
+    build_index(path, schema, (4, 5))
+    q, _ = parse_sql(_q1_sql(10000), path, schema)
+    assert "from the fresh sidecar lineitem.heap.idx4_5" in \
+        q.explain().reason
+
+
+def test_sql_grouped_expr_aggregates_at_int64(x64, tmp_path):
+    """An int64 column keeps the whole expression at int64 (c1 * c1 is
+    past 2^31 a row), and per-group sums pass 2^31 and 2^32; an int32-
+    only expression sums in int64 accumulators."""
+    rng = np.random.default_rng(7)
+    schema = HeapSchema(n_cols=3, visibility=False,
+                        dtypes=("int32", "int32", "int64"))
+    n = schema.tuples_per_page * 64
+    c0 = rng.integers(0, 4, n).astype(np.int32)
+    c1 = rng.integers(0, 1_000_000, n).astype(np.int32)
+    c2 = rng.integers(-(1 << 40), 1 << 40, n).astype(np.int64)
+    path = str(tmp_path / "w.heap")
+    build_heap_file(path, [c0, c1, c2], schema)
+    got = sql_query("SELECT c0, SUM(c1 * c1 + c2) AS s, AVG(c1 * c1 + c2) "
+                    "AS a, SUM(c1 * 7) AS s7, SUM(c1) AS s1 FROM t "
+                    "WHERE c1 > 1000 GROUP BY c0 ORDER BY c0 DESC",
+                    path, schema)
+    keys = np.arange(3, -1, -1)
+    np.testing.assert_array_equal(got["c0"], keys)
+    w1, w2 = c1.astype(np.int64), c2
+    for i, k in enumerate(keys):
+        m = (c0 == k) & (c1 > 1000)
+        s = int((w1[m] * w1[m] + w2[m]).sum())
+        assert got["s"][i] == s
+        assert got["a"][i] == np.int64(s) / np.int64(m.sum())
+        assert got["s7"][i] == int(w1[m].sum()) * 7 > 1 << 32
+        assert got["s1"][i] == int(w1[m].sum()) > 1 << 31
+
+
+def test_sql_grouped_multikey_order_by_with_ties(table):
+    """ORDER BY several group columns sorts the group rows
+    lexicographically: later keys break the ties of earlier ones.  A
+    trailing DESC refuses: SQL binds it to the last key alone."""
+    path, schema, c0, c1, c2 = table
+    out = sql_query("SELECT c0, c1, COUNT(*) AS n FROM t WHERE c0 < 6 "
+                    "AND c1 BETWEEN -2 AND 2 GROUP BY c0, c1 "
+                    "ORDER BY c1, c0", path, schema)
+    m = (c0 < 6) & (c1 >= -2) & (c1 <= 2)
+    pairs = sorted(set(zip(c1[m].tolist(), c0[m].tolist())))
+    assert list(zip(out["c1"].tolist(), out["c0"].tolist())) == pairs
+    assert out["n"].tolist() == [int((m & (c1 == b) & (c0 == a)).sum())
+                                 for b, a in pairs]
+    assert len({b for b, _ in pairs}) < len(pairs)    # ties were broken
+    out = sql_query("SELECT c1, c0 FROM t WHERE c0 < 6 AND c1 BETWEEN -2 "
+                    "AND 2 GROUP BY c0, c1 ORDER BY c1, c0 ASC",
+                    path, schema)
+    assert list(zip(out["c1"].tolist(), out["c0"].tolist())) == pairs
+    with pytest.raises(StromError) as ei:
+        sql_query("SELECT c1, c0 FROM t GROUP BY c0, c1 ORDER BY c1, c0 "
+                  "DESC", path, schema)
+    assert ei.value.errno == 22 and "DESC" in str(ei.value)
+    with pytest.raises(StromError) as ei:
+        sql_query("SELECT c0, COUNT(*) FROM t GROUP BY c0 ORDER BY c0, c2",
+                  path, schema)
+    assert ei.value.errno == 22 and "c2" in str(ei.value)
+
+
+def test_sql_grouped_expr_refusals(x64, tmp_path):
+    """What stays outside the subset refuses with EINVAL: MIN/MAX beside
+    grouped expression aggregates (in the list, HAVING or ORDER BY), a
+    nullable column in one, a JOIN, integer division, worker
+    processes."""
+    path, schema, _cols = _lineitem_q1(tmp_path, n=1500)
+    base = "SELECT c4, SUM(c1 * (100 - c2)) FROM t"
+    for stmt, why in (
+            ("SELECT c4, SUM(c1 * 2), MAX(c0) FROM t GROUP BY c4", "MAX"),
+            (f"{base} GROUP BY c4 HAVING MIN(c0) > 0", "MIN"),
+            (f"{base} GROUP BY c4 ORDER BY MAX(c0)", "MAX"),
+            ("SELECT c4, SUM(c1 / c2) FROM t GROUP BY c4", "division"),
+            (f"{base} JOIN d ON c4 = d.c0 GROUP BY c4", "JOIN")):
+        with pytest.raises(StromError) as ei:
+            sql_query(stmt, path, schema, tables={"d": (path, schema)})
+        assert ei.value.errno == 22 and why in str(ei.value), stmt
+    with pytest.raises(StromError) as ei:
+        sql_query(f"{base} GROUP BY c4", path, schema, workers=2)
+    assert ei.value.errno == 22 and "worker" in str(ei.value)
+    ns = HeapSchema(n_cols=2, visibility=False, dtypes=("int32", "int64"),
+                    nullable=(False, True))
+    npath = str(tmp_path / "null.heap")
+    build_heap_file(npath, [np.zeros(10, np.int32),
+                            np.arange(10, dtype=np.int64)], ns,
+                    nulls={1: np.arange(10) % 3 == 0})
+    with pytest.raises(StromError) as ei:
+        sql_query("SELECT c0, SUM(c1 * 2) FROM t GROUP BY c0", npath, ns)
+    assert ei.value.errno == 22 and "nullable" in str(ei.value)

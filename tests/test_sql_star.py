@@ -82,11 +82,21 @@ def test_expr_float_division_allowed(star):
 
 
 def test_expr_under_group_by_refused(star):
-    fact, schema, *_ = star
-    with pytest.raises(StromError) as ei:
-        sql_query("SELECT c0, SUM(c2 * c2) FROM t GROUP BY c0",
-                  fact, schema)
-    assert ei.value.errno == 22
+    """Grouped expression aggregates run (TPC-H Q1's shape); beside
+    MIN/MAX, or with a JOIN, they stay outside the subset."""
+    fact, schema, tables, c0, c1, c2, *_ = star
+    res = sql_query("SELECT c0, SUM(c2 * c2) AS s FROM t GROUP BY c0 "
+                    "ORDER BY c0", fact, schema)
+    keys = np.unique(c0)
+    np.testing.assert_array_equal(res["c0"], keys)
+    assert res["s"].tolist() == [int(np.sum(c2[c0 == k] * c2[c0 == k]))
+                                 for k in keys]
+    for stmt in ("SELECT c0, SUM(c2 * c2), MIN(c1) FROM t GROUP BY c0",
+                 "SELECT c2, SUM(c2 * 2) FROM t JOIN d1 ON c0 = d1.c0 "
+                 "JOIN d2 ON c1 = d2.c0 GROUP BY c2"):
+        with pytest.raises(StromError) as ei:
+            sql_query(stmt, fact, schema, tables=tables)
+        assert ei.value.errno == 22, stmt
 
 
 def test_star_aggregate_two_dims(star):

@@ -177,3 +177,74 @@ def test_clock_anchor_shifts_recorder_spans_onto_the_trace(
         assert min(abs(shifted - w) for w in waits) < 1_000_000
     doc = chrome_trace_from_events(events)
     assert doc["otherData"]["clock"] == "CLOCK_MONOTONIC"
+
+
+def _traced_query(q, tdir, caller="caller.query"):
+    """One run of *q* under the profiler; returns the result and the host
+    line holding the caller's annotation as [(start_ns, end_ns, name)]."""
+    from jax._src.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tdir), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation(caller):
+            out = q.run()
+    finally:
+        jax.profiler.stop_trace()
+    pb, = glob.glob(os.path.join(str(tdir), "plugins", "profile", "*",
+                                 "*.xplane.pb"))
+    for plane in ProfileData.from_file(pb).planes:
+        for line in plane.lines:
+            evs = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                   for e in line.events]
+            if any(n == caller for _, _, n in evs):
+                return out, evs
+    raise AssertionError("no host line holds the caller's annotation")
+
+
+def test_group_discover_stage_nests_its_scan(tmp_path):
+    """Value-keyed GROUP BY's key discovery is one ``group_discover``
+    entry: a ``strom.group_discover`` span holding the discovery scan's
+    read and batch stages, before the aggregation pass's own.  From a
+    fresh sidecar it is still one entry, with no table read inside."""
+    import numpy as np
+
+    from nvme_strom_tpu.scan.heap import HeapSchema, build_heap_file
+    from nvme_strom_tpu.scan.index import build_index
+    from nvme_strom_tpu.scan.query import Query
+    schema = HeapSchema(n_cols=3, visibility=False)
+    n = schema.tuples_per_page * 1500             # two 8 MiB batches
+    rng = np.random.default_rng(5)
+    cols = [rng.integers(0, 3, n).astype(np.int32),
+            rng.integers(0, 2, n).astype(np.int32),
+            rng.integers(-9, 9, n).astype(np.int32)]
+    path = str(tmp_path / "g.heap")
+    build_heap_file(path, cols, schema)
+
+    def query():
+        return Query(path, schema).group_by_cols(
+            [0, 1], agg_exprs=[("bin", "*", ("col", 2), ("lit", 3))])
+
+    query().run()                                 # compiles
+    before = dict(stats.snapshot().counters)
+    out, line = _traced_query(query(), tmp_path / "t1")
+    d = {k: v - before.get(k, 0)
+         for k, v in stats.snapshot().counters.items()}
+    assert out["count"].sum() == n
+    assert d["nr_group_discover"] == 1 and d["clk_group_discover"] > 0
+    assert d["nr_vfs_read"] == d["nr_kernel_dispatch"] == 4
+    (g0, g1), = [(a, b) for a, b, nm in line
+                 if nm == "strom.group_discover"]
+    reads = [(a, b) for a, b, nm in line if nm == "strom.vfs_read"]
+    inside = [r for r in reads if g0 <= r[0] and r[1] <= g1]
+    assert len(inside) == 2 and all(a > g1 for a, _ in
+                                    set(reads) - set(inside))
+    assert d["clk_group_discover"] >= sum(b - a for a, b in inside)
+    build_index(path, schema, (0, 1))
+    before = dict(stats.snapshot().counters)
+    out2 = query().run()
+    d = {k: v - before.get(k, 0)
+         for k, v in stats.snapshot().counters.items()}
+    assert d["nr_group_discover"] == 1 and d["nr_vfs_read"] == 2
+    assert out2["count"].tolist() == out["count"].tolist()

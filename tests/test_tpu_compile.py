@@ -104,6 +104,45 @@ def test_decode_pallas_compiles(one_chip, tmp_path):
     assert _has_kernel(c)
 
 
+def test_group_efn_compiles_at_the_q1_shape(one_chip):
+    """TPC-H Q1's grouped expression kernel on one 8 MiB batch of the
+    full-width LINEITEM heap (int64 decimals, x64): GROUP BY two flag
+    columns (three and two values, four pairs plus the sentinel), five
+    int64 sums.  The per-group masks must fuse into the reductions, not
+    materialize a (rows, groups) array per sum."""
+    from nvme_strom_tpu.ops.groupby import make_group_efn
+    from nvme_strom_tpu.scan.sql import _eval_expr
+    was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", True)
+    try:
+        dts = ("int32",) * 4 + ("int64",) * 4 + ("int32",) * 5 + \
+            ("int64",) * 12
+        schema = HeapSchema(n_cols=25, visibility=False, dtypes=dts)
+        u0 = jnp.asarray([65, 78, 82], jnp.int32)
+        u1 = jnp.asarray([70, 79], jnp.int32)
+        table = jnp.asarray([[0, 4], [1, 2], [4, 3]], jnp.int32)
+
+        def key_fn(cols):
+            i0 = jnp.clip(jnp.searchsorted(u0, cols[8]), 0, 2)
+            i1 = jnp.clip(jnp.searchsorted(u1, cols[9]), 0, 1)
+            return table[i0, i1]
+
+        disc = ("bin", "*", ("col", 5), ("bin", "-", ("lit", 100),
+                                        ("col", 6)))
+        trees = [("col", 4), ("col", 5), ("col", 6), disc,
+                 ("bin", "*", disc, ("bin", "+", ("lit", 100),
+                                     ("col", 7)))]
+        fn = make_group_efn(
+            schema, key_fn, 5, [lambda c, e=e: _eval_expr(e, c)
+                                for e in trees],
+            [np.int64(0)] * 5, [np.dtype(np.int64)] * 5,
+            predicate=lambda c: c[10] <= 10500)
+        c = fn.lower(_pages(one_chip, 1024)).compile()
+        assert c.memory_analysis().temp_size_in_bytes < (64 << 20)
+    finally:
+        jax.config.update("jax_enable_x64", was)
+
+
 @pytest.mark.parametrize("what", ["landing", "checksum"])
 def test_8gib_landing_and_check_stay_in_place(one_chip, what):
     """An 8 GiB uint8 destination is past int32 addressing, so landing is
