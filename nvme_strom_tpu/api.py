@@ -215,6 +215,11 @@ STAT_FIELDS: Tuple[str, ...] = (
     # scan_dispatch_batch = K) this moves once per K batches/spans, so
     # nr_kernel_dispatch / batches ~ 1/K on coalesced paths
     "nr_kernel_dispatch",
+    # value-keyed GROUP BY's one-pass key set: steps that added keys the
+    # scan met after the first batch's, and batches whose kernel ran
+    # again over only the keys they missed
+    "nr_group_key_grow",
+    "nr_group_rerun",
     # fault-tolerance layer (PR 1): retry/degradation accounting.  The
     # reference has no retry tier (EIO fails the task outright); these
     # count each recovery action so operators can see a degrading device
@@ -406,8 +411,10 @@ STAT_FIELDS: Tuple[str, ...] = (
     #                           and tear-down (one count per load)
     "nr_vfs_read", "clk_vfs_read",      # buffered reads of the VFS scan
     "nr_group_discover", "clk_group_discover",  # value-keyed GROUP BY's
-    #                           key discovery (sidecar or table scan; the
-    #                           scan's vfs_read/staging_retire nest in it)
+    #                           key finding: the one-pass bootstrap and
+    #                           growth steps (nested in staging_retire), or
+    #                           a sidecar or discovery scan (whose
+    #                           vfs_read/staging_retire nest in it)
     "nr_debug1", "clk_debug1",
     "nr_debug2", "clk_debug2",
     "nr_debug3", "clk_debug3",

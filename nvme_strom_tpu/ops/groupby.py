@@ -221,10 +221,12 @@ def make_group_efn(schema: HeapSchema, key_fn: Callable, n_groups: int,
                    expr_fns: Sequence[Callable], expr_zeros, expr_accs, *,
                    predicate: Optional[Callable] = None):
     """Build the jitted grouped expression-aggregate kernel
-    ``group_efn(pages_u8) -> {"count": (G,), "esums": [(G,) per expr]}``.
+    ``group_efn(pages_u8, *params) -> {"count": (G,), "esums": [(G,) per
+    expr]}``.
 
     One pass over a page batch: decode, the predicate, the group id from
-    ``key_fn(cols)`` (ids outside ``[0, n_groups)`` fall into no group),
+    ``key_fn(cols, *params)`` (ids outside ``[0, n_groups)`` fall into no
+    group; *params*, such as a key table, are arguments, not constants),
     then per group the row count and the masked sum of each
     ``expr_fns[i](cols)`` in ``expr_accs[i]`` (``expr_zeros[i]`` fills
     the rows a group does not select).  It computes nothing else: no
@@ -241,9 +243,9 @@ def make_group_efn(schema: HeapSchema, key_fn: Callable, n_groups: int,
     cnt_t = acc_dtypes(np.dtype(np.int32))[0]
 
     @jax.jit
-    def group_efn(pages_u8):
+    def group_efn(pages_u8, *params):
         cols, valid = decode_pages(pages_u8, schema)
-        keys = key_fn(cols)
+        keys = key_fn(cols, *params)
         sel = valid & (keys >= 0) & (keys < G)
         if predicate is not None:
             sel = sel & predicate(cols)

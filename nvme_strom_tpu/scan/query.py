@@ -37,6 +37,7 @@ import numpy as np
 
 from ..api import StromError
 from ..scan.heap import PAGE_SIZE, HeapSchema
+from .group_keys import GroupKeySet, GroupSpill, lookup_group_ids
 from .planner import (capability_cache, cost_direct_scan, cost_vfs_scan,
                       should_use_direct_scan)
 
@@ -71,16 +72,6 @@ class _ScanLimitReached(Exception):
     """Private control flow: the gather collected ``LIMIT`` rows early and
     the scan can stop issuing DMA (the executor stops pulling tuples once
     the plan's limit is satisfied)."""
-
-
-class _GroupSpill(Exception):
-    """Private control flow: key discovery crossed ``max_groups`` on a
-    shape the sorted-aggregation path can serve (1-2 key columns) — the
-    runner reroutes instead of failing with ENOMEM."""
-
-    def __init__(self, seen: int):
-        self.seen = seen
-        super().__init__(f"group key discovery passed {seen} distinct")
 
 
 class _HostCols(dict):
@@ -542,20 +533,25 @@ class Query:
                       having: Optional[Callable] = None,
                       max_groups: int = 1 << 16) -> "Query":
         """Terminal: SQL ``GROUP BY col[, col2]`` over actual column
-        VALUES — no key function, no group-count guess.  Two passes:
-        the distinct key set is discovered first (from a fresh sidecar
-        at zero table I/O when one exists, else a streamed projection
-        scan, timed as the ``group_discover`` stage), then aggregation
-        rides the normal GROUP BY kernels with a ``searchsorted`` key
-        function over the discovered keys.
+        VALUES — no key function, no group-count guess.  The group
+        kernels take an exact-membership key function over a key table
+        (:mod:`.group_keys`).  A single-process scan reads the table
+        once: the key set starts from the first page batch's keys and
+        grows inside the aggregation scan as batches meet keys it lacks
+        (:class:`.group_keys.OnePassGroups`).  A fresh sidecar gives the
+        whole key set at zero table I/O instead; worker processes, the
+        mesh and the index path discover the keys first by a projection
+        scan.  Finding keys is timed as the ``group_discover`` stage.
 
         Result = :meth:`group_by`'s (count/sums/mins/maxs/avgs/...)
         plus ``key_cols``: one array per key column, aligned with the
-        surviving groups — the SELECT-list face SQL gives GROUP BY.
-        Groups that select no rows are dropped (SQL semantics); *having*
-        then filters like :meth:`group_by`'s.  One or two integer
-        columns; discovery beyond *max_groups* distinct keys fails with
-        ENOMEM instead of silently truncating.
+        surviving groups — the SELECT-list face SQL gives GROUP BY, in
+        key order.  Groups that select no rows are dropped (SQL
+        semantics); *having* then filters like :meth:`group_by`'s.  One
+        to four integer columns; past *max_groups* distinct keys, column
+        aggregates over one or two key columns take the sorted
+        aggregation, anything else fails with ENOMEM instead of silently
+        truncating.
 
         *agg_exprs* (SQL's ``SUM(expr)``/``AVG(expr)`` under GROUP BY):
         expression trees in the :meth:`aggregate_exprs` grammar.  The
@@ -599,17 +595,15 @@ class Query:
                                  [uniq.index(k) for k in keys])
         self._op = "group_by"
         self._terminal_set = True
-        # key_fn None = unresolved; run() discovers the keys first
+        # key_fn None = unresolved; run() finds the keys
         self._group = (None, 0, agg_cols, None)
         self._group_cols = (cols_, agg_cols, having, int(max_groups))
         return self
 
     def _resolve_group_keys(self, session, device) -> None:
-        """Pass 1 of :meth:`group_by_cols`: discover the sorted distinct
-        key set, then install the derived ``searchsorted`` key function,
-        the group count, and the composed HAVING (empty groups dropped —
-        discovery may be a SUPERSET of the selected rows' keys when it
-        comes from a sidecar) into ``self._group``."""
+        """Discover the distinct key set first, then install the derived
+        key function, the group count and the composed HAVING into
+        ``self._group`` (the path of workers, the mesh and the index)."""
         self._install_group_keys(self._discover_group_keys(session,
                                                            device))
 
@@ -617,11 +611,9 @@ class Query:
         """Discovery half of :meth:`_resolve_group_keys`: the sorted
         distinct key set (packed uint64 for pairs, (g, N) lex rows for
         3-4 keys) from a fresh sidecar at zero table I/O, else a
-        streamed projection scan.  Raises :class:`_GroupSpill` past
-        ``max_groups`` when the sorted-aggregation fallback can serve
-        this shape (run() catches it); ENOMEM otherwise.  Timed as the
-        ``group_discover`` stage, whose scan's ``vfs_read`` and
-        ``staging_retire`` stages nest in it."""
+        streamed projection scan.  Raises :class:`GroupSpill` past
+        ``max_groups``.  Timed as the ``group_discover`` stage, whose
+        scan's ``vfs_read`` and ``staging_retire`` stages nest in it."""
         from ..stats import stats
         with stats.stage("group_discover"):
             return self._discover_group_keys_staged(session, device)
@@ -642,9 +634,17 @@ class Query:
             return None
         return ip if ok else None
 
+    def _group_onepass(self, plan: QueryPlan) -> bool:
+        """Whether this run finds the keys inside the aggregation scan:
+        a local scan of the table (not the index path), with no fresh
+        sidecar to give the keys at zero table I/O."""
+        return (plan.mode == "local" and plan.kernel != "invalid"
+                and plan.access_path in ("vfs", "direct")
+                and self._group_key_sidecar() is None)
+
     def _discover_group_keys_staged(self, session, device) -> np.ndarray:
         from .index import pack_pair
-        cols_, agg, user_having, max_groups = self._group_cols
+        cols_, _agg, _hv, max_groups = self._group_cols
         dts = [self.schema.col_dtype(c) for c in cols_]
         discovered = None
         ip = self._group_key_sidecar()
@@ -661,11 +661,7 @@ class Query:
             except Exception:   # raced away: fall to the scan
                 discovered = None
         if discovered is not None and len(discovered) > max_groups:
-            if len(cols_) <= 2:
-                raise _GroupSpill(len(discovered))
-            raise StromError(12, f"group_by_cols: {len(discovered)} "
-                                 f"distinct keys exceed max_groups="
-                                 f"{max_groups}")
+            raise GroupSpill(len(discovered))
         if discovered is None:
             gather, _f, _d = self._make_gather_fn(cols_,
                                                   want_positions=False)
@@ -692,12 +688,7 @@ class Query:
                     merged = np.unique(
                         np.concatenate([merged, u]), axis=0)
                 if len(merged) > max_groups:
-                    if nk <= 2:
-                        raise _GroupSpill(len(merged))
-                    raise StromError(
-                        12, f"group_by_cols: more than {max_groups} "
-                            f"distinct keys (raise max_groups, or use "
-                            f"group_by with a key function)")
+                    raise GroupSpill(len(merged))
                 return {}
 
             self._stream_collect(self._explain_inner(), collect, device,
@@ -705,119 +696,59 @@ class Query:
             discovered = merged
         return discovered
 
-    def _install_group_keys(self, discovered: np.ndarray) -> None:
-        """Installation half of :meth:`_resolve_group_keys`: derive the
-        ``searchsorted`` key function + group count from the (already
-        discovered, possibly worker-shipped) sorted key set and compose
-        the empty-group-dropping HAVING into ``self._group``."""
-        import jax.numpy as jnp
+    def _group_having(self):
+        """The value-keyed GROUP BY's HAVING: groups that select no row
+        dropped, then the caller's *having*."""
+        user = self._group_cols[2]
 
-        from .index import unpack_second
-        cols_, agg, user_having, _max_groups = self._group_cols
-        dts = [self.schema.col_dtype(c) for c in cols_]
-        self._group_discovered = discovered   # worker spec ships this
-        if len(cols_) == 1:
-            keys = discovered.astype(dts[0])
-            g = len(keys)
-            kj = jnp.asarray(keys) if g else None
-
-            def key_fn(cols, kj=kj, g=g):
-                v = cols[cols_[0]]
-                if kj is None:       # empty table: one dropped bucket
-                    return jnp.zeros(v.shape, jnp.int32)
-                return jnp.clip(jnp.searchsorted(kj, v), 0,
-                                g - 1).astype(jnp.int32)
-
-            n_groups = max(g, 1)
-            self._gk_decode = lambda gids, keys=keys: [keys[gids]]
-        elif len(cols_) == 2:
-            packed = discovered                      # sorted uint64
-            g = len(packed)
-            hi = (packed >> np.uint64(32))
-            if dts[0] == np.dtype(np.int32):
-                k0 = (hi.astype(np.int64) - (1 << 31)).astype(np.int32)
-            else:
-                k0 = hi.astype(np.uint32)
-            k1 = unpack_second(packed, dts[1])
-            u0, u1 = np.unique(k0), np.unique(k1)
-            if len(u0) * max(len(u1), 1) > (1 << 22):
-                raise StromError(
-                    12, "group_by_cols: dense pair table over 4M "
-                        "entries; use group_by with a key function")
-            # dense (rank0, rank1) -> group-id table; absent pairs (and
-            # masked rows) land in the sentinel bucket g, dropped by the
-            # count>0 HAVING
-            table = np.full((max(len(u0), 1), max(len(u1), 1)), g,
-                            np.int32)
-            if g:
-                table[np.searchsorted(u0, k0),
-                      np.searchsorted(u1, k1)] = \
-                    np.arange(g, dtype=np.int32)
-            u0j, u1j = jnp.asarray(u0), jnp.asarray(u1)
-            tj = jnp.asarray(table)
-
-            def key_fn(cols, u0j=u0j, u1j=u1j, tj=tj):
-                if u0j.shape[0] == 0:
-                    return jnp.zeros(cols[cols_[0]].shape, jnp.int32)
-                i0 = jnp.clip(jnp.searchsorted(u0j, cols[cols_[0]]), 0,
-                              u0j.shape[0] - 1)
-                i1 = jnp.clip(jnp.searchsorted(u1j, cols[cols_[1]]), 0,
-                              u1j.shape[0] - 1)
-                return tj[i0, i1].astype(jnp.int32)
-
-            n_groups = g + 1
-            self._gk_decode = lambda gids, k0=k0, k1=k1: [k0[gids],
-                                                          k1[gids]]
-
-        if len(cols_) >= 3:
-            krows = discovered.astype(np.int64)      # (g, N) lex-sorted
-            g = len(krows)
-            uniqs = [np.unique(krows[:, j]) for j in range(len(cols_))]
-            dims = [max(len(u), 1) for u in uniqs]
-            total = 1
-            for dnn in dims:
-                total *= dnn
-            if total > (1 << 22):
-                raise StromError(
-                    12, "group_by_cols: dense rank table over 4M "
-                        "entries; use group_by with a key function")
-            # mixed-radix flat table: rank tuple -> group id (sentinel
-            # g for combinations that never occur / masked rows)
-            table = np.full(total, g, np.int32)
-            if g:
-                flat = np.zeros(g, np.int64)
-                for j in range(len(cols_)):
-                    flat = flat * dims[j] + np.searchsorted(
-                        uniqs[j], krows[:, j])
-                table[flat] = np.arange(g, dtype=np.int32)
-            ujs = [jnp.asarray(u.astype(np.int64).astype(np.int32)
-                               if dts[j].kind == "i"
-                               else u.astype(np.uint32))
-                   for j, u in enumerate(uniqs)]
-            tjN = jnp.asarray(table)
-
-            def key_fn(cols, ujs=ujs, tjN=tjN, dims=tuple(dims)):
-                if ujs[0].shape[0] == 0:
-                    return jnp.zeros(cols[cols_[0]].shape, jnp.int32)
-                flat = None
-                for j, cj in enumerate(cols_):
-                    r = jnp.clip(jnp.searchsorted(ujs[j], cols[cj]), 0,
-                                 max(ujs[j].shape[0] - 1, 0))
-                    flat = r if flat is None else flat * dims[j] + r
-                return tjN[flat].astype(jnp.int32)
-
-            n_groups = g + 1
-            self._gk_decode = lambda gids, krows=krows, dts=dts: [
-                krows[:, j][gids].astype(dts[j])
-                for j in range(len(cols_))]
-
-        def hv(res, user=user_having):
+        def hv(res):
             m = np.asarray(res["count"]) > 0
             if user is not None:
                 m = m & np.asarray(user(res)).astype(bool)
             return m
+        return hv
 
-        self._group = (key_fn, n_groups, agg, hv)
+    def _install_group_keys(self, discovered: np.ndarray) -> None:
+        """Installation half of :meth:`_resolve_group_keys`: the exact-
+        membership key function over the (already discovered, possibly
+        worker-shipped) sorted key set, with group id = the key's rank
+        and the sentinel after the last, and the composed HAVING, into
+        ``self._group``."""
+        from .index import unpack_second
+        cols_, _agg, _hv, max_groups = self._group_cols
+        dts = [self.schema.col_dtype(c) for c in cols_]
+        self._group_discovered = discovered   # worker spec ships this
+        if len(cols_) == 1:
+            kcols = [discovered.astype(dts[0])]
+        elif len(cols_) == 2:
+            hi = (discovered >> np.uint64(32)).astype(np.int64)
+            kcols = [(hi - (1 << 31) if dts[0] == np.dtype(np.int32)
+                      else hi).astype(dts[0]),
+                     unpack_second(discovered, dts[1])]
+        else:                                    # (g, N) lex-sorted rows
+            kcols = [discovered[:, j].astype(dts[j])
+                     for j in range(len(cols_))]
+        keys = GroupKeySet(dts, max_groups)
+        keys.add(kcols)
+        g = len(keys)
+        tabs, ids = keys.table(sentinel=g)
+
+        def key_fn(cols, tabs=tabs, ids=ids):
+            return lookup_group_ids(cols, cols_, tabs, ids, g)
+
+        self._set_group_keys(keys, key_fn, g + 1)
+
+    def _set_group_keys(self, keys: GroupKeySet, key_fn, n_groups: int):
+        """Install a found key set: the kernels' group ids are its ids,
+        results list its groups in key order (:meth:`_finalize` takes
+        them, dropping the sentinel and unused ids), and ``key_cols``
+        decodes them."""
+        order = keys.key_order()
+        self._group_take = order
+        sorted_cols = [c[order] for c in keys.cols]
+        self._gk_decode = lambda gids: [c[gids] for c in sorted_cols]
+        self._group = (key_fn, n_groups, self._group_cols[1],
+                       self._group_having())
 
     def top_k(self, col: int, k: int, *, largest: bool = True) -> "Query":
         """Terminal: k best values of *col* + their global row positions."""
@@ -1328,7 +1259,7 @@ class Query:
                 return "xla", ("value-keyed GROUP BY: the discovered "
                                "key table is a captured array (Mosaic "
                                "kernels take arrays as inputs only); "
-                               "XLA serves the searchsorted key path")
+                               "XLA serves the key-table lookup")
             if jax.config.jax_enable_x64:
                 # acc_dtypes widens sums/sumsqs to i64/f64 under x64 —
                 # dtypes Mosaic cannot hold in SMEM on real hardware
@@ -1545,13 +1476,20 @@ class Query:
                 f"compute on the host CPU, not the accelerator")
         if self._group_cols is not None:
             side = self._group_key_sidecar()
-            source = (f"from the fresh sidecar {os.path.basename(side)} "
-                      f"at zero table I/O" if side is not None else
-                      "by a projection scan of the table")
+            if side is not None:
+                how = (f"distinct keys discovered first from the fresh "
+                       f"sidecar {os.path.basename(side)} at zero table "
+                       f"I/O")
+            elif self._group_onepass(plan) and not plan.workers:
+                how = ("keys grow inside the aggregation scan (the first "
+                       "page batch's keys, then each batch's misses, "
+                       "aggregated again over only the added keys)")
+            else:
+                how = ("distinct keys discovered first by a projection "
+                       "scan of the table")
             plan = dataclasses.replace(
                 plan, reason=plan.reason +
-                f"; value-keyed GROUP BY: distinct keys discovered first "
-                f"{source}, empty groups dropped")
+                f"; value-keyed GROUP BY: {how}, empty groups dropped")
         js = self._join_strategy()
         if js is not None:
             strat, n_parts = js
@@ -1775,35 +1713,9 @@ class Query:
                     return out
                 fn = lambda pages: project(inner(pages))
             return fn, None
-        if self._op == "group_by" and self._group_exprs is not None:
-            from ..ops.groupby import make_group_efn
-            key_fn, g, _agg, _having = self._group
-            fns, zeros, accs = self._star_expr_parts()
-            run = make_group_efn(self.schema, key_fn, g, fns, zeros, accs,
-                                 predicate=pred)
-            return run, None
         if self._op == "group_by":
-            key_fn, g, agg, _having = self._group
-            kw = dict(agg_cols=agg,
-                      predicate=(lambda cols: pred(cols)) if pred else None)
-            if kernel == "pallas" and self._group_cols is not None:
-                # an explicit kernel="pallas" override must refuse
-                # cleanly, not die inside pallas_call tracing
-                raise StromError(22, "value-keyed GROUP BY cannot run "
-                                     "on the pallas kernel (the "
-                                     "discovered key table is a "
-                                     "captured array); use kernel="
-                                     "'xla' or 'auto'")
-            if kernel == "pallas":
-                from ..ops.groupby_pallas import make_groupby_fn_pallas
-                run = make_groupby_fn_pallas(self.schema, lambda cols: key_fn(cols),
-                                             g, **kw)
-            else:
-                from ..ops.groupby import make_groupby_fn
-                run = make_groupby_fn(self.schema, lambda cols: key_fn(cols),
-                                      g, **kw)
-            from ..ops.groupby import combine_groupby
-            return (lambda pages: run(pages)), combine_groupby
+            key_fn, g, _agg, _having = self._group
+            return self._group_kernel(key_fn, g, kernel)
         if self._op == "top_k":
             from ..ops.topk import make_topk_fn
             col, k, largest = self._topk
@@ -1818,6 +1730,96 @@ class Query:
                            predicate=(lambda cols: pred(cols))
                            if pred else None, how=self._join_how)
         return (lambda pages: run(pages)), None
+
+    def _group_kernel(self, key_fn, n_groups: int, kernel: str):
+        """``(fn(pages, *params), combine)`` of the GROUP BY kernel over
+        *n_groups* groups: the grouped expression kernel when the
+        statement sums expressions, else the column kernel.  *params*
+        reach ``key_fn(cols, *params)`` (the one-pass key table)."""
+        pred = self._pred
+        if self._group_exprs is not None:
+            from ..ops.groupby import make_group_efn
+            fns, zeros, accs = self._star_expr_parts()
+            return make_group_efn(self.schema, key_fn, n_groups, fns,
+                                  zeros, accs, predicate=pred), None
+        from ..ops.groupby import combine_groupby
+        agg = self._group[2]
+        if kernel == "pallas" and self._group_cols is not None:
+            # an explicit kernel="pallas" override must refuse
+            # cleanly, not die inside pallas_call tracing
+            raise StromError(22, "value-keyed GROUP BY cannot run "
+                                 "on the pallas kernel (the "
+                                 "discovered key table is a "
+                                 "captured array); use kernel="
+                                 "'xla' or 'auto'")
+        if kernel == "pallas":
+            from ..ops.groupby_pallas import make_groupby_fn_pallas
+            run = make_groupby_fn_pallas(
+                self.schema, lambda cols: key_fn(cols), n_groups,
+                agg_cols=agg,
+                predicate=(lambda cols: pred(cols)) if pred else None)
+            return (lambda pages: run(pages)), combine_groupby
+        from ..ops.groupby import make_groupby_fn
+        run = make_groupby_fn(
+            self.schema, lambda cols, *p: key_fn(cols, *p), n_groups,
+            agg_cols=agg,
+            predicate=(lambda cols, *_: pred(cols)) if pred else None)
+        return run, combine_groupby
+
+    def _group_overflow(self, e: GroupSpill) -> None:
+        """Past ``max_groups``: return when the sorted aggregation serves
+        the statement (column aggregates over 1-2 key columns), else
+        fail with ENOMEM."""
+        if self._group_exprs is not None:
+            raise StromError(12, f"group_by_cols: {e.seen} distinct keys "
+                                 f"exceed max_groups; grouped expression "
+                                 f"aggregates have no sorted-aggregation "
+                                 f"path") from None
+        if len(self._group_cols[0]) > 2:
+            raise StromError(12, f"group_by_cols: more than "
+                                 f"{self._group_cols[3]} distinct keys "
+                                 f"(raise max_groups, or use group_by "
+                                 f"with a key function)") from None
+
+    def _run_group_onepass(self, plan: QueryPlan, device, session,
+                           kernel: str) -> dict:
+        """Value-keyed GROUP BY in one scan of the table: the group
+        kernel over a key table that grows inside the scan
+        (:class:`.group_keys.OnePassGroups`); the loop dispatches per
+        batch (the kernel holds host state, so it cannot coalesce)."""
+        import jax
+
+        from ..ops.filter_xla import decode_pages
+        from ..ops.groupby import combine_groupby
+        from .group_keys import OnePassGroups
+        cols_, _agg, _hv, max_groups = self._group_cols
+        pred = self._pred
+
+        def key_fn(cols, tabs, ids):
+            return lookup_group_ids(cols, cols_, tabs, ids, ids.shape[0])
+
+        @jax.jit
+        def gather_misses(pages, tabs, ids):
+            dcols, valid = decode_pages(pages, self.schema)
+            if pred is not None:
+                valid = valid & pred(dcols)
+            miss = key_fn(dcols, tabs, ids) == ids.shape[0]
+            out = {"mask": (valid & miss).reshape(-1)}
+            for j, c in enumerate(cols_):
+                out[f"f{j}"] = dcols[c].reshape(-1)
+            return out
+
+        keys = GroupKeySet([self.schema.col_dtype(c) for c in cols_],
+                           max_groups)
+        run = OnePassGroups(
+            keys, lambda cap: self._group_kernel(key_fn, cap + 1,
+                                                 kernel)[0],
+            gather_misses,
+            None if self._group_exprs is not None else combine_groupby)
+        self._stream_collect(plan, run, device, session)
+        out = run.finish()
+        self._set_group_keys(keys, None, len(keys))
+        return self._finalize(out) if out else {}
 
     # -- compute pushdown (ISSUE 14) ----------------------------------------
     def _pushdown_need_cols(self):
@@ -2018,20 +2020,20 @@ class Query:
         if nw >= 2 and mesh is None:
             return self._run_workers(nw, session=session, device=device)
         if self._group_cols is not None and self._group[0] is None:
-            # value-keyed GROUP BY: discover the distinct key set first
-            # (sidecar when fresh, streamed scan otherwise), then run as
-            # a normal group_by with a searchsorted key function; past
-            # max_groups the sorted-aggregation path takes over (the
-            # one-hot kernels' footprint grows with the group count)
+            # value-keyed GROUP BY: a local scan grows the key set inside
+            # the aggregation pass; otherwise the keys come first (fresh
+            # sidecar, or a discovery scan) and the run is a normal
+            # group_by.  Past max_groups the sorted-aggregation path takes
+            # over (the one-hot kernels' footprint grows with the groups)
             try:
+                plan = self._explain_inner(mesh=mesh)
+                if self._group_onepass(plan):
+                    return self._run_group_onepass(
+                        plan, device, session,
+                        plan.kernel if kernel == "auto" else kernel)
                 self._resolve_group_keys(session, device)
-            except _GroupSpill as e:
-                if self._group_exprs is not None:
-                    raise StromError(12, f"group_by_cols: {e.seen} "
-                                         f"distinct keys exceed "
-                                         f"max_groups; grouped expression "
-                                         f"aggregates have no sorted-"
-                                         f"aggregation path") from None
+            except GroupSpill as e:
+                self._group_overflow(e)
                 return self._run_groupby_sorted(device, session)
         plan = self.explain(mesh=mesh)
         if plan.kernel == "invalid":
@@ -2222,6 +2224,11 @@ class Query:
         SQL's post-aggregation semantics."""
         if self._op != "group_by" or not out:
             return out
+        take = getattr(self, "_group_take", None)
+        if take is not None:    # value-keyed: real groups, in key order
+            out = {k: ([np.asarray(a)[..., take] for a in v]
+                       if isinstance(v, list) else np.asarray(v)[..., take])
+                   for k, v in out.items()}
         if self._group_exprs is not None:
             return self._finalize_groups(self._finalize_exprs(out))
         count = np.asarray(out["count"])
@@ -2359,18 +2366,12 @@ class Query:
         (same faces as the one-hot kernels + ``key_cols``), via
         :meth:`_finalize` so HAVING/avgs/vars compose identically."""
         from .index import unpack_second
-        cols_, agg, user_having, _mg = self._group_cols
+        cols_, agg, _user_having, _mg = self._group_cols
         dts = [self.schema.col_dtype(c) for c in cols_]
         st = acc.state()
         keys = st.pop("keys")
-
-        def hv(res, user=user_having):
-            m = np.asarray(res["count"]) > 0
-            if user is not None:
-                m = m & np.asarray(user(res)).astype(bool)
-            return m
-
-        self._group = (None, len(keys), agg, hv)
+        self._group = (None, len(keys), agg, self._group_having())
+        self._group_take = None
         if len(cols_) == 1:
             self._gk_decode = lambda gids, keys=keys: [
                 keys.astype(dts[0])[gids]]
@@ -2582,7 +2583,8 @@ class Query:
                     discovered = self._discover_group_keys(session,
                                                            device)
                     self._install_group_keys(discovered)
-                except _GroupSpill:
+                except GroupSpill as e:
+                    self._group_overflow(e)
                     spill = True
             else:
                 discovered = getattr(self, "_group_discovered", None)

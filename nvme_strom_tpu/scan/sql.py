@@ -77,9 +77,9 @@ Mapping (each SQL shape -> the Query terminal that serves it):
 * sole MIN(c) / MAX(c), no GROUP -> ``top_k(c, 1)`` (index-served when
   a sidecar is fresh)
 * sole COUNT(DISTINCT c)         -> ``count_distinct(c)``
-* GROUP BY c[, c2]               -> ``group_by_cols`` (value-keyed,
-  keys discovered; HAVING composes; SUM/AVG of expressions ride
-  ``agg_exprs``)
+* GROUP BY c[, c2]               -> ``group_by_cols`` (value-keyed:
+  keys found during the aggregation scan, or from a fresh sidecar;
+  HAVING composes; SUM/AVG of expressions ride ``agg_exprs``)
 * ORDER BY c[, c2] [DESC]        -> ``order_by`` (sidecar-served when
   fresh; other selected columns fetched by position); ORDER BY an
   aggregate + LIMIT on grouped results = top-N groups

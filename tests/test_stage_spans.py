@@ -204,10 +204,12 @@ def _traced_query(q, tdir, caller="caller.query"):
 
 
 def test_group_discover_stage_nests_its_scan(tmp_path):
-    """Value-keyed GROUP BY's key discovery is one ``group_discover``
-    entry: a ``strom.group_discover`` span holding the discovery scan's
-    read and batch stages, before the aggregation pass's own.  From a
-    fresh sidecar it is still one entry, with no table read inside."""
+    """Value-keyed GROUP BY finds its keys inside the aggregation scan:
+    one ``group_discover`` entry, the bootstrap of the key set from the
+    first batch, is a ``strom.group_discover`` span nested in that
+    batch's ``staging_retire``, between the first read and the second;
+    each batch is read once.  From a fresh sidecar it is still one
+    entry, with no table read inside."""
     import numpy as np
 
     from nvme_strom_tpu.scan.heap import HeapSchema, build_heap_file
@@ -233,14 +235,18 @@ def test_group_discover_stage_nests_its_scan(tmp_path):
          for k, v in stats.snapshot().counters.items()}
     assert out["count"].sum() == n
     assert d["nr_group_discover"] == 1 and d["clk_group_discover"] > 0
-    assert d["nr_vfs_read"] == d["nr_kernel_dispatch"] == 4
+    # two batches, each read once; the bootstrap's gather is the third
+    # kernel call, and no batch missed a key
+    assert d["nr_vfs_read"] == 2 and d["nr_kernel_dispatch"] == 3
+    assert d["nr_group_key_grow"] == d["nr_group_rerun"] == 0
     (g0, g1), = [(a, b) for a, b, nm in line
                  if nm == "strom.group_discover"]
-    reads = [(a, b) for a, b, nm in line if nm == "strom.vfs_read"]
-    inside = [r for r in reads if g0 <= r[0] and r[1] <= g1]
-    assert len(inside) == 2 and all(a > g1 for a, _ in
-                                    set(reads) - set(inside))
-    assert d["clk_group_discover"] >= sum(b - a for a, b in inside)
+    reads = sorted((a, b) for a, b, nm in line if nm == "strom.vfs_read")
+    retires = sorted((a, b) for a, b, nm in line
+                     if nm == "strom.staging_retire")
+    assert len(reads) == len(retires) == 2
+    assert retires[0][0] <= g0 and g1 <= retires[0][1]
+    assert reads[0][1] <= g0 and g1 <= reads[1][0]
     build_index(path, schema, (0, 1))
     before = dict(stats.snapshot().counters)
     out2 = query().run()

@@ -107,10 +107,12 @@ def test_decode_pallas_compiles(one_chip, tmp_path):
 def test_group_efn_compiles_at_the_q1_shape(one_chip):
     """TPC-H Q1's grouped expression kernel on one 8 MiB batch of the
     full-width LINEITEM heap (int64 decimals, x64): GROUP BY two flag
-    columns (three and two values, four pairs plus the sentinel), five
-    int64 sums.  The per-group masks must fuse into the reductions, not
-    materialize a (rows, groups) array per sum."""
+    columns (four pairs in a capacity-4 key table, passed as arguments as
+    the one-pass scan passes it, plus the sentinel), five int64 sums.
+    The per-group masks must fuse into the reductions, not materialize a
+    (rows, groups) array per sum."""
     from nvme_strom_tpu.ops.groupby import make_group_efn
+    from nvme_strom_tpu.scan.group_keys import lookup_group_ids
     from nvme_strom_tpu.scan.sql import _eval_expr
     was = jax.config.jax_enable_x64
     jax.config.update("jax_enable_x64", True)
@@ -118,14 +120,9 @@ def test_group_efn_compiles_at_the_q1_shape(one_chip):
         dts = ("int32",) * 4 + ("int64",) * 4 + ("int32",) * 5 + \
             ("int64",) * 12
         schema = HeapSchema(n_cols=25, visibility=False, dtypes=dts)
-        u0 = jnp.asarray([65, 78, 82], jnp.int32)
-        u1 = jnp.asarray([70, 79], jnp.int32)
-        table = jnp.asarray([[0, 4], [1, 2], [4, 3]], jnp.int32)
 
-        def key_fn(cols):
-            i0 = jnp.clip(jnp.searchsorted(u0, cols[8]), 0, 2)
-            i1 = jnp.clip(jnp.searchsorted(u1, cols[9]), 0, 1)
-            return table[i0, i1]
+        def key_fn(cols, tabs, ids):
+            return lookup_group_ids(cols, [8, 9], tabs, ids, ids.shape[0])
 
         disc = ("bin", "*", ("col", 5), ("bin", "-", ("lit", 100),
                                         ("col", 6)))
@@ -137,7 +134,8 @@ def test_group_efn_compiles_at_the_q1_shape(one_chip):
                                 for e in trees],
             [np.int64(0)] * 5, [np.dtype(np.int64)] * 5,
             predicate=lambda c: c[10] <= 10500)
-        c = fn.lower(_pages(one_chip, 1024)).compile()
+        tab = _sds((4,), jnp.int32, one_chip)
+        c = fn.lower(_pages(one_chip, 1024), (tab, tab), tab).compile()
         assert c.memory_analysis().temp_size_in_bytes < (64 << 20)
     finally:
         jax.config.update("jax_enable_x64", was)
