@@ -130,9 +130,8 @@ class HillClimber:
 
     Rejected markers are the hysteresis: a settled trajectory never
     re-probes a direction whose outcome it has already measured at the
-    current operating point, so it cannot oscillate (the
-    no-reversals-in-the-last-epochs contract the autotune-gate
-    asserts).  Accepted steps also mark the opposite direction rejected
+    current operating point, so it cannot oscillate (no reversals once
+    settled).  Accepted steps also mark the opposite direction rejected
     — the climb just came from there and measured it worse.  Idle
     epochs defer evaluation; a freeze (the health machine owns the
     stripe) reverts any outstanding probe and suspends probing, while
@@ -145,7 +144,7 @@ class HillClimber:
         self.min_gain = float(min_gain)
         self.p99_tol = float(p99_tol)
         self.cooldown = int(cooldown)
-        #: per-epoch event tuples — the gate's knob-trajectory record
+        #: per-epoch event tuples — the knob-trajectory record
         self.history: List[list] = []
         self._probe: Optional[tuple] = None  # (family, dir, {m: old})
         self._baseline: Optional[Reading] = None
@@ -284,8 +283,8 @@ class AutoTuner:
     ``readahead_budget_mb_s`` are read once at Session construction
     (the recorder/cache configure() convention); with both off the
     session pays one predicted branch per hot-path site and no thread
-    is spawned.  ``step_epoch()`` is public so the autotune-gate and
-    tests drive epochs synchronously and deterministically."""
+    is spawned.  ``step_epoch()`` is public so tests drive epochs
+    synchronously and deterministically."""
 
     #: token-bucket burst: this many seconds of budget may be issued
     #: back-to-back before shaping bites (floor 1 MiB)
@@ -433,8 +432,8 @@ class AutoTuner:
     def step_epoch(self) -> None:
         """One controller epoch: sample sensors, run the climber, apply
         knob movements, then run one readahead issue pass.  Public so
-        the gate and unit tests drive it synchronously; the background
-        thread calls exactly this."""
+        tests drive it synchronously; the background thread calls
+        exactly this."""
         if self.enabled:
             self._tune_epoch()
         if self.ra_active:

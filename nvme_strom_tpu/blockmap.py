@@ -14,8 +14,8 @@ Three properties matter and are all enforced here:
   bytes-on-device the command would read; any request touching one rides
   the O_DIRECT lanes of the same task instead (the per-extent split,
   exactly like the PR 9 cache hit/miss split).  A filesystem that lies in
-  FIEMAP (see deploy checklist item 23) is caught by the passthru gate's
-  byte-identity check, not trusted here.
+  FIEMAP (see deploy checklist item 23) is caught by a byte-identity
+  check against the O_DIRECT read, not trusted here.
 * **Cache per generation.**  Mappings are cached per path keyed on
   ``(st_ino, st_size, st_mtime_ns)``; a write-back through the framework's
   own ladder calls :func:`invalidate` at the same site that invalidates

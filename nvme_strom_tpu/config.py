@@ -484,8 +484,8 @@ class Config:
                 help="capacity of the HBM tier of the unified extent "
                      "space (serving.hbm_tier): extents the RAM tier "
                      "touches twice migrate up into device-resident "
-                     "buffers (exclusive under tier_unified — the RAM "
-                     "copy is surrendered) and are served with no host "
+                     "buffers (exclusively — the RAM copy is "
+                     "surrendered) and are served with no host "
                      "memcpy at all; eviction demotes the bytes back "
                      "into the RAM tier.  0 (default) disables the "
                      "tier entirely — one branch per task.  Read at "
@@ -505,15 +505,6 @@ class Config:
                 maxval=16 << 20, alias_of="tier_kv_block_bytes",
                 help="alias of tier_kv_block_bytes (pre-unification "
                      "name)"))
-        reg(Var("tier_unified", True, "bool",
-                help="one placement/migration engine across HBM → "
-                     "pinned RAM → SSD (tiering.extent_space): second-"
-                     "touch promotion migrates extents up EXCLUSIVELY "
-                     "(the RAM copy is surrendered, so the tiers pool "
-                     "capacity), HBM victims demote down into RAM.  "
-                     "false reverts to three isolated tiers — no "
-                     "promotion, evictions drop — the A/B baseline "
-                     "bench.py --tiering measures against"))
         # resident-data integrity domain (ISSUE 16): checksummed tiers,
         # background scrub, pressure-driven degradation
         reg(Var("integrity", "off", "str",
@@ -587,8 +578,8 @@ class Config:
                 help="per-task span tracing into the flight recorder: "
                      "'off' costs one branch per event site and records "
                      "nothing, 'sampled' traces 1-in-N tasks (N from "
-                     "trace_sample_rate; the production setting — "
-                     "overhead gated <=3% by `make trace-gate`), 'all' "
+                     "trace_sample_rate; the production setting), "
+                     "'all' "
                      "traces every task (debugging/chaos).  Read at "
                      "Session construction (trace.recorder.configure())",
                 validate=_check_trace_policy))

@@ -20,8 +20,8 @@ the engine's per-member lanes instead:
   head item needs the fewest whole rounds dispatches next (the classic
   virtual-rounds trick, so one pass computes the next dispatch instead of
   spinning empty rounds).  Over any busy interval tenants receive bytes
-  proportional to their weights within one quantum's slack — the 3:1
-  fairness the qos-gate asserts.
+  proportional to their weights within one quantum's slack (the 3:1
+  split tests/test_daemon.py asserts end to end).
 
 The scheduler is deliberately engine-agnostic: it orders opaque
 :class:`WorkItem` objects and knows nothing about sockets or sessions, so

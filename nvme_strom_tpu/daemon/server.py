@@ -155,8 +155,8 @@ class StromDaemon:
 
     ``allow_fake`` additionally accepts dict source specs naming the
     loopback :class:`~nvme_strom_tpu.testing.FakeNvmeSource` — the
-    deterministic latency-bound backend the qos-gate and tests schedule
-    against; never enable it on a production socket.
+    deterministic latency-bound backend the tests schedule against;
+    never enable it on a production socket.
     """
 
     def __init__(self, socket_path: Optional[str] = None, *,

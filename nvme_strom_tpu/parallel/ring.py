@@ -118,8 +118,8 @@ def ring_permute_step(block, *, axis: str, ring: int,
 #: compiled ring programs keyed by (mesh, axis, shape, dtype, transport).
 #: The sharded loader and the cold-start handshake call per batch; a
 #: fresh closure per call would defeat jax's jit cache and pay a full
-#: retrace each time — on the latency-bound gate the retrace would cost
-#: more than the I/O being measured.  Meshes hash by value, so
+#: retrace each time — for a small batch the retrace would cost more
+#: than its I/O.  Meshes hash by value, so
 #: same-shape calls across Mesh instances share one program.
 _ring_jit_cache: dict = {}
 

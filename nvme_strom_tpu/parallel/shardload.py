@@ -18,9 +18,8 @@ redistribution never bounces through host exchange.
 Emulation note: a "host" here is a planning unit — on a real multi-host
 mesh it is one process (``jax.process_index()``) with its own NVMe set;
 on the virtual single-process mesh the loader runs one reader thread +
-engine session per virtual host, which is also exactly what the
-multichip gate scales (per-host submission windows are the bound on the
-latency-injected synthetic, so wall time divides by host count).
+engine session per virtual host (per-host submission windows are the
+bound on a latency-bound source, so wall time divides by host count).
 """
 
 from __future__ import annotations
@@ -122,8 +121,8 @@ def _read_host_shard(host: int, ids: List[int], source: Source,
 
 #: compiled redistribution programs keyed by (mesh, axis, rows_max,
 #: rows_per_dev, transport) — a fresh jit closure per load would retrace
-#: the ring scan every batch, and on the latency-bound gate the retrace
-#: dwarfs the I/O being measured.  Meshes hash by value.
+#: the ring scan every batch, and the retrace would dwarf the I/O of a
+#: small batch.  Meshes hash by value.
 _redistribute_cache: dict = {}
 
 

@@ -108,8 +108,8 @@ class HbmResidencyTier:
                     demoted.append(d)
         self._demote_to_host(demoted)
         # ONE placement engine: the extent space arms the RAM tier's
-        # second-touch promotion hook iff this tier is on and the space
-        # is unified — one branch when either is off
+        # second-touch promotion hook iff this tier is on — one branch
+        # when off
         extent_space.rewire()
 
     def clear(self) -> None:
@@ -267,9 +267,7 @@ class HbmResidencyTier:
     def _demote_to_host(self, demoted) -> None:
         """Demoted extents move DOWN through the unified space: capacity
         pressure migrates data into the RAM tier instead of dropping it
-        (a failed fill just means a future SSD re-read).  In split mode
-        (``tier_unified=false``) the space drops them — isolated tiers
-        do not migrate."""
+        (a failed fill just means a future SSD re-read)."""
         extent_space.demote_from_hbm(demoted)
 
     def _free_entry(self, e: _Entry) -> None:

@@ -83,7 +83,7 @@ class KvBlockPool:
     def __init__(self, session, spill, *, block_bytes: Optional[int] = None,
                  ram_blocks: int = 16, hbm_blocks: Optional[int] = None,
                  durable: bool = False) -> None:
-        bb = int(block_bytes or config.get("kv_block_bytes"))
+        bb = int(block_bytes or config.get("tier_kv_block_bytes"))
         if bb <= 0 or (bb & (bb - 1)):
             raise StromError(_errno.EINVAL,
                              f"block_bytes {bb} must be a power of two")
@@ -119,8 +119,8 @@ class KvBlockPool:
     # -- introspection -------------------------------------------------
 
     def residency(self) -> Dict[str, int]:
-        """Block counts per tier (the tpu_stat serving scoreboard and
-        the A/B bench read this)."""
+        """Block counts per tier (the tpu_stat serving scoreboard reads
+        this)."""
         with self._lock:
             out = {"hbm": 0, "ram": 0, "ssd": 0}
             for table in self._tables.values():

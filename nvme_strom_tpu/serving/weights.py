@@ -21,8 +21,8 @@ Per layer the flow is exactly PR 8's zero-copy landing ladder:
    pages (CPU), one H2D copy otherwise.
 
 Each retired layer emits a ``weight_stream`` span (submit→adopt) whose
-``layer`` arg lets the coldstart gate assert layer-ordered landing from
-the flight recorder; the aggregate landing rate is published as the
+``layer`` arg lets tests assert layer-ordered landing from the flight
+recorder; the aggregate landing rate is published as the
 ``coldstart_bytes_per_sec`` gauge.
 """
 
@@ -250,7 +250,7 @@ def stream_weights(path: str, *, session=None, source=None, device=None,
     (``weight_stream_depth`` default).  ``verify`` recomputes each
     leaf's crc32c against the manifest before adoption (PR 11; leaves
     without a stored checksum are skipped).  *source* overrides the
-    file source (the coldstart gate injects a latency-bound fake)."""
+    file source (tests inject the loopback fake)."""
     import jax
     from ..data.checkpoint import checkpoint_info
     from ..engine import Session, open_source
@@ -340,10 +340,9 @@ def stream_weights_sharded(path: str, *, hosts: Optional[int] = None,
     has landed somewhere.  Each host's spans adopt onto that host's
     device, so the landing is per-host HBM.  Wall time divides by the
     host count when the stream is latency-bound (per-host submission
-    windows run in parallel), which is what the multichip gate holds
-    the line on.  ``source_factory(h)`` opens host *h*'s local view of
-    the checkpoint (the gate injects latency-bound fakes); default is
-    ``open_source(path)`` per host."""
+    windows run in parallel).  ``source_factory(h)`` opens host *h*'s
+    local view of the checkpoint (tests inject loopback fakes); default
+    is ``open_source(path)`` per host."""
     import jax
     from ..data.checkpoint import checkpoint_info
     from ..engine import Session, open_source

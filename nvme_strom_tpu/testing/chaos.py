@@ -458,8 +458,10 @@ def scenario_scrub_heal(rng: random.Random, dirpath: str) -> str:
             # debit provably comes from the page-in verify
             config.set("scrub_bytes_per_sec", 0)
             repairs_a = _counter("nr_scrub_repair")
-            pool = KvBlockPool(sess, spill, block_bytes=bbk, ram_blocks=2,
-                               hbm_blocks=0)
+            # the pre-unification alias still sizes the pool
+            config.set("kv_block_bytes", bbk)
+            pool = KvBlockPool(sess, spill, ram_blocks=2, hbm_blocks=0)
+            assert pool.block_bytes == bbk
 
             def pat(i: int) -> bytes:
                 return bytes([(i * 7 + 1) % 256]) * bbk

@@ -19,7 +19,7 @@ bytes the planner asked for is a hard error, never a wrong-bytes read.
 Fault injection rides the attached source's :class:`FaultPlan` keyed by
 *file* offset (reverse-mapped from the device offset), so a fault tier
 fires identically whether the request went passthrough or O_DIRECT —
-the property the passthru gate's chaos phase depends on.
+the property the passthrough fail-stop tests depend on.
 """
 
 from __future__ import annotations

@@ -10,10 +10,9 @@ HBM → pinned RAM → SSD.  The per-tier stores stay where they grew up —
   (retry/hedge/mirror/checksum) healed the bytes, lands in the RAM tier
   through :meth:`ExtentSpace.fault_fill`;
 * **promotion** — the RAM tier's second-touch (ARC t1→t2) transition
-  hands the extent UP; under ``tier_unified`` (the default) the move is
-  *exclusive*: the RAM copy is surrendered (:meth:`yield_up` on the
-  tier) so HBM + RAM behave as one capacity pool instead of
-  double-caching the hot set;
+  hands the extent UP, and the move is *exclusive*: the RAM copy is
+  surrendered (:meth:`yield_up` on the tier) so HBM + RAM behave as one
+  capacity pool instead of double-caching the hot set;
 * **demotion** — HBM eviction victims move DOWN into the RAM tier; RAM
   eviction victims drop to the SSD-backed tier (the file itself — a
   future read is a demand fault, not data loss);
@@ -31,10 +30,6 @@ the tree onto this surface: tier internals (``lookup``/``fill``/
 ``admit``/``drop``/``promote_hook``/``invalidate_*``) outside this
 module and the two policy plugins are findings.
 
-Setting ``tier_unified = false`` reverts to three isolated tiers (no
-promotion, HBM evictions drop instead of demoting) — the A/B baseline
-``bench.py --tiering`` measures the unified engine against.
-
 The module-global ``extent_space`` follows the one-branch-when-off
 contract of the tiers it drives: ``configure()`` re-reads the capacity
 Vars once, hot paths check the plain per-tier ``active`` attributes.
@@ -45,7 +40,6 @@ from __future__ import annotations
 import os
 from typing import Dict, Optional, Sequence, Tuple
 
-from .config import config
 from .stats import stats
 from .trace import recorder as _trace
 from .integrity import domain as _integrity
@@ -150,7 +144,6 @@ class ExtentSpace:
     _ORDER = ("hbm", "ram")
 
     def __init__(self) -> None:
-        self.unified = True
         self._tiers: Dict[str, object] = {}
 
     # -- registry ------------------------------------------------------
@@ -184,22 +177,20 @@ class ExtentSpace:
         hbm_tier.configure()          # calls rewire() itself
 
     def clear_tiers(self) -> None:
-        """Drop every resident extent in every tier (test/gate reset)."""
+        """Drop every resident extent in every tier (test reset)."""
         for t in self._tiers.values():
             t.clear()
 
     def rewire(self) -> None:
         """Re-arm the inter-tier transitions after any tier's
         ``configure()``: the RAM tier's second-touch hook points at
-        :meth:`_promote_from_ram` only while the HBM tier is on AND the
-        space is unified — one branch when off, and ``tier_unified =
-        false`` reverts to three isolated tiers (the A/B baseline)."""
-        self.unified = bool(config.get("tier_unified"))
+        :meth:`_promote_from_ram` only while the HBM tier is on — one
+        branch when off."""
         ram = self._tiers.get("ram")
         hbm = self._tiers.get("hbm")
         if ram is None:
             return
-        on = hbm is not None and hbm.active and self.unified
+        on = hbm is not None and hbm.active
         ram.promote_hook = self._promote_from_ram if on else None
 
     # -- identity ------------------------------------------------------
@@ -282,10 +273,7 @@ class ExtentSpace:
         """HBM eviction victims move DOWN: each ``(key, data,
         source_ref)`` re-enters the RAM tier (a failed fill just means a
         future SSD re-read — the fault ladder is the floor of the
-        hierarchy).  Split mode drops instead: isolated tiers do not
-        migrate, which is exactly the baseline the tier gate beats."""
-        if not self.unified:
-            return
+        hierarchy)."""
         ram = self._tiers.get("ram")
         if ram is None:
             return

@@ -1,11 +1,13 @@
-"""Self-driving data-path tests (ISSUE 18, `make autotune-gate`).
+"""Self-driving data-path tests (ISSUE 18, `autotune` marker).
 
 Covers the controller contracts hardware-free: monotone hill-climb to
 the knob bound, p99-regression step-back, hysteresis (a settled
-trajectory never oscillates), health-machine freeze, stride and
-successor prediction, the token-bucket prefetch budget, ARC ghost-list
-isolation of speculative fills, declared knob bounds, and the
-everything-off inertness contract (one predicted branch, no counters).
+trajectory never oscillates), health-machine freeze (unit-level and on
+a session whose mirrored member fail-stops), stride and successor
+prediction, strided-scan readahead hits, the token-bucket prefetch
+budget, ARC ghost-list isolation of speculative fills, declared knob
+bounds, and the everything-off inertness contract (one predicted
+branch, no counters).
 """
 
 import os
@@ -350,3 +352,112 @@ def test_hedge_family_disarmed_under_policy_off(_snap):
         sess._tuner.stop()
         sess._tuner._seed_members()
         assert not sess._tuner._climber.family("hedge_ms").armed
+
+
+def _bad_statics():
+    """A planning window of 2 and a 256K request cap: knobs the
+    controller has room to move."""
+    config.set("io_backend", "python")
+    config.set("submit_window", 2)
+    config.set("member_queue_depth", 2)
+    config.set("dma_max_size", 256 << 10)
+    config.set("cache_bytes", 0)
+    config.set("cache_arbitration", False)
+    config.set("hedge_policy", "off")
+    config.set("readahead", False)
+
+
+def _read_ids(sess, src, ids):
+    handle, buf = sess.alloc_dma_buffer(len(ids) * CHUNK)
+    try:
+        res = sess.memcpy_ssd2ram(src, handle, list(ids), CHUNK)
+        sess.memcpy_wait(res.dma_task_id, timeout=60.0)
+        return bytes(buf.view()[:len(ids) * CHUNK])
+    finally:
+        sess.unmap_buffer(handle)
+
+
+def test_member_failstop_freezes_tuning(_snap, tmp_path):
+    """A mirrored member fail-stops mid-run: the controller freezes
+    (counted, no knob step in any later epoch) while the mirror keeps
+    serving the same bytes."""
+    from nvme_strom_tpu.testing import FakeStripedNvmeSource, FaultPlan
+
+    nchunks = 32
+    paths = []
+    for i in range(2):
+        p = os.path.join(str(tmp_path), f"frz{i}.bin")
+        make_test_file(p, nchunks * CHUNK)    # paired: one member's worth
+        paths.append(p)
+    _bad_statics()
+    config.set("autotune", True)
+    config.set("quarantine_after", 2)
+    config.set("quarantine_s", 60.0)
+    plan = FaultPlan()
+    src = FakeStripedNvmeSource(paths, CHUNK, fault_plan=plan,
+                                force_cached_fraction=0.0, mirror="paired")
+    try:
+        with Session() as sess:
+            sess._tuner.stop()      # drive epochs synchronously
+            ref = _read_ids(sess, src, range(nchunks))
+            for _ in range(4):
+                assert _read_ids(sess, src, range(nchunks)) == ref
+                sess._tuner.step_epoch()
+            plan.failstop_member = 0
+            plan.failstop_after = 0
+            freeze0 = stats.snapshot(reset_max=False).counters.get(
+                "nr_autotune_freeze", 0)
+            nhist = len(sess._tuner._climber.history)
+            for _ in range(4):
+                assert _read_ids(sess, src, range(nchunks)) == ref
+                sess._tuner.step_epoch()
+            later = sess._tuner._climber.history[nhist:]
+    finally:
+        src.close()
+    frozen = stats.snapshot(reset_max=False).counters.get(
+        "nr_autotune_freeze", 0) - freeze0
+    assert frozen > 0, "fail-stop never froze the controller"
+    assert not [k for ep in later for (k, *_r) in ep if k == "step"]
+
+
+@pytest.mark.parametrize("readahead", [True, False], ids=["on", "off"])
+def test_strided_scan_readahead(_snap, tmp_path, readahead):
+    """A scan read as sequential 4-chunk spans: with readahead on, the
+    predicted next spans are prefetched and at least half the demand
+    chunks hit; with it off, no readahead counter moves and the scan
+    stays exactly cold (no hit, one fill per chunk)."""
+    from nvme_strom_tpu.testing.fake import expected_bytes
+
+    nchunks, span = 32, 4
+    path = os.path.join(str(tmp_path), "ra.bin")
+    make_test_file(path, nchunks * CHUNK)
+    want = expected_bytes(0, nchunks * CHUNK)
+    _bad_statics()
+    config.set("cache_bytes", 64 << 20)
+    config.set("readahead", readahead)
+    config.set("readahead_budget_mb_s", 64.0)
+    residency_cache.configure()
+    residency_cache.clear()
+    names = ("nr_readahead_fill", "nr_readahead_hit", "nr_readahead_skip",
+             "bytes_readahead", "nr_cache_hit", "nr_cache_miss",
+             "nr_cache_fill")
+    before = stats.snapshot(reset_max=False).counters
+    src = FakeNvmeSource(path, force_cached_fraction=0.0)
+    try:
+        with Session() as sess:
+            assert sess._tuner.active == readahead
+            sess._tuner.stop()      # drive the issue loop synchronously
+            for first in range(0, nchunks, span):
+                got = _read_ids(sess, src, range(first, first + span))
+                assert got == want[first * CHUNK:(first + span) * CHUNK]
+                sess._tuner.step_epoch()
+    finally:
+        src.close()
+    after = stats.snapshot(reset_max=False).counters
+    d = {n: after.get(n, 0) - before.get(n, 0) for n in names}
+    if readahead:
+        hits = d["nr_cache_hit"]
+        assert hits / max(hits + d["nr_cache_miss"], 1) >= 0.5, d
+    else:
+        assert not any(d[n] for n in names[:4]), d
+        assert d["nr_cache_hit"] == 0 and d["nr_cache_fill"] == nchunks, d

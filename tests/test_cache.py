@@ -1,9 +1,10 @@
-"""Cross-query residency-cache tests (ISSUE 9, `make cache-gate`).
+"""Cross-query residency-cache tests (ISSUE 9, `cache` marker).
 
 Covers the tentpole contracts hardware-free: hit/miss split correctness
-through the engine, ARC scan resistance, lease pinning vs concurrent
-eviction, invalidation on the write-back path, degraded-mode fills
-through a quarantined member's mirror, and the cache-off no-op.
+through the engine, byte identity under eviction churn, ARC scan
+resistance, lease pinning vs concurrent eviction, invalidation on the
+write-back path, degraded-mode fills through a quarantined member's
+mirror, and the cache-off no-op.
 """
 
 import os
@@ -93,6 +94,37 @@ def test_partial_hit_reorder(tmp_data_file):
             assert bytes(host) == expected_bytes(0, 8 * CHUNK)
     finally:
         src.close()
+
+
+def test_eviction_churn_stays_byte_identical(tmp_path):
+    """Capacity a quarter of the table: every pass churns the ARC lists,
+    the reordered stream (hits are tail-packed) stays identical and
+    residency never exceeds capacity."""
+    import numpy as np
+
+    from nvme_strom_tpu.engine import reorder_chunks
+    nchunks, cap = 16, 4 * CHUNK
+    path = str(tmp_path / "evict.bin")
+    make_test_file(path, nchunks * CHUNK)
+    _enable(cap)
+    src = FakeNvmeSource(path, force_cached_fraction=0.0)
+    before = stats.snapshot(reset_max=False)
+    try:
+        with Session() as sess:
+            handle, buf = sess.alloc_dma_buffer(nchunks * CHUNK)
+            want = list(range(nchunks))
+            for _ in range(3):
+                res = sess.memcpy_ssd2ram(src, handle, want, CHUNK)
+                sess.memcpy_wait(res.dma_task_id)
+                host = reorder_chunks(
+                    np.frombuffer(buf.view()[:nchunks * CHUNK], np.uint8),
+                    CHUNK, res.chunk_ids, want)
+                assert bytes(host) == expected_bytes(0, nchunks * CHUNK)
+    finally:
+        src.close()
+    after = stats.snapshot(reset_max=False)
+    assert _delta(before, after, "nr_cache_evict") > 0
+    assert residency_cache.resident_bytes() <= cap
 
 
 # ---------------------------------------------------------------------------

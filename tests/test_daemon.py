@@ -5,8 +5,9 @@ real Unix socket: session lifecycle with byte identity through the
 shared memfd buffer, protocol-version fail-closed, admission rejection
 under quota, orphan reaping (abrupt disconnect AND a SIGKILLed
 subprocess client), max-session admission, token-bucket shaping, the
-QoS scheduler's class/weight policy at the unit level, and the
-daemon's stats/trace/prometheus surface.
+QoS scheduler's class/weight policy at the unit level and end to end
+(bytes delivered under DRR weights), and the daemon's
+stats/trace/prometheus surface.
 """
 
 from __future__ import annotations
@@ -309,6 +310,51 @@ def test_scheduler_drop_session():
     assert sched.depth() == 1
     assert sched.next_item(timeout=1).tenant == "b"
     sched.close()
+
+
+def test_weighted_fairness_end_to_end(tmp_path):
+    """Real daemon, one dispatcher, 3:1-weighted tenants with their whole
+    backlog queued before the first dispatch: while both are still
+    backlogged the bytes delivered split within 25% of 3:1."""
+    n_tasks, per_task = 128, 4
+    path = str(tmp_path / "fair.bin")
+    make_test_file(path, n_tasks * per_task * CHUNK)
+    # the per-request latency makes the lane the bottleneck, so the
+    # split is set by the scheduler while both queues are non-empty
+    spec = {"kind": "fake", "path": path, "latency_s": 0.004,
+            "force_cached_fraction": 0.0}
+    d = StromDaemon(str(tmp_path / "f.sock"), allow_fake=True,
+                    dispatchers=0).start()
+    try:
+        heavy = DaemonSession(d.socket_path, tenant="heavy", weight=3.0)
+        light = DaemonSession(d.socket_path, tenant="light", weight=1.0)
+        mon = DaemonSession(d.socket_path, tenant="_monitor")
+        try:
+            for sess in (heavy, light):
+                src = sess.open_source(spec)
+                h, _buf = sess.alloc_dma_buffer(per_task * CHUNK)
+                for t in range(n_tasks):
+                    sess.memcpy_ssd2ram(
+                        src, h, list(range(t * per_task, (t + 1) * per_task)),
+                        CHUNK)
+            d.start_dispatchers(1)
+            # heavy drains near 4/3 * n_tasks done; sample well before
+            deadline = time.monotonic() + 120.0
+            while True:
+                st = mon.daemon_stat()["tenants"]
+                done = sum(st[t]["tasks"] for t in ("heavy", "light"))
+                if done >= n_tasks:
+                    break
+                assert time.monotonic() < deadline, f"stalled at {done}"
+                time.sleep(0.002)
+            hb, lb = st["heavy"]["bytes"], st["light"]["bytes"]
+            assert lb > 0, "light tenant starved outright"
+            assert 3.0 * 0.75 <= hb / lb <= 3.0 * 1.25, (hb, lb)
+        finally:
+            for sess in (heavy, light, mon):
+                sess.close()
+    finally:
+        d.close()
 
 
 def test_token_bucket_shaping_throttles_end_to_end(tmp_path, data_file):

@@ -1,12 +1,13 @@
-"""Zero-copy landing tests (ISSUE 8, `make landing-gate`).
+"""Zero-copy landing tests (ISSUE 8, `landing` marker).
 
 The tentpole contract: on an eligible command the engine's reads land
 directly in an owned :class:`LandingBuffer` the device array aliases —
 no staging hop — with per-command fallback to the staged ring recorded
 by reason.  Covers plan-time eligibility routing, the partial-tail slot
 riding both paths, fixed-buffer re-registration across a mid-task lane
-scale-out, `_old_engines` drain at close, and direct-vs-staged byte
-identity under transient faults.
+scale-out, `_old_engines` drain at close, bytes touched per byte
+delivered on the direct path, and direct-vs-staged byte identity down
+the fault ladder (transient EIO, a torn read, hedged legs).
 """
 
 import numpy as np
@@ -19,8 +20,9 @@ from nvme_strom_tpu import Session, StromError, config, stats
 from nvme_strom_tpu.engine import PlainSource, StripedSource
 from nvme_strom_tpu.hbm import (HbmRegistry, StagingPipeline,
                                 load_file_to_device, plan_landing)
-from nvme_strom_tpu.testing import (FakeNvmeSource, FaultPlan,
-                                    make_test_file)
+from nvme_strom_tpu.stats import bytes_touched_ratio
+from nvme_strom_tpu.testing import (FakeNvmeSource, FakeStripedNvmeSource,
+                                    FaultPlan, make_test_file)
 
 pytestmark = pytest.mark.landing
 
@@ -241,8 +243,38 @@ def test_fixed_registration_survives_lane_scale_out(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# fault-ladder identity (compact pytest leg; the full ladder runs in
-# `python -m nvme_strom_tpu.testing.landing_gate`)
+# bytes touched per byte delivered
+# ---------------------------------------------------------------------------
+
+def test_direct_landing_touches_each_byte_once(tmp_path):
+    """On the direct path the engine's reads land in the buffer the
+    device array aliases: the command touches at most 1.05 bytes per
+    byte delivered (no staging copy) and matches the staged ring."""
+    size, chunk = 4 << 20, 1 << 20
+    path = str(tmp_path / "ratio.bin")
+    make_test_file(path, size)
+    # a freshly written file is page-cached: arbitration would route
+    # every chunk write-back and move no DMA
+    config.set("cache_arbitration", False)
+    reg = HbmRegistry()
+    config.set("landing", "staged")
+    with Session() as sess, PlainSource(path) as src:
+        _res, staged = _pipeline_load(sess, reg, src, size, chunk)
+    config.set("landing", "direct")
+    before = _counters()
+    with Session() as sess, PlainSource(path) as src:
+        res, direct = _pipeline_load(sess, reg, src, size, chunk)
+    d = _delta(before)
+    assert res.landing == "direct"
+    assert direct == staged
+    assert d.get("nr_landing_direct", 0) >= 1
+    assert d.get("nr_landing_fallback", 0) == 0
+    ratio = bytes_touched_ratio(d)
+    assert ratio is not None and ratio <= 1.05, d
+
+
+# ---------------------------------------------------------------------------
+# fault-ladder identity
 # ---------------------------------------------------------------------------
 
 def test_direct_vs_staged_identity_under_transient_faults(tmp_path):
@@ -269,3 +301,77 @@ def test_direct_vs_staged_identity_under_transient_faults(tmp_path):
     assert direct == staged
     with open(path, "rb") as f:
         assert direct == f.read()
+
+
+def _striped_failstop(tmp_path):
+    """Two striped members, every 3rd read EIOs once (transient)."""
+    paths = []
+    for m in range(2):
+        p = str(tmp_path / f"tm{m}.bin")
+        make_test_file(p, 1 << 20, seed=m)
+        paths.append(p)
+    return (lambda: FakeStripedNvmeSource(
+        paths, stripe_chunk_size=CHUNK,
+        fault_plan=FaultPlan(fail_every_nth=3),
+        force_cached_fraction=0.0)), CHUNK, _expected_stream(paths, CHUNK)
+
+
+def _corrupt_once(tmp_path):
+    """A torn read that flips once and heals on re-read: the checksum
+    re-read tier repairs it."""
+    from nvme_strom_tpu.scan.heap import PAGE_SIZE, HeapSchema, build_heap_file
+
+    config.set("checksum_verify", True)
+    schema = HeapSchema(n_cols=2, visibility=False)
+    n = schema.tuples_per_page * 8
+    path = str(tmp_path / "co.heap")
+    build_heap_file(path, [np.arange(n, dtype=np.int32),
+                           (n - np.arange(n)).astype(np.int32)], schema)
+    with open(path, "rb") as f:
+        want = f.read()
+    return (lambda: FakeNvmeSource(
+        path,
+        fault_plan=FaultPlan(corrupt_once_offsets={2 * PAGE_SIZE + 99}),
+        force_cached_fraction=0.0)), PAGE_SIZE, want
+
+
+def _hedged(tmp_path):
+    """Hedged legs racing a slow member on a mirrored stripe."""
+    from nvme_strom_tpu.testing.chaos import (expected_mirrored_stream,
+                                              make_mirrored_members)
+
+    chunk = 128 << 10
+    paths = make_mirrored_members(str(tmp_path), n_pairs=1, size=1 << 20,
+                                  tag="hm")
+    config.set("hedge_policy", "fixed")
+    config.set("hedge_ms", 2.0)
+    return (lambda: FakeStripedNvmeSource(
+        paths, stripe_chunk_size=chunk,
+        fault_plan=FaultPlan(slow_member=1, slow_s=0.02),
+        force_cached_fraction=0.0, mirror="paired")), chunk, \
+        expected_mirrored_stream(paths, chunk)
+
+
+@pytest.mark.parametrize("ladder", [_striped_failstop, _corrupt_once,
+                                    _hedged],
+                         ids=["striped_failstop", "corrupt_once", "hedged"])
+def test_direct_vs_staged_identity_down_the_fault_ladder(tmp_path, ladder):
+    """Each rung of the fault ladder heals both landing paths to the
+    same bytes as the file's logical stream."""
+    make_src, chunk, want = ladder(tmp_path)
+
+    def load(mode):
+        config.set("landing", mode)
+        src = make_src()
+        reg = HbmRegistry()
+        try:
+            with Session() as sess:
+                res, got = _pipeline_load(sess, reg, src, src.size, chunk)
+            assert res.landing == mode
+        finally:
+            src.close()
+        return got
+
+    staged, direct = load("staged"), load("direct")
+    assert staged == want[:len(staged)]
+    assert direct == staged

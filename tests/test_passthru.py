@@ -1,4 +1,4 @@
-"""Raw NVMe passthrough tests (ISSUE 19, `make passthru-gate`).
+"""Raw NVMe passthrough tests (ISSUE 19, `passthru` marker).
 
 Covers the raw-command data path hardware-free: the blockmap's
 FIEMAP/synthetic extent resolution against the emulator's SLBA/NLB
@@ -6,9 +6,9 @@ oracle, per-extent eligibility splits for every refusing FIEMAP flag,
 LBA alignment shaving, generation caching + write-ladder invalidation,
 capability-probe refusal reasons down the failover ladder, the fault
 ladder (hedge wins, member fail-stop/health) riding over passthrough
-lanes, autotuner epochs on a passthrough workload, the
-zero-counters-when-pinned guarantee, SLBA drift detection, and the
-emulator's command validation.
+lanes, byte identity across a mixed passthrough + O_DIRECT split,
+autotuner epochs on a passthrough workload, the zero-counters-when-pinned
+guarantee, SLBA drift detection, and the emulator's command validation.
 """
 
 import os
@@ -376,13 +376,52 @@ def test_autotuner_epochs_on_passthru_lane(tmp_path):
         "controller saw a passthrough workload but never acted"
 
 
+def test_mixed_split_reads_byte_identical(tmp_path):
+    """A fragmented file with an UNWRITTEN hole and an INLINE tail reads
+    through the mixed passthrough + O_DIRECT plan: both lanes carry
+    bytes and the result matches the O_DIRECT-only read and the
+    generator oracle."""
+    _base_config()
+    nchunks = 8
+    size = nchunks * CHUNK
+    path = str(tmp_path / "split.bin")
+    make_test_file(path, size)
+    emu = PassthruEmulator(str(tmp_path / "split.img"))
+    # FIEMAP flags 0x800 = UNWRITTEN, 0x200 = DATA_INLINE
+    emu.provision(path, frag=4,
+                  ineligible=((CHUNK, 4096, 0x800),
+                              (5 * CHUNK + 512, 8192, 0x200)))
+    try:
+        with FakeNvmeSource(path, force_cached_fraction=0.0) as ref_src, \
+                Session() as sess:
+            odirect = _read_pass(sess, ref_src, nchunks)
+        before = stats.snapshot(reset_max=False)
+        src = FakeNvmeSource(path, force_cached_fraction=0.0)
+        emu.attach(src)
+        try:
+            with Session() as sess:
+                passthru = _read_pass(sess, src, nchunks)
+        finally:
+            src.close()
+    finally:
+        emu.close()
+    after = stats.snapshot(reset_max=False)
+    assert odirect == expected_bytes(0, size)
+    assert passthru == odirect
+    assert _counter_delta(before, after, "nr_passthru_dma") > 0
+    assert _counter_delta(before, after, "nr_passthru_refused_extent") > 0
+    assert 0 < _counter_delta(before, after, "bytes_passthru") < size
+
+
 # ---------------------------------------------------------------------------
 # zero-counters guarantee + drift + command validation
 # ---------------------------------------------------------------------------
 
-def test_pinned_ladder_moves_zero_passthru_counters(tmp_path):
+def _pinned_ladder_moves_zero_passthru_counters(tmp_path, backend):
+    """engine_backend pinned below the passthrough rung, emulator
+    attached: the same bytes move and no passthrough counter does."""
     _base_config()
-    config.set("engine_backend", "threadpool")
+    config.set("engine_backend", backend)
     size = 2 * CHUNK
     path = str(tmp_path / "pin.bin")
     make_test_file(path, size)
@@ -403,6 +442,15 @@ def test_pinned_ladder_moves_zero_passthru_counters(tmp_path):
              if (k.startswith("nr_passthru") or k == "bytes_passthru")
              and _counter_delta(before, after, k)}
     assert not dirty
+    assert emu.commands_served == 0
+
+
+def test_pinned_ladder_moves_zero_passthru_counters(tmp_path):
+    _pinned_ladder_moves_zero_passthru_counters(tmp_path, "threadpool")
+
+
+def test_pinned_uring_moves_zero_passthru_counters(tmp_path):
+    _pinned_ladder_moves_zero_passthru_counters(tmp_path, "uring")
 
 
 def test_slba_drift_is_a_hard_error(tmp_path):

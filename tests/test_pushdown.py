@@ -1,11 +1,12 @@
-"""Compute-pushdown tests (ISSUE 14, ``make pushdown-gate``).
+"""Compute-pushdown tests (ISSUE 14, ``pushdown`` marker).
 
 Codec round-trips against the pure-numpy oracle (per encoding, edges
 included), fused-kernel vs oracle identity (Pallas interpret mode and
 the XLA fallback), the planner's per-column host/chip/raw decision under
-forced transport rates, EXPLAIN's wire-byte prediction, and packed
-extents riding the residency tier (hits after eviction churn, logical
-accounting)."""
+forced transport rates, EXPLAIN's wire-byte prediction, packed extents
+riding the residency tier (hits after eviction churn, logical
+accounting) and the fault ladder (a fail-stopped member's packed
+extents served by its mirror)."""
 
 import os
 
@@ -374,3 +375,54 @@ def test_pushdown_counters_move(tmp_path):
         (b.get("nr_pushdown_decode_chip", 0)
          + b.get("nr_pushdown_decode_host", 0))
     assert a.get("bytes_wire_saved", 0) > b.get("bytes_wire_saved", 0)
+
+
+def test_packed_scan_fails_over_to_mirror(tmp_path):
+    """The packed sidecar striped over a mirrored pair loses member 0
+    mid-scan: its extents come from the pair partner, the member latches
+    FAILED, and the aggregate matches the unpacked truth."""
+    import shutil
+
+    from nvme_strom_tpu.cache import residency_cache
+    from nvme_strom_tpu.engine import Session
+    from nvme_strom_tpu.ops.decode_xla import make_decode_filter_fn_xla
+    from nvme_strom_tpu.scan.executor import TableScanner
+    from nvme_strom_tpu.testing import FakeStripedNvmeSource, FaultPlan
+
+    chunk = 64 << 10
+    (path, schema, meta), cols = _mixed_table(tmp_path, n=200_000)
+    with open(packed_path_for(path), "rb") as f:
+        blob = f.read()
+    blob += b"\0" * ((-len(blob)) % chunk)    # zero pages scan as no rows
+    m0, m1 = str(tmp_path / "pk0.bin"), str(tmp_path / "pk1.bin")
+    with open(m0, "wb") as f:
+        f.write(blob)
+    shutil.copyfile(m0, m1)
+    config.set("cache_bytes", 0)
+    config.set("cache_arbitration", False)
+    config.set("dma_max_size", chunk)
+    config.set("io_retries", 1)
+    config.set("canary_interval_s", 0.0)
+    residency_cache.configure()
+
+    def pred(c):
+        return c[0] > 7
+
+    src = FakeStripedNvmeSource(
+        [m0, m1], stripe_chunk_size=chunk,
+        fault_plan=FaultPlan(failstop_member=0, failstop_after=4),
+        force_cached_fraction=0.0, mirror="paired")
+    b = _counters()
+    try:
+        with Session() as sess:
+            out = TableScanner(src, schema, session=sess,
+                               chunk_size=chunk).scan_filter(
+                make_decode_filter_fn_xla(meta, pred))
+    finally:
+        src.close()
+    a = _counters()
+    count, sums = _oracle(cols, pred)
+    assert int(out["count"]) == count
+    assert [int(x) for x in out["sums"]] == sums
+    assert a.get("nr_mirror_read", 0) > b.get("nr_mirror_read", 0)
+    assert a.get("nr_member_failed", 0) > b.get("nr_member_failed", 0)

@@ -2,18 +2,21 @@
 
 Everything here runs single-process over the forced-CPU mesh (conftest
 pins ``xla_force_host_platform_device_count=8``): "hosts" are the
-planner's ownership units, each backed by its own engine session, which
-is exactly the posture the multichip gate scales.  What is asserted:
+planner's ownership units, each backed by its own engine session.  What
+is asserted:
 
 * the host-ownership partition is disjoint, exhaustive, and
   member-aligned on striped sources;
 * the sharded loader's redistributed (and gathered) bytes are identical
-  to a single-host ``load_pages_sharded`` of the same source;
+  to a single-host ``load_pages_sharded`` of the same source, and to the
+  file when every host reads through its own source;
 * the sharded cold-start lands a byte-identical model with layer-ordered
-  adoption per host;
+  adoption per host, at every host count;
 * cross-host KV migration is byte-identical, and a mid-migration
   destination failure rolls back leaving the source SSD-resumable.
 """
+
+import os
 
 import numpy as np
 import pytest
@@ -189,12 +192,85 @@ def test_shard_wait_histogram_populated(page_file):
         assert d["n"] >= 1 and d.get("p50_ns", 0) >= 0
 
 
+@pytest.mark.parametrize("hosts", [1, 2, 4])
+def test_multihost_gather_identity_with_per_host_sources(page_file, hosts):
+    """Every host reads its shard through its own source (the posture of
+    a real mesh, where each host owns its NVMe queues): the gathered
+    array equals the file bytes at every host count."""
+    from nvme_strom_tpu.parallel import load_pages_multihost
+    from nvme_strom_tpu.testing import FakeNvmeSource
+
+    # a page is one request, serialized per host session
+    config.set("queue_depth", 1)
+    config.set("dma_max_size", PAGE_SIZE)
+    config.set("coalesce_limit", 0)
+    opened = []
+
+    def factory(h):
+        src = FakeNvmeSource(page_file, force_cached_fraction=0.0)
+        opened.append(h)
+        return src
+
+    with open(page_file, "rb") as f:
+        data = f.read()
+    with PlainSource(page_file) as plan_src:
+        out = load_pages_multihost(plan_src, _mesh(), hosts=hosts,
+                                   source_factory=factory, gather=True)
+    assert np.asarray(out).tobytes() == data
+    assert sorted(opened) == list(range(hosts))
+
+
 # -- sharded cold-start ----------------------------------------------------
+
+_LAYER_BYTES = 256 << 10
+_N_LAYERS = 12
+
+
+def _make_checkpoint(dirpath):
+    """A 12-layer checkpoint, each layer one f32 leaf of _LAYER_BYTES."""
+    from nvme_strom_tpu.data.checkpoint import save_checkpoint
+
+    rng = np.random.default_rng(11)
+    tree = {"layers": [
+        {"w": rng.standard_normal(_LAYER_BYTES // 4).astype(np.float32)}
+        for _ in range(_N_LAYERS)]}
+    path = os.path.join(dirpath, "model.ckpt")
+    save_checkpoint(path, tree)
+    return path, tree
+
+
+def _check_tree(model, tree):
+    import jax.tree_util as jtu
+
+    for kp, leaf in jtu.tree_flatten_with_path(tree)[0]:
+        key = jtu.keystr(kp)
+        assert np.array_equal(np.asarray(model.leaf(key)),
+                              np.asarray(leaf)), \
+            f"streamed leaf {key} diverged from the checkpointed tree"
+
+
+@pytest.mark.parametrize("hosts", [1, 2])
+def test_sharded_coldstart_identity_with_per_host_sources(tmp_path, hosts):
+    """Each host streams its layers through its own source, one request
+    per layer, depth 1: the model lands byte-identical at every host
+    count."""
+    from nvme_strom_tpu.serving.weights import stream_weights_sharded
+    from nvme_strom_tpu.testing import FakeNvmeSource
+
+    path, tree = _make_checkpoint(str(tmp_path))
+    config.set("dma_max_size", _LAYER_BYTES)
+    model = stream_weights_sharded(
+        path, hosts=hosts, depth=1,
+        source_factory=lambda h: FakeNvmeSource(path,
+                                                force_cached_fraction=0.0))
+    try:
+        _check_tree(model, tree)
+    finally:
+        model.close()
+
 
 def test_sharded_coldstart_identity_and_layer_order(tmp_path):
     from nvme_strom_tpu.serving.weights import stream_weights_sharded
-    from nvme_strom_tpu.testing.coldstart_gate import (_check_tree,
-                                                       _make_checkpoint)
 
     path, tree = _make_checkpoint(str(tmp_path))
     config.set("trace_policy", "all")

@@ -572,46 +572,6 @@ def test_strom_query_join_heap_rejects_bad_table(tmp_path):
     assert "Traceback" not in out.stderr
 
 
-@pytest.mark.parametrize("platform", ["tpu", "cpu"])
-def test_bench_headline_requires_a_tpu(monkeypatch, platform):
-    """The headline keeps an ssd2tpu child's number only when the child
-    ran on a TPU; any other platform is an error, never a CPU row."""
-    import types
-
-    import bench
-    out = (f"platform: {platform} kind: some kind count: 1\n"
-           f"transferred: 1.00 GB in 1.00s  => 1.25 GB/s\n")
-    monkeypatch.setattr(bench.subprocess, "run", lambda *a, **k:
-                        types.SimpleNamespace(returncode=0, stdout=out,
-                                              stderr=""))
-    if platform == "tpu":
-        gbps, meta = bench._run_mode("f", [])
-        assert gbps == 1.25 and meta["device"]["kind"] == "some kind"
-    else:
-        with pytest.raises(RuntimeError, match="no TPU"):
-            bench._run_mode("f", [])
-
-
-def test_bench_lock_excludes_concurrent_capture(tmp_path, monkeypatch):
-    """Two capture runs must serialize on the bench lock: a smoke run
-    overlapping the matrix's ssd2tpu row once recorded 0.14 GB/s
-    against an adjacent clean 1.01 (round-4 contamination incident)."""
-    import fcntl
-
-    import bench
-    monkeypatch.setattr(bench, "LOCK_PATH", str(tmp_path / "b.lock"))
-    holder = bench.hold_bench_lock("first")
-    try:
-        second = open(bench.LOCK_PATH, "w")
-        with pytest.raises(OSError):
-            fcntl.flock(second, fcntl.LOCK_EX | fcntl.LOCK_NB)
-        second.close()
-    finally:
-        holder.close()
-    # released on close: a fresh holder acquires without blocking
-    bench.hold_bench_lock("second").close()
-
-
 def test_strom_query_cli_group_by_cols(tmp_path):
     """--group-by-cols groups by VALUES: key_cols in the JSON output,
     --having composes, conflicting terminals rejected."""

@@ -275,6 +275,10 @@ def test_concurrent_snapshots_do_not_consume_max_dma(tmp_path):
     """Plain snapshots observe the high-water mark without consuming it
     (N concurrent readers all see the same peak); only export() resets it
     to the current in-flight level."""
+    # any earlier Session in this process started the background
+    # exporter, whose export() is the legitimate resetter; stop it so
+    # only the readers below touch the mark (the next Session restarts it)
+    stats.stop_export()
     base = stats.snapshot().counters.get("max_dma_count", 0)
     stats.gauge_add("max_dma_count", 7)
     want = base + 7
