@@ -409,7 +409,14 @@ STAT_FIELDS: Tuple[str, ...] = (
     #                           plus its landing dispatch
     "nr_load_setup", "clk_load_setup",  # load_file_to_device's set-up
     #                           and tear-down (one count per load)
-    "nr_vfs_read", "clk_vfs_read",      # buffered reads of the VFS scan
+    "nr_vfs_read", "clk_vfs_read",      # the VFS scan's waits for its next
+    #                           batch from the read-ahead ring
+    "nr_vfs_read_blocked",    # of those, waits whose batch was not read
+    #                           yet on entry (the ring failed to hide it)
+    "nr_vfs_readahead", "clk_vfs_readahead",  # the ring's buffered reads,
+    #                           on its reader thread
+    "nr_vfs_readahead_fence_blocked",  # ring refills that waited for the
+    #                           transfer out of the slot to finish
     "nr_group_discover", "clk_group_discover",  # value-keyed GROUP BY's
     #                           key finding: the one-pass bootstrap and
     #                           growth steps (nested in staging_retire), or

@@ -3820,27 +3820,32 @@ class Query:
         """Buffered fallback below the planner threshold (the conventional
         path the reference leaves small tables on).  Reads through the
         Source abstraction, so multi-file stripe sets and live Source
-        objects scan identically to the direct path."""
+        objects scan identically to the direct path.  The 8 MiB batches
+        are read ahead on a reader thread into ``h2d_depth_max + 1``
+        reused buffers (:class:`.read_ring.ReadAheadRing`), joined before
+        an owned source closes."""
         import jax
 
+        from ..config import config as _cfg
+        from ..hbm.staging import safe_device_put
         from ..stats import stats
         from .executor import fold_results
+        from .read_ring import ReadAheadRing
         dev = device or jax.local_devices()[0]
         src, own = self._open_owned()
         try:
-            n_pages = src.size // PAGE_SIZE
-            batch = max((8 << 20) // PAGE_SIZE, 1)
             acc = None
-            for p0 in range(0, n_pages, batch):
-                n = min(batch, n_pages - p0)
-                raw = bytearray(n * PAGE_SIZE)
-                with stats.stage("vfs_read"):
-                    src.read_buffered(p0 * PAGE_SIZE, memoryview(raw))
-                pages = np.frombuffer(raw, np.uint8).reshape(n, PAGE_SIZE)
-                with stats.stage("staging_retire"):
-                    out = fn(jax.device_put(pages, dev))
-                stats.add("nr_kernel_dispatch")
-                acc = fold_results(acc, out, combine)
+            with ReadAheadRing(src, (8 << 20) // PAGE_SIZE,
+                               int(_cfg.get("h2d_depth_max")) + 1) as ring:
+                for slot, pages in ring:
+                    with stats.stage("staging_retire"):
+                        # safe_device_put: the CPU backend would alias
+                        # the slot, which the reader refills
+                        dev_pages = safe_device_put(pages, dev)
+                        ring.recycle(slot, dev_pages)
+                        out = fn(dev_pages)
+                    stats.add("nr_kernel_dispatch")
+                    acc = fold_results(acc, out, combine)
         finally:
             if own:
                 src.close()

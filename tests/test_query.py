@@ -1539,3 +1539,241 @@ def test_group_by_cols_three_columns(tmp_path):
     np.testing.assert_array_equal(dist["sums"], out["sums"])
     with pytest.raises(StromError):
         Query(path, schema).group_by_cols([0, 1, 2, 3, 0])  # 5 keys
+
+
+# -- the buffered scan's read-ahead ring ------------------------------------
+
+#: ring depth under ``h2d_depth_max`` 2 (the ring holds depth + 1 batches)
+RING = 3
+BATCH_PAGES = (8 << 20) // 8192
+
+
+def _ring_counters():
+    from nvme_strom_tpu.stats import stats
+    return dict(stats.snapshot().counters)
+
+
+def _ring_delta(before):
+    after = _ring_counters()
+    return {k: after[k] - before.get(k, 0) for k in after}
+
+
+def _readers_alive():
+    import threading
+    return [t for t in threading.enumerate()
+            if t.name == "strom-vfs-readahead"]
+
+
+@pytest.mark.parametrize("batches", [1, RING - 1, RING + 1, 3 * RING + 1])
+def test_vfs_ring_batches_survive_buffer_reuse(tmp_path, batches):
+    """Every batch's device array, kept until the scan ends, still holds
+    the file's bytes: no slot the reader refilled is aliased by an
+    un-copied ``device_put``.  The last batch is partial."""
+    from nvme_strom_tpu.testing import make_test_file
+    config.set("h2d_depth_max", RING - 1)
+    n_pages = (batches - 1) * BATCH_PAGES + 3
+    path = str(tmp_path / "ring.bin")
+    make_test_file(path, n_pages * 8192)
+    kept = []
+
+    def collect(pages_dev):
+        kept.append(pages_dev)
+        return {}
+
+    before = _ring_counters()
+    Query(path, HeapSchema(n_cols=1))._vfs_scan(collect, None, None)
+    d = _ring_delta(before)
+    want = np.fromfile(path, np.uint8).reshape(n_pages, 8192)
+    assert len(kept) == batches
+    assert [a.shape[0] for a in kept] == \
+        [BATCH_PAGES] * (batches - 1) + [3]
+    for i, a in enumerate(kept):
+        np.testing.assert_array_equal(
+            np.asarray(a), want[i * BATCH_PAGES:(i + 1) * BATCH_PAGES])
+    assert d["nr_vfs_read"] == d["nr_vfs_readahead"] == batches
+    assert not _readers_alive()
+
+
+class _FailingSource:
+    """An owned source whose ``read_buffered`` raises at one offset, and
+    that records at close whether the ring's reader was still alive."""
+
+    def __init__(self, inner, fail_offset, err):
+        self._inner = inner
+        self.size = inner.size
+        self._fail = fail_offset
+        self.err = err
+        self.alive_at_close = None
+
+    def read_buffered(self, offset, dest):
+        if offset == self._fail:
+            raise self.err
+        self._inner.read_buffered(offset, dest)
+
+    def close(self):
+        self.alive_at_close = bool(_readers_alive())
+        self._inner.close()
+
+
+class _KernelFault(Exception):
+    pass
+
+
+@pytest.mark.parametrize("where", ["read", "kernel"])
+def test_vfs_ring_error_joins_reader_before_close(tmp_path, monkeypatch,
+                                                   where):
+    """A read error at batch k is raised in the caller as the same
+    StromError; a kernel error stops the scan too.  Either way the
+    reader is joined before the owned source closes."""
+    import errno
+
+    from nvme_strom_tpu.engine import open_source
+    schema = HeapSchema(n_cols=1, visibility=False)
+    config.set("h2d_depth_max", RING - 1)
+    config.set("debug_no_threshold", False)          # the vfs path
+    n = schema.tuples_per_page * (4 * BATCH_PAGES + 5)
+    path = str(tmp_path / "f.heap")
+    build_heap_file(path, [np.arange(n, dtype=np.int32) % 7], schema)
+    k = 2
+    err = StromError(errno.EIO, "planted read error")
+    src = _FailingSource(open_source(path),
+                         k * BATCH_PAGES * 8192 if where == "read" else -1,
+                         err)
+    monkeypatch.setattr(Query, "_open_owned", lambda self: (src, True))
+    calls = []
+
+    def kernel(pages_dev):
+        calls.append(pages_dev.shape[0])
+        if len(calls) == k + 1:
+            raise _KernelFault
+        return {}
+
+    q = Query(path, schema).where(lambda cols: cols[0] > 2)
+    if where == "read":
+        with pytest.raises(StromError) as got:
+            q.run()
+        assert got.value is err
+    else:
+        with pytest.raises(_KernelFault):
+            q._vfs_scan(kernel, None, None)
+        assert len(calls) == k + 1
+    assert src.alive_at_close is False
+    assert not _readers_alive()
+
+
+def test_vfs_ring_limit_stops_reads_early(tmp_path):
+    """LIMIT over the buffered path: the scan stops at the first batch,
+    the reader has read at most the ring's depth past it, and no reader
+    is left alive."""
+    schema = HeapSchema(n_cols=1, visibility=False)
+    config.set("h2d_depth_max", RING - 1)
+    config.set("debug_no_threshold", False)
+    batches = RING + 3
+    n = schema.tuples_per_page * (batches - 1) * BATCH_PAGES + 11
+    c0 = np.arange(n, dtype=np.int32)
+    path = str(tmp_path / "l.heap")
+    build_heap_file(path, [c0], schema)
+    before = _ring_counters()
+    out = Query(path, schema).select(limit=4).run()
+    d = _ring_delta(before)
+    assert int(out["count"]) == 4
+    np.testing.assert_array_equal(out["col0"], c0[:4])
+    assert d["nr_vfs_read"] == 1
+    assert 1 <= d["nr_vfs_readahead"] <= 1 + RING < batches
+    assert not _readers_alive()
+
+
+def test_vfs_ring_counters_and_answer(tmp_path):
+    """An aggregate over the ring: one caller wait and one reader read a
+    batch, blocked waits a subset of the waits, and the answer that of
+    numpy."""
+    rng = np.random.default_rng(29)
+    schema = HeapSchema(n_cols=2, visibility=False)
+    config.set("debug_no_threshold", False)
+    n = schema.tuples_per_page * (3 * BATCH_PAGES + 17)
+    c0 = rng.integers(-50, 50, n).astype(np.int32)
+    c1 = rng.integers(0, 1000, n).astype(np.int32)
+    path = str(tmp_path / "c.heap")
+    build_heap_file(path, [c0, c1], schema)
+    before = _ring_counters()
+    out = Query(path, schema).where(lambda cols: cols[0] > 10).run()
+    d = _ring_delta(before)
+    m = c0 > 10
+    assert int(out["count"]) == int(m.sum())
+    assert int(out["sums"][1]) == int(c1[m].sum())
+    assert d["nr_vfs_read"] == d["nr_vfs_readahead"] == 4
+    assert 0 <= d["nr_vfs_read_blocked"] <= d["nr_vfs_read"]
+    assert d["clk_vfs_readahead"] > 0
+    assert not _readers_alive()
+
+
+class _LateTransfer:
+    """A device array whose copy of the slot is taken only when it is
+    fenced, as an accelerator's host->device read may still be running
+    after ``device_put`` returns."""
+
+    def __init__(self, pages):
+        self._pages = pages
+        self.copy = None
+        self._polls = 0
+
+    def is_ready(self):
+        self._polls += 1
+        return self.copy is not None
+
+    def block_until_ready(self):
+        if self.copy is None:
+            self.copy = np.array(self._pages)
+        return self
+
+
+def test_vfs_ring_stress_refills_only_fenced_slots(tmp_path):
+    """More rings than cores, one-page batches, a switch interval of a
+    microsecond: every batch arrives once, in order, and a slot is
+    refilled only after its transfer was fenced, so each late copy still
+    holds its own batch."""
+    import os
+    import sys
+    import threading
+
+    from nvme_strom_tpu.engine import open_source
+    from nvme_strom_tpu.scan.read_ring import ReadAheadRing
+    from nvme_strom_tpu.testing import make_test_file
+    n_pages = 64
+    path = str(tmp_path / "s.bin")
+    make_test_file(path, n_pages * 8192)
+    want = np.fromfile(path, np.uint8).reshape(n_pages, 8192)
+    errors = []
+
+    def scan(depth):
+        try:
+            with open_source(path) as src, \
+                    ReadAheadRing(src, 1, depth) as ring:
+                held = []
+                for slot, pages in ring:
+                    dev = _LateTransfer(pages)
+                    ring.recycle(slot, dev)
+                    held.append(dev)
+            for i, dev in enumerate(held):
+                dev.block_until_ready()
+                if not np.array_equal(dev.copy[0], want[i]):
+                    errors.append((depth, i))
+            if len(held) != n_pages:
+                errors.append((depth, "count", len(held)))
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append((depth, repr(e)))
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        workers = [threading.Thread(target=scan, args=(2 + i % 3,))
+                   for i in range((os.cpu_count() or 2) + 2)]
+        for t in workers:
+            t.start()
+        for t in workers:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in workers)
+    assert errors == []
+    assert not _readers_alive()

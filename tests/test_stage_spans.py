@@ -182,6 +182,16 @@ def test_clock_anchor_shifts_recorder_spans_onto_the_trace(
 def _traced_query(q, tdir, caller="caller.query"):
     """One run of *q* under the profiler; returns the result and the host
     line holding the caller's annotation as [(start_ns, end_ns, name)]."""
+    out, lines = _traced_query_lines(q, tdir, caller)
+    for evs in lines:
+        if any(n == caller for _, _, n in evs):
+            return out, evs
+    raise AssertionError("no host line holds the caller's annotation")
+
+
+def _traced_query_lines(q, tdir, caller):
+    """One run of *q* under the profiler; returns the result and every
+    line of the trace as [(start_ns, end_ns, name)]."""
     from jax._src.profiler import ProfileData
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -194,13 +204,10 @@ def _traced_query(q, tdir, caller="caller.query"):
         jax.profiler.stop_trace()
     pb, = glob.glob(os.path.join(str(tdir), "plugins", "profile", "*",
                                  "*.xplane.pb"))
-    for plane in ProfileData.from_file(pb).planes:
-        for line in plane.lines:
-            evs = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
-                   for e in line.events]
-            if any(n == caller for _, _, n in evs):
-                return out, evs
-    raise AssertionError("no host line holds the caller's annotation")
+    return out, [[(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                  for e in line.events]
+                 for plane in ProfileData.from_file(pb).planes
+                 for line in plane.lines]
 
 
 def test_group_discover_stage_nests_its_scan(tmp_path):
@@ -254,3 +261,41 @@ def test_group_discover_stage_nests_its_scan(tmp_path):
          for k, v in stats.snapshot().counters.items()}
     assert d["nr_group_discover"] == 1 and d["nr_vfs_read"] == 2
     assert out2["count"].tolist() == out["count"].tolist()
+
+
+def test_vfs_readahead_spans_sit_on_the_readers_line(tmp_path):
+    """The buffered scan's reads run on the ring's reader thread: one
+    ``strom.vfs_readahead`` span per batch, on a host line other than
+    the caller's, which keeps one ``strom.vfs_read`` wait per batch, in
+    batch order before that batch's ``staging_retire``."""
+    import numpy as np
+
+    from nvme_strom_tpu.scan.heap import HeapSchema, build_heap_file
+    from nvme_strom_tpu.scan.query import Query
+    schema = HeapSchema(n_cols=2, visibility=False)
+    config.set("debug_no_threshold", False)
+    n = schema.tuples_per_page * 2500             # three 8 MiB batches
+    c0 = np.arange(n, dtype=np.int32) % 11
+    path = str(tmp_path / "r.heap")
+    build_heap_file(path, [c0, c0], schema)
+
+    def query():
+        return Query(path, schema).where(lambda cols: cols[0] > 4)
+
+    query().run()                                 # compiles
+    out, lines = _traced_query_lines(query(), tmp_path / "t",
+                                     "caller.query")
+    assert int(out["count"]) == int((c0 > 4).sum())
+    caller, = [ln for ln in lines
+               if any(nm == "caller.query" for _, _, nm in ln)]
+    ahead = [ln for ln in lines if ln is not caller
+             and any(nm == "strom.vfs_readahead" for _, _, nm in ln)]
+    assert len(ahead) == 1
+    assert sum(nm == "strom.vfs_readahead" for _, _, nm in ahead[0]) == 3
+    assert not any(nm == "strom.vfs_readahead" for _, _, nm in caller)
+    reads = sorted((a, b) for a, b, nm in caller if nm == "strom.vfs_read")
+    retires = sorted((a, b) for a, b, nm in caller
+                     if nm == "strom.staging_retire")
+    assert len(reads) == len(retires) == 3
+    assert all(r[1] <= t[0] for r, t in zip(reads, retires))
+    assert all(t[1] <= r[0] for t, r in zip(retires, reads[1:]))
