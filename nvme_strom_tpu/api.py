@@ -422,6 +422,11 @@ STAT_FIELDS: Tuple[str, ...] = (
     #                           growth steps (nested in staging_retire), or
     #                           a sidecar or discovery scan (whose
     #                           vfs_read/staging_retire nest in it)
+    "nr_mesh_submit", "clk_mesh_submit",  # a mesh scan step's engine
+    #                           submits for every shard (the engine's own
+    #                           submit and page-cache copy nest in it)
+    "nr_mesh_wait", "clk_mesh_wait",  # the mesh stream's waits on its
+    #                           shards' read tasks (polls and blocking)
     "nr_debug1", "clk_debug1",
     "nr_debug2", "clk_debug2",
     "nr_debug3", "clk_debug3",

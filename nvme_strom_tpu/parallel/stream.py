@@ -25,6 +25,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..api import StromError
+from ..config import config
 from ..engine import Session, Source, reorder_chunks
 from ..hbm.staging import safe_device_put
 from ..scan.heap import PAGE_SIZE
@@ -122,9 +123,16 @@ class ShardedBatchStream:
         self._shape = (batch_pages, PAGE_SIZE)
         self._idx = list(self.sharding.addressable_devices_indices_map(
             self._shape).items())
-        per_shard = batch_pages // n_shards * PAGE_SIZE
+        per_shard = batch_pages // n_shards
+        # a shard's pages of a step are read as engine chunks of the
+        # largest power-of-two page count that divides them, up to the
+        # engine's chunk size: one chunk a shard a step by default, so the
+        # engine plans and probes a step's 8 MiB once, not page by page
+        self._chunk_pages = min(per_shard & -per_shard,
+                                max(config.get("chunk_size") // PAGE_SIZE,
+                                    1))
         # double buffering: ring of 2 pinned buffers per addressable shard
-        self._bufs = [[self.session.alloc_dma_buffer(per_shard)
+        self._bufs = [[self.session.alloc_dma_buffer(per_shard * PAGE_SIZE)
                        for _ in range(2)] for _ in self._idx]
         self._fence: List[Optional[jax.Array]] = [None, None]
 
@@ -134,20 +142,25 @@ class ShardedBatchStream:
         if self._fence[ring] is not None:
             # bounded: a dead backend fails the stream with ENODEV
             # instead of hanging the double-buffer rotation
-            bounded_fence(self._fence[ring], "mesh-h2d")
+            with stats.stage("staging_fence"):
+                bounded_fence(self._fence[ring], "mesh-h2d")
             self._fence[ring] = None
         tasks = []
         base = b * self.batch_pages
-        for k, (dev, idx) in enumerate(self._idx):
-            rows = idx[0]
-            r0 = base + (rows.start or 0)
-            r1 = base + (rows.stop if rows.stop is not None else self.batch_pages)
-            handle, _buf = self._bufs[k][ring]
-            res = self.session.memcpy_ssd2ram(
-                self.source, handle, list(range(r0, r1)), PAGE_SIZE)
-            # submit timestamp rides with the task: the fan-in loop below
-            # turns it into the per-shard wait distribution
-            tasks.append((dev, res, time.monotonic_ns()))
+        with stats.stage("mesh_submit"):
+            for k, (dev, idx) in enumerate(self._idx):
+                rows = idx[0]
+                r0 = base + (rows.start or 0)
+                r1 = base + (rows.stop if rows.stop is not None
+                             else self.batch_pages)
+                handle, _buf = self._bufs[k][ring]
+                cp = self._chunk_pages
+                res = self.session.memcpy_ssd2ram(
+                    self.source, handle, list(range(r0 // cp, r1 // cp)),
+                    cp * PAGE_SIZE)
+                # submit timestamp rides with the task: the fan-in loop
+                # below turns it into the per-shard wait distribution
+                tasks.append((dev, res, time.monotonic_ns()))
         return ring, tasks
 
     def _collect(self, ring, tasks) -> jax.Array:
@@ -164,15 +177,32 @@ class ShardedBatchStream:
                 recorder.span("shard_wait", tasks[k][2], t1,
                               args={"shard": k})
 
+        def wait(k, timeout):
+            """Shard *k*'s finished task, or None if a poll times out."""
+            with stats.stage("mesh_wait"):
+                try:
+                    return self.session.memcpy_wait(
+                        tasks[k][1].dma_task_id, timeout=timeout)
+                except StromError as e:
+                    if e.errno == _errno.ETIMEDOUT:
+                        return None
+                    raise
+
         def place(k, done) -> None:
+            account(k)
             _handle, buf = self._bufs[k][ring]
-            # slot i holds chunk chunk_ids[i]: with a partially cached
-            # source the engine fronts direct-I/O chunks and tails
-            # write-back chunks, so restore file order before placement
-            host = reorder_chunks(np.frombuffer(buf.view(), np.uint8),
-                                  PAGE_SIZE, done.chunk_ids,
-                                  sorted(done.chunk_ids)).reshape(-1, PAGE_SIZE)
-            shards[k] = safe_device_put(host, tasks[k][0])
+            # one staging_retire entry a step (counted at the assembly);
+            # the shards' placements add their time to it
+            with stats.stage("staging_retire", n=0):
+                # slot i holds chunk chunk_ids[i]: with a partially cached
+                # source the engine fronts direct-I/O chunks and tails
+                # write-back chunks, so restore file order before placement
+                host = reorder_chunks(np.frombuffer(buf.view(), np.uint8),
+                                      self._chunk_pages * PAGE_SIZE,
+                                      done.chunk_ids,
+                                      sorted(done.chunk_ids)).reshape(
+                                          -1, PAGE_SIZE)
+                shards[k] = safe_device_put(host, tasks[k][0])
 
         # completion fan-in (PR 5): with per-member engine lanes the
         # shards' SSD DMAs finish independently, so start each device's
@@ -182,24 +212,18 @@ class ShardedBatchStream:
         while remaining:
             progressed = False
             for k in list(remaining):
-                try:
-                    done = self.session.memcpy_wait(
-                        tasks[k][1].dma_task_id, timeout=0.0)
-                except StromError as e:
-                    if e.errno == _errno.ETIMEDOUT:
-                        continue
-                    raise
-                account(k)
+                done = wait(k, 0.0)
+                if done is None:
+                    continue
                 place(k, done)
                 remaining.remove(k)
                 progressed = True
             if remaining and not progressed:
                 k = remaining.pop(0)
-                done = self.session.memcpy_wait(tasks[k][1].dma_task_id)
-                account(k)
-                place(k, done)
-        arr = jax.make_array_from_single_device_arrays(
-            self._shape, self.sharding, shards)
+                place(k, wait(k, None))
+        with stats.stage("staging_retire"):
+            arr = jax.make_array_from_single_device_arrays(
+                self._shape, self.sharding, shards)
         self._fence[ring] = arr
         return arr
 
@@ -252,6 +276,9 @@ def distributed_scan_filter(source: Source, mesh: Mesh, step, *,
     with ShardedBatchStream(source, mesh, batch_pages=batch_pages,
                             session=session) as stream:
         for _first, arr in stream:
-            acc = fold_results(acc, step(arr), combine)
+            # the step's kernel call and fold end its staging_retire
+            with stats.stage("staging_retire", n=0):
+                acc = fold_results(acc, step(arr), combine)
+            stats.add("nr_kernel_dispatch")
     # per-leaf: heterogeneous list leaves keep their acc dtypes
     return {} if acc is None else jax.tree.map(np.asarray, acc)

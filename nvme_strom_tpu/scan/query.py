@@ -44,6 +44,23 @@ from .planner import (capability_cache, cost_direct_scan, cost_vfs_scan,
 __all__ = ["Query", "QueryPlan"]
 
 _PALLAS_MAX_GROUPS = 64   # static unroll bound (ops/groupby_pallas.py)
+#: pages of one buffered-scan batch (8 MiB); a mesh step gives each chip
+#: one such batch
+BATCH_PAGES = (8 << 20) // PAGE_SIZE
+
+
+def _mesh_steps(n_pages: int, n_shards: int, batch_pages=None):
+    """``(step_pages, covered_pages)`` of a mesh scan: one batch a shard
+    per step by default; *batch_pages* (the caller's) rounds to a shard
+    multiple, never below one page a shard, and shrinks to the largest
+    step the table fills, so a small table or an odd *batch_pages* still
+    scans.  The pages past *covered_pages* are the tail."""
+    bp = batch_pages or BATCH_PAGES * n_shards
+    bp = max(bp // n_shards * n_shards, n_shards)
+    bp = min(bp, n_pages // n_shards * n_shards)
+    if bp < n_shards:
+        return n_shards, 0
+    return bp, n_pages // bp * bp
 
 
 @functools.lru_cache(maxsize=64)
@@ -1918,10 +1935,9 @@ class Query:
                 fn, _combine = self._build_fn("xla")
                 dev = device or jax.local_devices()[0]
                 n_pages = src.size // PAGE_SIZE
-                batch = max((8 << 20) // PAGE_SIZE, 1)
                 acc = None
-                for p0 in range(0, n_pages, batch):
-                    n = min(batch, n_pages - p0)
+                for p0 in range(0, n_pages, BATCH_PAGES):
+                    n = min(BATCH_PAGES, n_pages - p0)
                     raw = bytearray(n * PAGE_SIZE)
                     src.read_buffered(p0 * PAGE_SIZE, memoryview(raw))
                     packed = np.frombuffer(raw, np.uint8).reshape(
@@ -2147,54 +2163,30 @@ class Query:
         chosen = plan.kernel if kernel == "auto" else kernel
         fn, combine = self._build_fn(chosen)
         if mesh is not None:
-            import jax
-
             from ..parallel.stream import distributed_scan_filter
             from .executor import fold_results
-            n_shards = mesh.shape["dp"]
             src, own = self._open_owned()
             try:
-                n_pages = src.size // PAGE_SIZE
-                bp = batch_pages or max(
-                    n_shards, (1 << 20) // PAGE_SIZE * n_shards)
-                # round to a shard multiple (user-supplied values included,
-                # never below one page per shard) and shrink to the largest
-                # batch that fits, so a small table or an odd batch_pages
-                # still scans; the remainder rides the tail path below
-                bp = max(bp // n_shards * n_shards, n_shards)
-                bp = min(bp, n_pages // n_shards * n_shards)
+                bp, covered = _mesh_steps(src.size // PAGE_SIZE,
+                                          mesh.shape["dp"], batch_pages)
                 acc = None
-                covered = 0
-                if bp >= n_shards:
-                    out = distributed_scan_filter(src, mesh, fn,
+                if covered:
+                    acc = distributed_scan_filter(src, mesh, fn,
                                                   batch_pages=bp,
                                                   combine=combine,
-                                                  session=session)
-                    if out:
-                        acc = out
-                    covered = (n_pages // bp) * bp
-                # the stream drops any partial final batch (it cannot fill
-                # every shard evenly); scan the tail on a local device so
-                # mesh results cover every page, like the local path does.
-                # Batched reads: a table smaller than batch_pages arrives
-                # whole on this path and must not become one giant alloc
-                dev = jax.local_devices()[0]
-                tail_batch = max((8 << 20) // PAGE_SIZE, 1)
-                for p0 in range(covered, n_pages, tail_batch):
-                    npg = min(tail_batch, n_pages - p0)
-                    raw = bytearray(npg * PAGE_SIZE)
-                    src.read_buffered(p0 * PAGE_SIZE, memoryview(raw))
-                    pages = np.frombuffer(raw, np.uint8).reshape(
-                        -1, PAGE_SIZE)
-                    acc = fold_results(acc, fn(jax.device_put(pages, dev)),
-                                       combine)
-                if acc is None:
-                    return {}
-                return self._finalize(
-                    jax.tree.map(np.asarray, acc))
+                                                  session=session) or None
+                # the stream drops any partial final step (it cannot fill
+                # every shard evenly); the buffered batch loop scans the
+                # tail on one local device, so mesh results cover every
+                # page, like the local path does
+                tail = self._vfs_scan(fn, combine, device,
+                                      first_page=covered, src=src)
+                if tail:
+                    acc = fold_results(acc, tail, combine)
             finally:
                 if own:
                     src.close()
+            return self._finalize(acc) if acc is not None else {}
         if plan.access_path == "direct":
             from ..config import config as _cfg
             from .executor import TableScanner
@@ -3529,18 +3521,13 @@ class Query:
         from ..parallel.stream import ShardedBatchStream
         n_shards = mesh.shape["dp"]
         n_pages = src.size // PAGE_SIZE
-        bp = batch_pages or max(
-            n_shards, (1 << 20) // PAGE_SIZE * n_shards)
-        bp = max(bp // n_shards * n_shards, n_shards)
-        bp = min(bp, n_pages // n_shards * n_shards)
-        covered = 0
-        if bp >= n_shards:
+        bp, covered = _mesh_steps(n_pages, n_shards, batch_pages)
+        if covered:
             with ShardedBatchStream(src, mesh, batch_pages=bp,
                                     session=session) as stream:
                 for _first, arr in stream:
                     yield arr
-            covered = (n_pages // bp) * bp
-        tail_batch = max((8 << 20) // PAGE_SIZE, n_shards)
+        tail_batch = max(BATCH_PAGES, n_shards)
         for p0 in range(covered, n_pages, tail_batch):
             npg = min(tail_batch, n_pages - p0)
             raw = bytearray(npg * PAGE_SIZE)
@@ -3816,14 +3803,16 @@ class Query:
         return {"values": v[offset:end], "positions": p[offset:end],
                 "per_device_count": counts, "n_dropped": np.int32(0)}
 
-    def _vfs_scan(self, fn, combine, device) -> dict:
+    def _vfs_scan(self, fn, combine, device, *, first_page: int = 0,
+                  src=None) -> dict:
         """Buffered fallback below the planner threshold (the conventional
         path the reference leaves small tables on).  Reads through the
         Source abstraction, so multi-file stripe sets and live Source
         objects scan identically to the direct path.  The 8 MiB batches
         are read ahead on a reader thread into ``h2d_depth_max + 1``
         reused buffers (:class:`.read_ring.ReadAheadRing`), joined before
-        an owned source closes."""
+        an owned source closes.  The mesh path scans its tail here: the
+        pages from *first_page* on, of its already open *src*."""
         import jax
 
         from ..config import config as _cfg
@@ -3832,11 +3821,12 @@ class Query:
         from .executor import fold_results
         from .read_ring import ReadAheadRing
         dev = device or jax.local_devices()[0]
-        src, own = self._open_owned()
+        src, own = (src, False) if src is not None else self._open_owned()
         try:
             acc = None
-            with ReadAheadRing(src, (8 << 20) // PAGE_SIZE,
-                               int(_cfg.get("h2d_depth_max")) + 1) as ring:
+            with ReadAheadRing(src, BATCH_PAGES,
+                               int(_cfg.get("h2d_depth_max")) + 1,
+                               first_page=first_page) as ring:
                 for slot, pages in ring:
                     with stats.stage("staging_retire"):
                         # safe_device_put: the CPU backend would alias

@@ -32,17 +32,21 @@ __all__ = ["ReadAheadRing"]
 
 
 class ReadAheadRing:
-    """Page batches of *src* read ahead into *depth* reused buffers.
+    """Page batches of *src*, from page *first_page* to its end, read
+    ahead into *depth* reused buffers.
 
     Use as a context manager: leaving it stops the reader and joins it,
     on every path, so the caller may close *src* right after.  A read
     error is raised in the caller at the batch it belongs to."""
 
-    def __init__(self, src, batch_pages: int, depth: int):
+    def __init__(self, src, batch_pages: int, depth: int, *,
+                 first_page: int = 0):
         self._src = src
+        self._first = int(first_page)
         self._n_pages = src.size // PAGE_SIZE
         self._batch = max(int(batch_pages), 1)
-        self._n_batches = -(-self._n_pages // self._batch)
+        self._n_batches = max(-(-(self._n_pages - self._first)
+                                // self._batch), 0)
         self._depth = max(1, min(int(depth), self._n_batches))
         self._slot_bytes = self._batch * PAGE_SIZE
         self._mm: Optional[mmap.mmap] = None
@@ -92,7 +96,7 @@ class ReadAheadRing:
         src, bb = self._src, self._slot_bytes
         mv = memoryview(self._mm)
         try:
-            for p0 in range(0, self._n_pages, self._batch):
+            for p0 in range(self._first, self._n_pages, self._batch):
                 slot, held = self._free.get()
                 if self._stop.is_set():
                     return
