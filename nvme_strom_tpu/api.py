@@ -399,7 +399,11 @@ STAT_FIELDS: Tuple[str, ...] = (
     # timed by stats.stage() (which also puts each entry on a running
     # jax.profiler trace as a strom.<stage> span)
     "nr_pagecache_copy", "clk_pagecache_copy",  # write-back page-cache
-    #                           copies, nested in ioctl_memcpy_submit
+    #                           copies run inline on the caller (runs under
+    #                           the pool threshold), nested in
+    #                           ioctl_memcpy_submit
+    "nr_writeback_copy", "clk_writeback_copy",  # write-back runs copied on
+    #                           the session's I/O pool, off the caller
     "nr_staging_fence", "clk_staging_fence",    # waits for the device to
     #                           finish reading a staging buffer to reuse
     "nr_staging_fence_blocked",  # of those, fences whose transfer had not

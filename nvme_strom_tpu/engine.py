@@ -1119,6 +1119,14 @@ def plan_shard_ownership(source: Source, chunk_ids: Sequence[int],
 
 _N_TASK_SLOTS = 512  # reference uses 512 hash slots (kmod/nvme_strom.c:639-644)
 
+#: write-back runs of at least this many bytes are copied out of the page
+#: cache on the session's I/O pool, off the caller's thread; shorter runs
+#: (KV blocks, single pages) stay inline in the submit.  On an 8-core
+#: Xeon host a pool handoff (queue, wake a worker, signal completion)
+#: took 30-43 us, against 4.2 us for an inline 64 KiB copy, 12 us for
+#: 256 KiB and 57-70 us for 1 MiB
+_WRITEBACK_ASYNC_MIN = 1 << 20
+
 
 class DmaTask:
     __slots__ = ("task_id", "state", "errno_", "errmsg", "pending", "frozen",
@@ -2002,12 +2010,17 @@ class Session:
         are copied straight into the destination (SSD2RAM behaviour,
         :1926-1934).
 
+        Write-back bytes, like direct bytes, are valid only after
+        :meth:`memcpy_wait` returns: runs of at least
+        ``_WRITEBACK_ASYNC_MIN`` bytes are copied on the session's I/O
+        pool, and a failed copy surfaces from the wait with its errno.
+
         ``speculative`` marks a readahead prefetch (ISSUE 18): the task
         skips the residency-tier hit split (a prefetch of resident data
         has nothing to do), does not train the readahead predictor, and
         its wait-time cache fills carry provenance so ARC's ghost lists
         stay blind to speculation."""
-        # the caller's whole submit, the page-cache copies included
+        # the caller's whole submit, the inline page-cache copies included
         with stats.stage("ioctl_memcpy_submit") as st:
             return self._submit_read(st.t0, source, buf_handle, chunk_ids,
                                      chunk_size, dest_offset, wb_buffer,
@@ -2326,13 +2339,17 @@ class Session:
                     self._submit_pool_requests(task, source, native_rs,
                                                dest)
 
-            # --- write-back copies (synchronous, like the in-ioctl memcpy;
-            #     AFTER direct submission so the device queue fills first
-            #     and these page-cache copies overlap in-flight direct I/O).
-            #     A run of file-consecutive chunks sits in consecutive
-            #     slots, so it is one read of up to coalesce_limit bytes:
-            #     a syscall per chunk of small chunks (the sharded loader's
-            #     8 KiB pages) overran the task deadline on a cached GiB file
+            # --- write-back copies, AFTER direct submission so the device
+            #     queue fills first.  A run of file-consecutive chunks sits
+            #     in consecutive slots, so it is one read of up to
+            #     coalesce_limit bytes: a syscall per chunk of small chunks
+            #     (the sharded loader's 8 KiB pages) overran the task
+            #     deadline on a cached GiB file.  A large run is a task
+            #     reference queued on the I/O pool (the copy drops the
+            #     interpreter lock, so the caller plans and dispatches
+            #     beside it) and completes at memcpy_wait like a direct
+            #     read; a short one costs less than the handoff and is
+            #     copied here, like the reference's in-ioctl memcpy
             run_max = max(chunk_size, int(config.get("coalesce_limit")))
             target = wb_buffer if wb_buffer is not None else dest
             r0 = 0
@@ -2345,10 +2362,21 @@ class Session:
                 length = min((r1 - r0) * chunk_size, source.size - base)
                 off = ((dest_offset if wb_buffer is None else 0)
                        + (nr_ssd + r0) * chunk_size)
-                with stats.stage("pagecache_copy", ring="writeback",
-                                 tid=task.trace_id, offset=base,
-                                 length=length):
-                    source.read_buffered(base, target[off:off + length])
+                piece = target[off:off + length]
+                if length >= _WRITEBACK_ASYNC_MIN:
+                    self._task_get(task)
+                    try:
+                        self._pool.submit(self._writeback_copy, task,
+                                          source, base, piece)
+                    except BaseException as e:
+                        self._task_put(task, StromError(
+                            _errno.ESHUTDOWN, str(e)))
+                        raise
+                else:
+                    with stats.stage("pagecache_copy", ring="writeback",
+                                     tid=task.trace_id, offset=base,
+                                     length=length):
+                        source.read_buffered(base, piece)
                 r0 = r1
 
             # --- residency-tier hit serving (tail-packed after the
@@ -2811,6 +2839,31 @@ class Session:
         finally:
             if scratch is not None:
                 scratch.close()
+
+    def _writeback_copy(self, task: DmaTask, source: Source, base: int,
+                        dest: memoryview) -> None:
+        """One write-back run copied out of the page cache on a pool
+        thread; a failed copy latches on the task and surfaces from
+        memcpy_wait."""
+        if task.errno_:
+            # task already failed (first-error latch or watchdog expiry)
+            stats.add("nr_chunk_cancelled")
+            self._task_put(task, None)
+            return
+        err: Optional[StromError] = None
+        try:
+            with stats.stage("writeback_copy", ring="writeback",
+                             tid=task.trace_id, offset=base,
+                             length=len(dest)):
+                source.read_buffered(base, dest)
+        except StromError as e:
+            err = e
+        except OSError as e:
+            err = StromError(e.errno or _errno.EIO, str(e))
+        except BaseException as e:  # any failure must latch, never silently DONE
+            err = StromError(_errno.EIO, f"{type(e).__name__}: {e}")
+        finally:
+            self._task_put(task, err)
 
     def _do_request(self, task: DmaTask, source: Source,
                     r: Request, dest: memoryview) -> None:

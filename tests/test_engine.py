@@ -276,6 +276,133 @@ def test_writeback_to_separate_wb_buffer(tmp_data_file):
         src.close()
 
 
+MIB = 1 << 20
+
+
+def _held_writeback_source(path, *, fail_at=None, exc=None):
+    """A fully cached fake whose write-back reads block on ``release``;
+    ``entered`` is set once a read has started.  The read at *fail_at*
+    raises *exc* instead."""
+    import threading
+    src = FakeNvmeSource(path, force_cached_fraction=1.0)
+    src.entered, src.release = threading.Event(), threading.Event()
+    plain = src.read_buffered
+
+    def read_buffered(off, dest):
+        src.entered.set()
+        assert src.release.wait(10.0), "write-back read never released"
+        if off == fail_at:
+            raise exc
+        plain(off, dest)
+    src.read_buffered = read_buffered
+    return src
+
+
+def _counts():
+    return dict(stats.snapshot().counters)
+
+
+def test_writeback_copy_completes_at_wait_not_submit(tmp_data_file):
+    """A run at the pool threshold is copied off the caller's thread: the
+    submit returns while the copy is held, the task is still running, and
+    memcpy_wait returns once the copy has landed the file's bytes."""
+    src = _held_writeback_source(tmp_data_file)
+    try:
+        with Session() as sess:
+            handle, buf = sess.alloc_dma_buffer(2 * MIB)
+            res = sess.memcpy_ssd2ram(src, handle, [0, 1], MIB)
+            assert src.entered.wait(5.0)
+            with pytest.raises(StromError) as ei:
+                sess.memcpy_wait(res.dma_task_id, timeout=0.0)
+            assert ei.value.errno == errno.ETIMEDOUT
+            src.release.set()
+            done = sess.memcpy_wait(res.dma_task_id, timeout=10.0)
+            assert done.nr_ram2dev == 2 and done.chunk_ids == [0, 1]
+            assert bytes(buf.view()[:2 * MIB]) == expected_bytes(0, 2 * MIB)
+            sess.unmap_buffer(handle, timeout=5.0)
+    finally:
+        src.close()
+
+
+@pytest.mark.parametrize("exc", [OSError(errno.EIO, "media error"),
+                                 StromError(errno.EBADMSG, "bad page")],
+                         ids=["oserror", "stromerror"])
+def test_writeback_copy_error_surfaces_at_wait(tmp_data_file, exc):
+    """A failed pool copy of one run becomes the task's error with its
+    errno: memcpy_wait raises it, the task is reaped, and the buffer
+    unmaps once the other run has drained."""
+    config.set("coalesce_limit", MIB)             # one run a chunk
+    src = _held_writeback_source(tmp_data_file, fail_at=2 * MIB, exc=exc)
+    src.release.set()
+    try:
+        with Session() as sess:
+            handle, _buf = sess.alloc_dma_buffer(4 * MIB)
+            res = sess.memcpy_ssd2ram(src, handle, [0, 1, 2, 3], MIB)
+            with pytest.raises(StromError) as ei:
+                sess.memcpy_wait(res.dma_task_id, timeout=10.0)
+            assert ei.value.errno == exc.errno
+            assert res.dma_task_id not in sess.pending_tasks()
+            sess.unmap_buffer(handle, timeout=5.0)
+            assert handle not in sess.list_buffers()
+    finally:
+        src.close()
+
+
+@pytest.mark.parametrize("chunk,n,pooled", [
+    (CHUNK, 15, False),                 # 960 KiB: under the threshold
+    (CHUNK, 16, True),                  # 1 MiB: at it
+    (MIB, 2, True),                     # 2 MiB: over it
+], ids=["under", "at", "over"])
+def test_writeback_run_size_picks_inline_or_pool(tmp_data_file, chunk, n,
+                                                 pooled):
+    """A run under ``_WRITEBACK_ASYNC_MIN`` is a ``pagecache_copy`` on the
+    caller; a run at or over it is a ``writeback_copy`` on the pool."""
+    from nvme_strom_tpu.engine import _WRITEBACK_ASYNC_MIN
+    assert (n * chunk >= _WRITEBACK_ASYNC_MIN) == pooled
+    src = FakeNvmeSource(tmp_data_file, force_cached_fraction=1.0)
+    try:
+        before = _counts()
+        res, data = _run_copy(src, list(range(n)), chunk)
+        after = _counts()
+    finally:
+        src.close()
+    assert res.nr_ram2dev == n
+    assert data == expected_bytes(0, n * chunk)
+    inline = after["nr_pagecache_copy"] - before["nr_pagecache_copy"]
+    pool = after["nr_writeback_copy"] - before["nr_writeback_copy"]
+    assert (inline, pool) == ((0, 1) if pooled else (1, 0))
+    assert (after["clk_writeback_copy"] > before["clk_writeback_copy"]) \
+        == pooled
+
+
+def test_session_close_drains_queued_writeback_copies(tmp_data_file):
+    """Closing a session whose copies are still queued behind a held one
+    (a one-worker pool) runs them all: nothing is reaped as failed or
+    cancelled, and the buffer holds the file."""
+    import threading
+    config.set("coalesce_limit", MIB)             # four runs
+    src = _held_writeback_source(tmp_data_file)
+    try:
+        sess = Session(max_workers=1)
+        handle, buf = sess.alloc_dma_buffer(4 * MIB)
+        before = _counts()
+        res = sess.memcpy_ssd2ram(src, handle, [0, 1, 2, 3], MIB)
+        assert src.entered.wait(5.0)
+        assert res.dma_task_id in sess.pending_tasks()
+        timer = threading.Timer(0.2, src.release.set)
+        timer.start()
+        try:
+            assert sess.close(timeout=10.0) == []
+        finally:
+            timer.join(5.0)
+        after = _counts()
+        assert after["nr_writeback_copy"] - before["nr_writeback_copy"] == 4
+        assert after["nr_chunk_cancelled"] == before["nr_chunk_cancelled"]
+        assert bytes(buf.view()[:4 * MIB]) == expected_bytes(0, 4 * MIB)
+    finally:
+        src.close()
+
+
 def test_striped_source_copy(tmp_path):
     paths = [str(tmp_path / f"m{i}.bin") for i in range(4)]
     stripe_chunk = 64 << 10

@@ -148,6 +148,29 @@ def test_sql_q6_on_the_mesh_equals_numpy(case, heaps, rows, mesh):
         assert d["nr_kernel_dispatch"] == steps + tail
 
 
+@pytest.mark.parametrize("batch_pages,pooled", [(STEP, True), (256, False)],
+                         ids=["8MiB_shards_on_the_pool",
+                              "512KiB_shards_inline"])
+def test_sql_q6_mesh_shard_copies_take_the_pool_by_size(
+        batch_pages, pooled, heaps, rows, mesh):
+    """On the page-cache-resident heap every shard of a step is one
+    write-back run: an 8 MiB shard is copied on the engine's I/O pool and
+    completes at the fan-in's wait, a 512 KiB one stays inline in the
+    submit.  The revenue is the same exact int64 either way."""
+    path, n = heaps["steps_and_tail"]
+    steps, tail = geometry(PAGES["steps_and_tail"], batch_pages)
+    sql = q6_sql(*REQS[0])
+    before = dict(stats.snapshot().counters)
+    out = sql_query(sql, path, SCHEMA, mesh=mesh, batch_pages=batch_pages)
+    d = delta(before)
+    assert int(np.asarray(out["revenue"]).reshape(-1)[0]) == \
+        numpy_q6(rows, n, *REQS[0])
+    assert d["nr_mesh_submit"] == steps
+    assert d["nr_kernel_dispatch"] == steps + tail
+    runs = (d["nr_writeback_copy"], d["nr_pagecache_copy"])
+    assert runs == ((steps * DP, 0) if pooled else (0, steps * DP))
+
+
 def test_geometry_of_the_cases():
     assert geometry(PAGES["steps_and_tail"]) == (2, 3)
     assert geometry(PAGES["under_one_step"]) == (1, 1)     # a 3-page tail

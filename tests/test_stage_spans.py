@@ -22,8 +22,8 @@ from nvme_strom_tpu.trace import chrome_trace_from_events, recorder
 #: the module (the package's ``stats`` attribute is the registry)
 stats_mod = importlib.import_module("nvme_strom_tpu.stats")
 
-STAGES = {"ioctl_memcpy_submit", "pagecache_copy", "ioctl_memcpy_wait",
-          "staging_fence", "staging_retire", "load_setup"}
+STAGES = {"ioctl_memcpy_submit", "ioctl_memcpy_wait", "staging_fence",
+          "staging_retire", "load_setup"}
 STAGING = 1 << 20                  # a 4 MiB file is 4 staged batches
 #: three buffers: the 4th batch refills the first, so one load fences once
 BUFFERS = 3
@@ -35,10 +35,11 @@ def _load(path, sess):
         return load_file_to_device(src, session=sess, staging_bytes=STAGING)
 
 
-def _traced_load(path, sess, tdir):
+def _traced_load(path, sess, tdir, others=None):
     """One load under the profiler (host spans only, as the benchmark
     traces); returns the host line that holds the caller's annotation
-    as [(start_ns, end_ns, name, stats)]."""
+    as [(start_ns, end_ns, name, stats)], and appends every other line
+    to *others* when given."""
     from jax._src.profiler import ProfileData
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -50,14 +51,19 @@ def _traced_load(path, sess, tdir):
         jax.profiler.stop_trace()
     pb, = glob.glob(os.path.join(str(tdir), "plugins", "profile", "*",
                                  "*.xplane.pb"))
+    caller = None
     for plane in ProfileData.from_file(pb).planes:
         for line in plane.lines:
             evs = [(e.start_ns, e.start_ns + e.duration_ns, e.name,
                     dict(e.stats) if e.name == "strom.clock" else None)
                    for e in line.events]
-            if any(n == CALLER for _, _, n, _ in evs):
-                return evs
-    raise AssertionError("no host line holds the caller's annotation")
+            if caller is None and any(n == CALLER for _, _, n, _ in evs):
+                caller = evs
+            elif others is not None:
+                others.append(evs)
+    if caller is None:
+        raise AssertionError("no host line holds the caller's annotation")
+    return caller
 
 
 def _counters(sess):
@@ -83,7 +89,8 @@ def test_staged_load_leaves_stage_spans_on_the_callers_line(
         tmp_data_file, staged, tmp_path):
     _load(tmp_data_file, staged)              # compiles the landing
     before = _counters(staged)
-    line = _traced_load(tmp_data_file, staged, tmp_path / "trace")
+    others = []
+    line = _traced_load(tmp_data_file, staged, tmp_path / "trace", others)
     d = _delta(staged, before)
     (c0, c1), = [(a, b) for a, b, n, _ in line if n == CALLER]
     spans = [(a, b, n) for a, b, n, _ in line if n.startswith("strom.")]
@@ -96,11 +103,17 @@ def test_staged_load_leaves_stage_spans_on_the_callers_line(
     assert d["nr_staging_retire"] == per["staging_retire"] == 4
     assert d["nr_load_setup"] == 1 and per["load_setup"] == 5
     assert d["nr_ioctl_memcpy_submit"] == per["ioctl_memcpy_submit"] == 4
-    assert d["nr_pagecache_copy"] == per["pagecache_copy"] >= 4
-    # page-cache copies run inside the submit that does them
-    subs = [(a, b) for a, b, n in spans if n == "strom.ioctl_memcpy_submit"]
-    assert all(any(a <= p and q <= b for a, b in subs)
-               for p, q, n in spans if n == "strom.pagecache_copy")
+    # each batch is one 1 MiB write-back run, copied on the I/O pool: a
+    # strom.writeback_copy span on a pool thread's line, none on the
+    # caller's, inside the load, after the first submit has queued it
+    assert d["nr_pagecache_copy"] == 0
+    copies = [(a, b) for ln in others for a, b, n, _ in ln
+              if n == "strom.writeback_copy"]
+    assert d["nr_writeback_copy"] == len(copies) == 4
+    assert not any(n == "strom.writeback_copy" for *_, n in spans)
+    first_sub = min(a for a, _, n in spans
+                    if n == "strom.ioctl_memcpy_submit")
+    assert all(first_sub <= p <= q <= c1 for p, q in copies)
 
 
 @pytest.mark.parametrize("arbitration", [True, False])
@@ -116,8 +129,10 @@ def test_pagecache_copies_only_where_arbitration_writes_back(
     assert d["nr_staging_fence_blocked"] <= d["nr_staging_fence"]
     assert d["clk_load_setup"] > 0
     # a freshly written file is page-cache resident: with arbitration on
-    # every chunk is copied from the page cache, with it off none is
-    assert (d["nr_pagecache_copy"] > 0) == arbitration
+    # every chunk is copied from the page cache (inline or on the I/O
+    # pool), with it off none is
+    assert (d["nr_pagecache_copy"] + d["nr_writeback_copy"] > 0) \
+        == arbitration
     assert (d["nr_submit_dma"] == 0) == arbitration
 
 
